@@ -109,6 +109,10 @@ def main(argv=None) -> int:
                 except Exception:
                     pass
         compactor = threading.Thread(target=compact_loop, daemon=True)
+    if store is not None:
+        # which WAL appender is live (native/walcore.cc or the python one):
+        # a toolchain failure must be readable from the hub's own output
+        print(f"wal {wal_file} native={store.wal_native}", flush=True)
     print(f"serving on {srv.address}", flush=True)
     stop = threading.Event()
 

@@ -8,6 +8,7 @@ election, healthz+metrics serving, then Scheduler.Run against the hub.
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import signal
 import sys
@@ -47,6 +48,16 @@ def main(argv=None) -> int:
     if args.disable_preemption is not None:
         cfg.disable_preemption = args.disable_preemption
 
+    # name the device before anything is scheduled on it: a backend that
+    # cannot initialise raises HERE, and whoever started this process
+    # reads which chip (or CPU) its decisions come from
+    from ..scheduler import (compile_log, device_report,
+                             enable_compile_cache)
+    cache_dir = enable_compile_cache()
+    compiles = compile_log()
+    print("kube-scheduler device " + json.dumps(
+        {**device_report(), "compile_cache": cache_dir}), flush=True)
+
     client = HTTPClient(args.master)
     sched = build_scheduler(client, cfg)
 
@@ -66,6 +77,9 @@ def main(argv=None) -> int:
         stop.set()
     signal.signal(signal.SIGTERM, shutdown)
     signal.signal(signal.SIGINT, shutdown)
+    # a run loop that keeps raising the same exception ends the process
+    # with a non-zero exit (Scheduler._run_loop) for the supervisor to see
+    sched.on_fatal = shutdown
 
     if cfg.leader_election.leader_elect:
         le = cfg.leader_election
@@ -94,6 +108,13 @@ def main(argv=None) -> int:
         sched.stop()
     if healthz is not None:
         healthz.stop()
+    # what this process compiled and what the persistent cache gave it
+    print("kube-scheduler compiles " + json.dumps(compiles.summary()),
+          flush=True)
+    if sched.fatal_error is not None:
+        print(f"kube-scheduler: scheduling loop failed: "
+              f"{sched.fatal_error!r}", file=sys.stderr, flush=True)
+        return 1
     return 0
 
 

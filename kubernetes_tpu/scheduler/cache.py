@@ -207,6 +207,15 @@ class Cache:
         with self._lock:
             return pod.metadata.key() in self._assumed
 
+    def without_assumed(self, pods: List[Pod]) -> List[Pod]:
+        """`pods` minus the ones this cache holds as assumed — one lock
+        for a whole popped batch."""
+        with self._lock:
+            if not self._assumed:
+                return pods
+            return [p for p in pods
+                    if p.metadata.key() not in self._assumed]
+
     def assumed_pods(self) -> List[Pod]:
         """The in-flight (assumed, unconfirmed) pods — the set the chaos
         invariant checker sweeps for reservations pinned to dead nodes."""

@@ -13,7 +13,10 @@ constraint templates at once,
                                                         # forbids a match
     mask[u, n] = viol[u, n] == 0
 
-three [U, T] × [T, N] matmuls that land on the MXU. The topology index
+three [U, T] × [T, N] matmuls that land on the MXU — at full precision:
+the operands are integer-valued f32 (0/1 selectors, weights up to 100,
+counts that reach thousands in a zone) and the TPU's default matmul
+would round them to bf16 first, exact only up to 256. The topology index
 (scheduler/topology.py) maintains the sparse counts incrementally and
 routes evaluation here when U·T·N is large; small batches stay on host
 numpy (identical arithmetic — tests/test_topology.py asserts equality).
@@ -35,11 +38,16 @@ def _bucket(n: int, minimum: int = 8) -> int:
     return max(minimum, 1 << max(0, math.ceil(math.log2(max(1, n)))))
 
 
+def _matmul(a, b):
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 @jax.jit
 def _affinity_masks_jit(has_dom, present, sel_dom, sel_present, sel_absent):
     hd = has_dom.astype(jnp.float32)
     pr = (present & has_dom).astype(jnp.float32)
-    viol = sel_dom @ (1.0 - hd) + sel_present @ (1.0 - pr) + sel_absent @ pr
+    viol = (_matmul(sel_dom, 1.0 - hd) + _matmul(sel_present, 1.0 - pr)
+            + _matmul(sel_absent, pr))
     return viol == 0.0
 
 
@@ -48,7 +56,7 @@ def _affinity_scores_jit(weights, counts):
     """[U, T] preferred-term weights × [T, N] match/carry counts — the
     segment-reduction form of interpod_affinity.go's pair-weight
     accumulation."""
-    return weights @ counts
+    return _matmul(weights, counts)
 
 
 def affinity_masks(has_dom: np.ndarray, present: np.ndarray,

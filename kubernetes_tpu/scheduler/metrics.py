@@ -118,6 +118,12 @@ class SchedulerMetrics:
         self.capped_scans = r.counter(
             "scheduler_capped_scans_total",
             "Scans truncated at a documented cap, by cap name")
+        # exceptions that escaped a scheduling cycle in the served run loop
+        # (Scheduler._run_loop): a scan the device compiler refuses must
+        # be visible on /metrics, not only as a traceback on stderr
+        self.loop_errors = r.counter(
+            "scheduler_loop_errors_total",
+            "Exceptions raised out of a scheduling cycle in the run loop")
         # ---- sharded drain (mesh execution substrate) ----
         # batches routed through the shard_map kernel (per-shard
         # filter+score, cross-shard argmax) vs the GSPMD/single paths

@@ -47,6 +47,11 @@ MAX_INFLIGHT_BINDS = 2
 #: express-occupancy EWMA blend: old weight per sized cycle (0.8 keeps
 #: the signal hot ~3 cycles after an express burst drains)
 EXPRESS_EWMA_DECAY = 0.8
+#: consecutive run-loop cycles that may raise the SAME exception before
+#: the loop gives up (Scheduler.fatal_error / on_fatal): two retries
+#: ride out a transient fault, a third identical failure is a broken
+#: program
+MAX_LOOP_ERROR_STREAK = 3
 #: EWMA of the express share of queue depth above which bulk caps take
 #: an extra shrink unit — express bands have been queueing recently,
 #: so the next arrival should not wait out a mega-batch commit
@@ -168,6 +173,13 @@ class Scheduler:
         #: its successor batch finished the device scan — the commit
         #: thread's shrink signal to the drain
         self._commit_lagging = False
+        #: True when the commit stage now in flight must be joined before
+        #: the drain thread pops again: it requeues losers, or it assumes
+        #: pods whose placements feed host-side (anti-)affinity masks.
+        #: Decisions are a function of the queue and the cluster, never
+        #: of commit-thread timing (the bit-identity contract of the
+        #: sharded drain and the chaos determinism contract both need it)
+        self._commit_settles = False
         self.cache = Cache(clock=clock)
         self.queue = SchedulingQueue(clock=clock)
         self.informers = informer_factory or SharedInformerFactory(client)
@@ -194,6 +206,11 @@ class Scheduler:
             self.algorithm.speculative = bool(speculative)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        #: the exception that ended the run loop (MAX_LOOP_ERROR_STREAK
+        #: identical failures in a row), and the hook the loop calls with
+        #: it — cmd/kube_scheduler exits non-zero from there
+        self.fatal_error: Optional[BaseException] = None
+        self.on_fatal: Optional[Callable[[BaseException], None]] = None
         self._in_flight = 0  # pods popped but not yet decided this cycle
         #: async binding (the reference's bind goroutine, scheduler.go:521):
         #: assume synchronously, POST the bulk bind from a single binder
@@ -628,13 +645,16 @@ class Scheduler:
         pods = self.queue.pop_batch(max_pods or self._drain_cap(),
                                     timeout=timeout,
                                     on_pop=_mark_in_flight)
+        pods = self._skip_assumed(pods)
         if not pods:
+            self._in_flight = 0
             return []
         pods = self._drf_order(pods)
         if self.tracer.enabled:
             for pod in pods:
                 self.tracer.pod_event("scheduler", "drain_member", pod,
                                       cycle=cycle)
+        chunk: List[Pod] = []
         try:
             results: List[ScheduleResult] = []
             while pods:
@@ -648,9 +668,29 @@ class Scheduler:
                     # is memoized by list identity (core._soft_plan_cached)
                     chunk, pods = pods, []
                 results.extend(self._schedule_batch_locked(chunk, cycle))
+        except Exception:
+            # ref: scheduleOne's error path requeues the pod
+            # (recordSchedulingFailure -> Error func): popped pods live
+            # only in this cycle, so dropping them here would turn a
+            # failed scan into pods that pend forever with a quiet loop.
+            # Members the failed chunk already assumed are skipped at
+            # their next pop (_skip_assumed).
+            for pod in self._skip_assumed(chunk + pods):
+                self.queue.add(pod)
+            raise
         finally:
             self._in_flight = 0
         return results
+
+    def _skip_assumed(self, pods: List[Pod]) -> List[Pod]:
+        """Ref: skipPodSchedule (scheduler.go:445-463, "pod has been
+        assumed"). An update event that lands while a pod is in flight
+        re-adds it to the queue (queue.update's not-pending branch); by
+        its next pop the first attempt may have assumed it — bound and
+        awaiting confirmation, or reserved at the gang permit gate. A
+        second attempt would schedule it against its own reservation:
+        a gang member fails its whole gang that way."""
+        return self.cache.without_assumed(pods)
 
     def _schedule_batch_locked(self, pods: List[Pod], cycle: int
                                ) -> List[ScheduleResult]:
@@ -689,15 +729,26 @@ class Scheduler:
         Returns the number of successful assumes (one cache mutation each —
         the pipelined drain's chain_seq bookkeeping)."""
         bound: List[ScheduleResult] = []
+        unschedulable: List[Pod] = []
         for res in results:
             if res.node_name is None:
                 if res.retry:
                     # lost an in-batch conflict; immediately rescheduleable
                     self.queue.add(res.pod)
                 else:
-                    self._handle_unschedulable(res.pod, cycle + 1)
+                    unschedulable.append(res.pod)
             else:
                 bound.append(res)
+        # park every loser BEFORE diagnosing any: whole-gang preemption
+        # prices the gang's pending members, and a sibling still in flight
+        # here is invisible to it — the first member would price a
+        # one-member gang and the second re-evict the same victims
+        for pod in unschedulable:
+            self.unschedulable_count += 1
+            self.metrics.schedule_attempts.inc(result="unschedulable")
+            self.queue.add_unschedulable_if_not_present(pod, cycle + 1)
+        for pod in unschedulable:
+            self._handle_unschedulable(pod, cycle + 1)
         if bound:
             return self._assume_and_bind_all(bound)
         return 0
@@ -724,13 +775,11 @@ class Scheduler:
                 # a real accelerator's dispatch/fetch waits release the
                 # GIL, and on a many-core host the XLA CPU "device" runs
                 # on cores the commit thread doesn't contend; only a
-                # GIL-starved small host loses to the extra thread
-                try:
-                    import jax
-                    backend = jax.default_backend()
-                except Exception:
-                    backend = "cpu"
-                self._commit_async = backend != "cpu" or \
+                # GIL-starved small host loses to the extra thread. A
+                # backend that fails to initialise raises here: it must
+                # never read as "we are on CPU"
+                import jax
+                self._commit_async = jax.default_backend() != "cpu" or \
                     (_os.cpu_count() or 1) >= 4
         return self._commit_async
 
@@ -805,12 +854,21 @@ class Scheduler:
                 # exactly the self-heal the rollback needs)
                 self._gang_housekeeping()
                 cycle = self.queue.scheduling_cycle
+                if commit_fut is not None and self._commit_settles:
+                    # the in-flight commit requeues losers or feeds the
+                    # host-side masks: what this cycle pops and launches
+                    # must not depend on how far the commit thread got
+                    commit_fut.result()
+                    commit_fut = None
                 if carry:
                     pods, carry = carry, []
                 else:
                     pods = self.queue.pop_batch(self._drain_cap(), timeout=0,
                                                 on_pop=_mark)
-                    pods = self._drf_order(pods)
+                    kept = self._skip_assumed(pods)
+                    if len(kept) < len(pods):
+                        _mark(len(kept) - len(pods))
+                    pods = self._drf_order(kept)
                 if pods:
                     # spread-carrying pods schedule in sub-chunks so their
                     # soft scores refresh as winners land (core.soft_batch_limit)
@@ -855,6 +913,15 @@ class Scheduler:
                     break
                 pending = None
                 if pods:
+                    if commit_fut is not None and (
+                            (prev is not None and not prev[0].residual_free)
+                            or self.algorithm.reads_host_placements(pods)):
+                        # this launch (or the repair of the batch in
+                        # flight, which reads the state this launch
+                        # refreshes) builds masks from the snapshot the
+                        # commit thread is still assuming into
+                        commit_fut.result()
+                        commit_fut = None
                     tl0 = self.tracer.now() if self.tracer.enabled else 0.0
                     if prev is not None:
                         with self._algo_lock:
@@ -885,14 +952,16 @@ class Scheduler:
                                                         commit_fut)
                 prev = (pending, cycle) if pending is not None else None
         finally:
-            if commit_fut is not None:
-                try:
-                    commit_fut.result()
-                except Exception:
-                    pass
+            # settle the commit stage before the bookkeeping reset; its
+            # exception surfaces after the cleanup below, unless the drain
+            # itself is already unwinding with one
+            commit_exc = commit_fut.exception() \
+                if commit_fut is not None else None
             self._commit_lagging = False
             with self._count_lock:
                 self._in_flight = 0
+        if commit_exc is not None:
+            raise commit_exc
         with self._count_lock:
             return self.scheduled_count - start
 
@@ -940,6 +1009,8 @@ class Scheduler:
         self._pipe_outcomes.append(
             [(r.pod, r.node_name) for r in results
              if r.node_name is not None])
+        self._commit_settles = not pending.residual_free or any(
+            r.node_name is None for r in results)
         if self._commit_overlaps():
             return self._commit_pool.submit(self._commit_stage, results,
                                             cycle, t0)
@@ -1479,9 +1550,8 @@ class Scheduler:
         trace.log_if_long(100.0)
 
     def _handle_unschedulable(self, pod: Pod, cycle: int) -> None:
-        self.unschedulable_count += 1
-        self.metrics.schedule_attempts.inc(result="unschedulable")
-        self.queue.add_unschedulable_if_not_present(pod, cycle)
+        """Diagnose a parked loser (attribution, event) and try to
+        preempt for it; _commit_results parked it already."""
         # _algo_lock: this may run on the COMMIT thread while the drain
         # thread tensorizes the next batch — explain iterates the snapshot
         # and preempt refreshes it, both of which would race the launch
@@ -1666,12 +1736,27 @@ class Scheduler:
         self._thread.start()
 
     def _run_loop(self) -> None:
+        last_error, streak = None, 0
         while not self._stop.is_set():
             try:
-                self.schedule_pending(timeout=0.2)
-            except Exception:
+                if self.schedule_pending(timeout=0.2):
+                    last_error, streak = None, 0
+            except Exception as e:
                 import traceback
                 traceback.print_exc()
+                self.metrics.loop_errors.inc()
+                error = (type(e), str(e))
+                streak = streak + 1 if error == last_error else 1
+                last_error = error
+                if streak >= MAX_LOOP_ERROR_STREAK:
+                    # the same failure on every cycle is not transient (a
+                    # scan the device compiler refuses, a backend that is
+                    # gone): stop instead of spinning with /healthz green
+                    self.fatal_error = e
+                    self._stop.set()
+                    if self.on_fatal is not None:
+                        self.on_fatal(e)
+                    return
             self.cache.cleanup_expired_assumed_pods()
 
     def stop(self) -> None:

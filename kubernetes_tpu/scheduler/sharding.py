@@ -138,6 +138,13 @@ def resolve_mesh(mesh=None):
                     "degenerate mesh")
             devices = devices[:mesh]
         if len(devices) < 2:
+            # "auto" on a one-device host is the single-device path, by
+            # definition — said aloud, so nobody reads a one-chip run as
+            # a sharded one
+            import logging
+            logging.getLogger("scheduler").warning(
+                "%s=auto found %d device: the drain runs single-device, "
+                "no mesh", source, len(devices))
             return None
         return Mesh(np.array(devices), (NODE_AXIS,))
     if NODE_AXIS not in mesh.axis_names:

@@ -248,6 +248,11 @@ class Store:
         if self._wal is not None:
             self._wal.flush()
 
+    @property
+    def wal_native(self) -> bool:
+        """True when the journal appends through native/walcore.cc."""
+        return self._wal is not None and self._wal.native
+
     def flush_wal(self) -> None:
         """Wait until every journaled record is in the file. In deferred
         mode the worker lags the write path by design (a process crash can

@@ -112,13 +112,20 @@ def dominant_shares_reference(usage: np.ndarray,
     return np.max(shares, axis=1)
 
 
-def _order_kernel(prio, share, pos):
-    """[P] priorities + [P] per-pod dominant shares + [P] pop positions
-    -> permutation: priority desc, share asc, position asc. Position is
-    the unique final key, so the permutation never depends on sort
-    stability."""
+def _order_kernel(usage, cap, tidx, prio, n):
+    """[T, R] usage + [R] capacity + [P] per-pod tenant rows + [P]
+    priorities, the first n of P real -> permutation: priority desc,
+    dominant share asc, pop position asc, the pad slots last (their
+    own leading key, so no real pod can tie with one). Position is the
+    unique final key, so the permutation never depends on sort
+    stability. ONE program per (T capacity, P bucket): shares, the
+    per-pod gather and the sort ride one dispatch, and a pop of any
+    size inside the bucket reuses it — on the chip a sort compiles in
+    seconds, which an unbucketed pod axis would pay per pop size."""
     import jax.numpy as jnp
-    return jnp.lexsort((pos, share, -prio))
+    pos = jnp.arange(prio.shape[0], dtype=jnp.int32)
+    share = _dominant_kernel(usage, cap)[tidx]
+    return jnp.lexsort((pos, share, -prio, pos >= n))
 
 
 def drf_order_reference(prio: np.ndarray, share: np.ndarray,
@@ -263,22 +270,23 @@ class DRFAccount:
             return list(pods)
         if len(pods) < self.DEVICE_FLOOR:
             return self.order_batch_reference(pods)
+        n = len(pods)
+        P = 1 << (n - 1).bit_length()    # power-of-two pod bucket
+        tidx = np.zeros((P,), np.int32)
+        prio = np.zeros((P,), np.int32)
         with self._lock:
-            tidx = np.array([self.tenant_index(tenant_of(p))
-                             for p in pods], np.int32)
-            T = max(1, len(self._names))
-            usage = self._usage[:T].copy()
+            tidx[:n] = [self.tenant_index(tenant_of(p)) for p in pods]
+            # the whole doubling-capacity ledger: rows past the last
+            # tenant are zero and no tidx names them
+            usage = self._usage.copy()
             cap = self._capacity.copy()
-        import jax.numpy as jnp
+        prio[:n] = [helpers.pod_priority(p) for p in pods]
         from ..scheduler import sharding
-        u = sharding.put(self.mesh, "tenant_usage", usage)
-        c = sharding.put(self.mesh, "tenant_capacity", cap)
-        shares = _jit(_dominant_kernel)(u, c)
-        prio = np.array([helpers.pod_priority(p) for p in pods], np.int32)
-        pos = np.arange(len(pods), dtype=np.int32)
+        put = lambda name, arr: sharding.put(self.mesh, name, arr)
         perm = np.asarray(_jit(_order_kernel)(
-            jnp.asarray(prio), shares[tidx], jnp.asarray(pos)))
-        return [pods[int(i)] for i in perm]
+            put("tenant_usage", usage), put("tenant_capacity", cap),
+            put("tidx", tidx), put("prio", prio), np.int32(n)))
+        return [pods[int(i)] for i in perm[:n]]
 
     def order_batch_reference(self, pods: List[Pod]) -> List[Pod]:
         """The serial numpy mirror of order_batch (parity surface)."""

@@ -15,16 +15,18 @@ import pytest
 import kubernetes_tpu
 
 
-# native/walcore.so is a ctypes-loaded shared library (native/build.py),
-# not a Python extension module; pkgutil still lists it
-NOT_PYTHON_MODULES = {"kubernetes_tpu.native.walcore"}
+# native/walcore-<source hash>.so is a ctypes-loaded shared library
+# (native/build.py), not a Python extension module; pkgutil still lists
+# it — and it appears mid-run, so every xdist worker must leave it out
+# whether or not it exists yet
+NOT_PYTHON_MODULES = "kubernetes_tpu.native.walcore"
 
 
 def _all_modules():
     mods = []
     for info in pkgutil.walk_packages(kubernetes_tpu.__path__,
                                       prefix="kubernetes_tpu."):
-        if info.name not in NOT_PYTHON_MODULES:
+        if not info.name.startswith(NOT_PYTHON_MODULES):
             mods.append(info.name)
     return sorted(mods)
 

@@ -375,6 +375,21 @@ class TestKernelParity:
         assert results[0].node_name == "n2"
 
 
+def test_floor_tenths_is_the_reference_integer_division():
+    """LeastRequested's (cap-req)*10 // cap without a divide: exact on
+    every boundary, for benign divisors (4000) and for ones whose f32
+    reciprocal rounds down (3900, 110 — where the TPU's divide scored
+    every exact multiple one point low)."""
+    from kubernetes_tpu.scheduler.kernels.batch import _floor_tenths
+    for den, step in ((1, 1), (7, 1), (110, 1), (3900, 1), (4000, 1),
+                      (31 << 30, 1 << 20)):
+        num = np.arange(0, den + 1, step, dtype=np.int64)
+        num = num[:: max(1, len(num) // 20000)]
+        got = np.asarray(_floor_tenths(num.astype(np.float32),
+                                       np.float32(den)))
+        assert (got == (10 * num) // den).all(), den
+
+
 class TestFullPriorityParity:
     """M3: all 8 default priorities — kernel+ScoreCompiler choice must land on
     an oracle-max node (prioritize_nodes over the feasible set)."""
@@ -1223,3 +1238,97 @@ class TestAlignSplitGate:
         cache.add_pod(bound)
         sched.refresh()
         assert sched.topo_scan_likely([plain])
+
+
+class TestRunLoopFailures:
+    """A failed scheduling cycle is counted, keeps its pods, and — when it
+    repeats — ends the loop, instead of a traceback loop under a green
+    /healthz (what a scan the device compiler refuses used to become)."""
+
+    def _cluster(self, n_pods=3):
+        client = Client()
+        node = client.nodes().create(make_node("n0"))
+        sched = Scheduler(client, batch_size=8)
+        sched.cache.add_node(node)
+        for i in range(n_pods):
+            sched.queue.add(client.pods().create(make_pod(f"p{i}")))
+        return client, sched
+
+    def test_failed_cycle_requeues_its_pods(self, monkeypatch):
+        _, sched = self._cluster()
+
+        def boom(pods):
+            raise RuntimeError("scan refused")
+        monkeypatch.setattr(sched.algorithm, "schedule", boom)
+        with pytest.raises(RuntimeError, match="scan refused"):
+            sched.schedule_pending()
+        # popped pods live only in the cycle: none may be dropped
+        assert sched.queue.num_pending() == 3
+
+    def test_repeated_failure_stops_the_loop(self, monkeypatch):
+        from kubernetes_tpu.scheduler.scheduler import MAX_LOOP_ERROR_STREAK
+        _, sched = self._cluster()
+        calls = []
+
+        def boom(pods):
+            calls.append(len(pods))
+            raise RuntimeError("scan refused")
+        monkeypatch.setattr(sched.algorithm, "schedule", boom)
+        fatal = []
+        sched.on_fatal = fatal.append
+        sched._run_loop()          # returns by itself: no spinning
+        assert len(calls) == MAX_LOOP_ERROR_STREAK
+        assert isinstance(sched.fatal_error, RuntimeError)
+        assert fatal == [sched.fatal_error]
+        assert sched.metrics.loop_errors.value() == MAX_LOOP_ERROR_STREAK
+        assert "scheduler_loop_errors_total 3" in \
+            sched.metrics.registry.expose()
+
+    def test_transient_failure_recovers(self, monkeypatch):
+        client, sched = self._cluster()
+        real = sched.algorithm.schedule
+        state = {"n": 0}
+
+        def flaky(pods):
+            state["n"] += 1
+            if state["n"] == 1:
+                raise RuntimeError("transient")
+            out = real(pods)
+            sched._stop.set()      # one good cycle, then leave the loop
+            return out
+        monkeypatch.setattr(sched.algorithm, "schedule", flaky)
+        sched._run_loop()
+        assert sched.fatal_error is None
+        assert sched.metrics.loop_errors.value() == 1
+        assert all(p.spec.node_name == "n0" for p in client.pods().list())
+
+    def test_pod_requeued_after_assume_is_not_scheduled_twice(self):
+        """Ref skipPodSchedule: an update event that lands while a pod is
+        in flight re-adds it to the queue; once the first attempt has
+        assumed it, the duplicate must be dropped at its next pop (a gang
+        member re-scheduled against its own permit-gate reservation
+        failed its whole gang)."""
+        client, sched = self._cluster(n_pods=1)
+        results = sched.schedule_pending()
+        assert [r.node_name for r in results] == ["n0"]
+        pod = client.pods().get("p0")
+        assert sched.cache.is_assumed_pod(pod)   # no informer confirms it
+        sched.queue.update(None, pod)            # the late update event
+        assert sched.queue.num_pending() == 1
+        assert sched.schedule_pending() == []
+        sched.queue.update(None, pod)            # same, pipelined drain
+        assert sched.drain_pipelined() == 0
+        assert sched.queue.num_pending() == 0 and sched._in_flight == 0
+
+    def test_commit_stage_exception_surfaces_from_drain(self, monkeypatch):
+        """drain_pipelined used to swallow the commit thread's exception
+        at the end of the drain."""
+        _, sched = self._cluster()
+        monkeypatch.setenv("KTPU_COMMIT_THREAD", "1")
+
+        def boom(results, cycle):
+            raise RuntimeError("commit failed")
+        monkeypatch.setattr(sched, "_commit_results", boom)
+        with pytest.raises(RuntimeError, match="commit failed"):
+            sched.drain_pipelined()
+        assert sched._in_flight == 0

@@ -148,6 +148,34 @@ def test_sharded_drain_bit_identical(variant):
     assert len(next(iter(usage.values())).sharding.device_set) == 8
 
 
+@pytest.mark.parametrize("variant", ["anti-affinity", "anti-affinity-dir2"])
+def test_drain_independent_of_commit_thread_timing(variant, monkeypatch):
+    """The bit-identity above compares two drains, so each drain must be
+    a function of its queue and cluster alone. On a host with >= 4 cores
+    (and on any accelerator) the commit stage runs on its own thread; a
+    launch that read the snapshot while that thread was part-way through
+    its assumes gave binds that depended on the interleaving (the
+    anti-affinity-dir2 failures: repair of a chained batch re-assigned
+    against a half-committed predecessor). Inline commit, a fast commit
+    thread and a commit thread slowed per assume must agree pod for
+    pod."""
+    import time as _time
+    from kubernetes_tpu.scheduler import Scheduler
+    monkeypatch.setenv("KTPU_COMMIT_THREAD", "0")
+    _, inline, _ = _drain(1, variant)
+    monkeypatch.setenv("KTPU_COMMIT_THREAD", "1")
+    _, threaded, _ = _drain(1, variant)
+    tracked = Scheduler._tracked_assume
+
+    def slow_assume(self, pod):
+        _time.sleep(0.002)
+        tracked(self, pod)
+    monkeypatch.setattr(Scheduler, "_tracked_assume", slow_assume)
+    _, slowed, _ = _drain(1, variant)
+    assert inline == threaded
+    assert inline == slowed
+
+
 @pytest.mark.parametrize("shards", [4, 8])
 @pytest.mark.parametrize("variant", ["soft-affinity", "nominated"])
 def test_new_shapes_sharded_bit_identical(variant, shards):

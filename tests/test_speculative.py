@@ -296,6 +296,9 @@ class TestX64PackedArgmax:
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
         env.pop("PYTEST_CURRENT_TEST", None)
+        # one process per chip: a child with its own JAX is pinned to the
+        # CPU from its first instruction, whatever this process runs on
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run([sys.executable, str(script)], env=env,
                              capture_output=True, text=True, timeout=540)
         assert out.returncode == 0, out.stderr[-4000:]
