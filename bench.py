@@ -232,12 +232,15 @@ def run_config(n_nodes, n_pods, variant, batch=None, seed_pods=0,
         sched.algorithm.mirror.invalidate_usage()
     _warm_dirty_scatter(sched)
     # per-phase attribution for the TIMED drain only (warmup batches
-    # above also run the launch/finish machinery): host term-prep wall vs
-    # device scan wait vs repair wall, plus the epoch-keyed cache
+    # above also run the launch/finish machinery): the stage histograms'
+    # sums (tensorize vs scan wait vs repair) and the epoch-keyed cache
     # effectiveness — the lens that shows term-table rebuilds per drain
-    # are O(topology changes), not O(batches)
+    # are O(topology changes), not O(batches) — each as drain end - start
     algo = sched.algorithm
-    algo.reset_phase_stats()
+    stage = sched.metrics.scheduling_duration
+    st0 = {op: stage.sum(operation=op)
+           for op in ("tensorize", "scan_wait", "repair")}
+    pf0 = dict(algo.phase_stats)
     topo = algo.topology
     tb0, th0 = topo.table_builds, topo.table_hits
     mb0, mh0 = topo.mask_row_builds, topo.mask_row_hits
@@ -262,17 +265,20 @@ def run_config(n_nodes, n_pods, variant, batch=None, seed_pods=0,
         # programs built or loaded inside the timed drain: every one is
         # a bucket the warm-up above missed
         "compiles_in_drain": compiles.programs - programs0,
-        "host_term_prep_s": round(ps["term_prep_s"], 4),
-        "device_scan_wait_s": round(ps["scan_wait_s"], 4),
-        "repair_reassign_s": round(ps["repair_s"], 4),
+        "host_term_prep_s": round(
+            stage.sum(operation="tensorize") - st0["tensorize"], 4),
+        "device_scan_wait_s": round(
+            stage.sum(operation="scan_wait") - st0["scan_wait"], 4),
+        "repair_reassign_s": round(
+            stage.sum(operation="repair") - st0["repair"], 4),
         "table_builds": topo.table_builds - tb0,
         "table_hits": topo.table_hits - th0,
         # the incremental [U, N] affinity-mask maintenance (ISSUE 14):
         # builds ~ O(presence changes), hits ~ O(batches)
         "mask_row_builds": topo.mask_row_builds - mb0,
         "mask_row_hits": topo.mask_row_hits - mh0,
-        "profile_builds": ps["profile_builds"],
-        "profile_hits": ps["profile_hits"],
+        "profile_builds": ps["profile_builds"] - pf0["profile_builds"],
+        "profile_hits": ps["profile_hits"] - pf0["profile_hits"],
         "inscan_fallbacks": {
             r: sched.metrics.topo_inscan_fallbacks.value(reason=r) - v
             for r, v in fb0.items()},

@@ -1097,6 +1097,8 @@ class APIServer:
                         bindings.append(b)
                     outs = self.client.pods(req.namespace or None) \
                         .bind_bulk(bindings)
+                self.request_metrics.pods_bound.inc(sum(
+                    1 for o in outs if not isinstance(o, Exception)))
                 # slim per-slot results — the reference's bind returns
                 # metav1.Status, never the pod; echoing N full pods would
                 # cost an encode+decode per bind on the hot path
@@ -1141,6 +1143,7 @@ class APIServer:
                 if not self._enforce_namespace(h, req, binding):
                     return
                 out = self.client.pods(req.namespace or None).bind(binding)
+                self.request_metrics.pods_bound.inc()
                 self._respond(h, 201, out)
                 return
             if data.get("kind") == "List" and \

@@ -47,14 +47,20 @@ def main(argv=None) -> int:
         import os
 
         from ..state.store import Store
+        from ..utils.metrics import StoreMetrics
         os.makedirs(args.data_dir, exist_ok=True)
         wal_file = os.path.join(args.data_dir, "store.wal")
-        store = Store(wal_path=wal_file, wal_sync=args.wal_sync)
+        store = Store(wal_path=wal_file, wal_sync=args.wal_sync,
+                      metrics=StoreMetrics())
     srv = APIServer(store=store, host=args.bind_address,
                     port=args.port, audit_log_path=args.audit_log_path,
                     tls_cert_file=args.tls_cert_file,
                     tls_key_file=args.tls_private_key_file,
                     client_ca_file=args.client_ca_file)
+    if store is not None:
+        # the store's own families (wal_*, store_lock_wait_seconds,
+        # store_compaction_seconds) beside the request families
+        srv.metrics.add_registry("store", store.metrics.registry)
     if args.client_ca_file and not args.token_auth_file:
         # x509-only authn: cert identities + default-deny RBAC
         from ..apiserver.auth import CertAuthenticator, RBACAuthorizer

@@ -15,17 +15,23 @@ trace_id. The exported JSONL is canonically ordered (export_jsonl), so
 the contract rests on the deterministic SET of spans, not on which
 informer thread's append won a race within a settle window.
 
-Cost model: batch/stage spans are one record per batch (always on);
-pod-lifecycle spans are sampled 1-in-`pod_sample` by a crc32 of the
-trace_id (default 16, KTPU_TRACE_SAMPLE overrides; harnesses pass 1 to
-capture every pod). The recorder is a per-component ring — oldest spans
-evict, and the eviction count is itself visible (`dropped`).
+Cost model: a stage (SpanTracer.stage) is entered once per batch,
+request or transaction, never per pod. Its histogram is always on; its
+trace annotation costs the profiler's own check while no profiler
+session runs; its span reaches the flight recorder only where a harness
+attached an enabled tracer. Pod-lifecycle spans are sampled
+1-in-`pod_sample` by a crc32 of the trace_id (default 16,
+KTPU_TRACE_SAMPLE overrides; harnesses pass 1 to capture every pod) and
+cost nothing on a disabled tracer. The recorder is a per-component ring
+— oldest spans evict, and the eviction count is itself visible
+(`dropped`).
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import zlib
 from collections import deque
@@ -142,6 +148,53 @@ class FlightRecorder:
             return sum(len(b) for b in self._buffers.values())
 
 
+class _Stage:
+    """One timed interval at a layer boundary (SpanTracer.stage)."""
+
+    __slots__ = ("_tracer", "_name", "_histogram", "_labels", "_component",
+                 "_ring", "_annotation", "start", "attrs", "seconds")
+
+    def __init__(self, tracer, name, histogram, labels, component, trace,
+                 ring, attrs):
+        self._tracer = tracer
+        self._name = name
+        self._histogram = histogram
+        self._labels = labels
+        self._component = component
+        self._ring = ring
+        #: recorded with the span; a caller may add what it only learns
+        #: inside the interval
+        self.attrs = attrs
+        #: the interval on the tracer's clock: where it began, set on
+        #: entry, and its length, set on exit
+        self.start = 0.0
+        self.seconds = 0.0
+        # only a process that already runs JAX can have a profiler
+        # session: the hub calls the same helper and never imports it
+        jax = sys.modules.get("jax") if trace is not None else None
+        self._annotation = jax.profiler.TraceAnnotation(trace) \
+            if jax is not None else None
+
+    def __enter__(self) -> "_Stage":
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self.start = self._tracer.clock.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tracer = self._tracer
+        end = tracer.clock.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self.seconds = end - self.start
+        if self._histogram is not None:
+            self._histogram.observe(self.seconds, **self._labels)
+        if self._ring and tracer.enabled:
+            tracer.recorder.record(Span("", self._component, self._name,
+                                        self.start, end,
+                                        self.attrs or None))
+
+
 class SpanTracer:
     """The emitting half: components call record()/event()/pod_event()
     and the spans land in the shared FlightRecorder. All timestamps come
@@ -174,10 +227,34 @@ class SpanTracer:
             return self.enabled
         return zlib.crc32(trace_id.encode()) % self.pod_sample == 0
 
+    def stage(self, name: str, histogram=None, *,
+              labels: Optional[dict] = None, trace: Optional[str] = None,
+              component: str = "scheduler", ring: bool = True,
+              **attrs) -> _Stage:
+        """THE timer of a layer boundary, a context manager: one pair of
+        reads of this tracer's clock, and on exit
+
+        - `histogram.observe(seconds, **labels)`: always, the series an
+          operator scrapes (None: a component without metrics);
+        - `trace`: the interval wrapped in a jax.profiler.TraceAnnotation
+          of that name, so that while a profiler session runs the span
+          lies on the /host:CPU plane on the device trace's own clock.
+          Leaf stages only: a parent's annotation would cover the host
+          time its leaves leave unexplained;
+        - a span in the flight recorder, if this tracer is enabled.
+          `ring=False` keeps out a stage whose count depends on
+          real-time thread timing (same-seed span logs stay identical).
+
+        Works on a disabled tracer (NULL_TRACER for a component that has
+        none): "disabled" switches the ring and the per-pod milestones,
+        not the stages."""
+        return _Stage(self, name, histogram, labels or {}, component,
+                      trace, ring, attrs)
+
     def record(self, component: str, name: str, start: float,
                end: Optional[float] = None, trace_id: str = "",
                **attrs) -> None:
-        """Record a finished interval (batch/stage spans — always on)."""
+        """Record a finished interval in the flight recorder."""
         if not self.enabled:
             return
         self.recorder.record(Span(trace_id, component, name, start,

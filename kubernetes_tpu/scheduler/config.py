@@ -152,6 +152,7 @@ class KubeSchedulerConfiguration:
 def build_scheduler(client, cfg: KubeSchedulerConfiguration):
     """Configurator: config -> a wired Scheduler (ref: factory.go
     CreateFromConfig/CreateFromProvider)."""
+    from ..observability import SpanTracer
     from .scheduler import Scheduler
     policy = cfg.policy or Policy()
     extenders = [HTTPExtender(e) for e in policy.extenders]
@@ -159,7 +160,11 @@ def build_scheduler(client, cfg: KubeSchedulerConfiguration):
         client, batch_size=cfg.batch_size,
         scheduler_name=cfg.scheduler_name,
         disable_preemption=cfg.disable_preemption,
-        extenders=extenders)
+        extenders=extenders,
+        # the served process exposes no flight recorder: its stages are
+        # on /metrics and on the profiler's trace, and the per-pod
+        # milestones would be sampled into a ring nobody can read
+        tracer=SpanTracer(enabled=False))
     # rebuild the algorithm's scorer with policy weights
     if policy.priorities is not None or \
             policy.hard_pod_affinity_symmetric_weight != HARD_POD_AFFINITY_WEIGHT:

@@ -40,6 +40,7 @@ import numpy as np
 from ..api import helpers
 from ..api.core import Pod
 from ..api.serde import deepcopy_obj
+from ..observability.tracer import NULL_TRACER
 from .cache import Cache, Snapshot
 from .nodeinfo import NodeInfo, pod_has_affinity_constraints
 from . import predicates as preds
@@ -388,9 +389,9 @@ class BatchScheduler:
         #: bare-algorithm tests); used for in-scan fallback counters
         self.sched_metrics = None
         #: observability.SpanTracer, installed by the shell: the device
-        #: path's stage spans (tensorize / scan wait) ride the same
-        #: flight recorder as the shell's launch/commit/bind spans
-        self.tracer = None
+        #: path's stages (self._stage) ride the same histogram family,
+        #: trace and flight recorder as the shell's commit/bind stages
+        self.tracer = NULL_TRACER
         self._fallback_streak: Dict[str, int] = {}
         #: (pod-list, plan) from the most recent _soft_plan: the drain's
         #: soft_batch_limit and the launch's _assign_soft_terms see the
@@ -398,18 +399,18 @@ class BatchScheduler:
         #: channel-planning pass runs once per batch, not twice
         self._soft_plan_memo: Optional[Tuple[List[Pod], Optional[dict]]] = \
             None
-        #: per-drain phase accounting, surfaced by bench.py's affinity
-        #: breakdown: host term-prep wall vs device scan wait vs
-        #: repair/reassign wall, plus profile-cache effectiveness
-        #: (term-table cache counters live on the TopologyIndex)
-        self.phase_stats = {"term_prep_s": 0.0, "scan_wait_s": 0.0,
-                            "repair_s": 0.0, "profile_builds": 0,
-                            "profile_hits": 0}
+        #: profile-cache effectiveness (term-table cache counters live
+        #: on the TopologyIndex); the stages' times are on
+        #: scheduler_scheduling_duration_seconds{operation}
+        self.phase_stats = {"profile_builds": 0, "profile_hits": 0}
 
-    def reset_phase_stats(self) -> None:
-        for k in self.phase_stats:
-            self.phase_stats[k] = 0 if isinstance(
-                self.phase_stats[k], int) else 0.0
+    def _stage(self, name: str, **attrs):
+        """The timer of one boundary of a batch: SchedulerMetrics.stage
+        under a shell, the trace annotation and the flight recorder's
+        span alone for a bare algorithm."""
+        if self.sched_metrics is not None:
+            return self.sched_metrics.stage(self.tracer, name, **attrs)
+        return self.tracer.stage(name, trace="sched." + name, **attrs)
 
     def refresh(self) -> None:
         dirty = self.cache.update_snapshot(self.snapshot)
@@ -1509,210 +1510,206 @@ class BatchScheduler:
             return None
         from ..utils.features import DEFAULT_FEATURE_GATE
         from .kernels.batch import pack_results, schedule_batch
-        dirty = self.cache.update_snapshot(self.snapshot)
-        # volume predicates can NEVER ride a chain (PV reservations need
-        # committed state); affinity CAN — its stale mask (snapshot lacks
-        # the chain's uncommitted winners) is repaired post-kernel against
-        # stale_winners, the same overlay that validates same-batch winners
-        affinity_only = not self._has_filter_extenders() and all(
-            not (_pod_has_conflict_volumes(p) or _pod_has_pvc(p)
-                 or _pod_has_attach_volumes(p)) for p in pods)
-        chain_intact = chain_seq is not None and (
-            chain_seq() if callable(chain_seq)
-            else self.cache.mutation_seq == chain_seq)
-        chaining = (chain is not None
-                    and (chain.residual_free or chain.affinity_chainable)
-                    and DEFAULT_FEATURE_GATE.enabled("SchedulerDeviceChaining")
-                    and chain_intact
-                    and not self._static_likely
-                    and self.mirror.device_ready()
-                    and affinity_only)
-        if chaining:
-            self.mirror.apply_chained(self.snapshot, dirty)
-            self.topology.apply(self.snapshot, dirty)
-            if dirty:
-                # keep the scorer's gate fresh on the chained path too: if
-                # this drain's own commits introduced score-contributing
-                # carriers, static_scores below turns non-None and refuses
-                # the chain — matching the sequential path's scoring
-                self.scorer.set_cluster_has_affinity_pods(
-                    self.topology.has_score_carriers())
-        else:
-            # the dirty list is consumed either way — a chain refusal must
-            # still apply it, or the mirror would never see these updates
-            # (update_snapshot won't return them again)
-            self.mirror.apply(self.snapshot, dirty)
-            self.topology.apply(self.snapshot, dirty)
-            if dirty:
-                self.scorer.set_cluster_has_affinity_pods(
-                    self.topology.has_score_carriers())
-            if chain is not None:
-                return None
-        import time as _time
-        tr = self.tracer if self.tracer is not None \
-            and self.tracer.enabled else None
-        t_tz = tr.now() if tr is not None else 0.0
-        t_prep = _time.perf_counter()
-        extra_mask, profiles, extra_group = self._residual_mask(pods)
-        residual_free = extra_mask is None and not any(
-            helpers.pod_host_ports(p) or _pod_has_conflict_volumes(p)
-            for p in pods)
-        affinity_chainable = affinity_only and not any(
-            helpers.pod_host_ports(p) for p in pods)
-        #: gang units present -> the all-or-nothing kernel decides this
-        #: batch. Gang batches CHAIN like singleton batches: the kernel's
-        #: trial/commit carry isolates uncommitted (rejected-gang) state,
-        #: so its post-batch usage is exactly committed-gang placements —
-        #: each of which the commit path assumes (bind or reservation)
-        gang_units = self.gang.batch_groups(pods) \
-            if self.gang is not None else None
-        batch = PodBatchTensors(pods, self.mirror, self.terms,
-                                extra_mask=extra_mask,
-                                extra_group=extra_group,
-                                seq_base=self._seq_base)
-        self._seq_base += len(pods)
-        w = self.scorer.weights
-        batch.resource_weights[0] = w.get("LeastRequestedPriority", 1)
-        batch.resource_weights[1] = w.get("BalancedResourceAllocation", 1)
-        # gang batches skip the in-scan spread/topology tables — the
-        # gang kernel's trial/commit scan does not carry them; repair
-        # (with whole-gang demotion) validates affinity interactions,
-        # matching the pre-in-scan semantics. Soft credit tables DO ride
-        # gang batches (trial/committed accumulators in the gang carry —
-        # what lifted the soft_gang sub-batching), and nominated
-        # reservations ride both kernels as the same phantom overlay (a
-        # mixed batch's singletons must not steal a preemptor's freed
-        # space).
-        spread_sig = None
-        topo_cover = "fallback"
-        if gang_units is None:
-            spread_sig = self._assign_spread_groups(pods, batch)
-            topo_cover = self._assign_topology_terms(pods, batch, profiles)
-        soft_sig = self._assign_soft_terms(pods, batch)
-        spread_present = spread_sig is not None
-        soft_present = soft_sig is not None
-        self.phase_stats["term_prep_s"] += _time.perf_counter() - t_prep
-        if tr is not None:
-            tr.record("scheduler", "tensorize", t_tz, tr.now(),
-                      pods=len(pods))
-        nom_dev = self._nominated_device()
-        if nom_dev is not None:
-            # each pod's own nominated row, from the EXACT snapshot the
-            # reservation tensor was built from (pod.status and even the
-            # live map may lag) — subtraction and tensor can never desync
-            for i, pod in enumerate(pods):
-                row = self._nom_rows_by_key.get(pod.metadata.key())
-                if row is not None:
-                    batch.nom_row[i] = row
-        static = self.scorer.static_scores(pods, batch)
-        has_prio_ext = any(e.config.prioritize_verb for e in self.extenders)
-        # hysteresis: while host-computed static scores are in play, later
-        # launches refuse the chain up front instead of discarding work.
-        # In-scan spread/soft tables no longer force the flush: their
-        # running counts CHAIN as carried device state (gated below), so
-        # the old recompute-from-batch-start invalidation is gone
-        self._static_likely = static is not None or has_prio_ext
-        if has_prio_ext:
+        with self._stage("refresh"):
+            dirty = self.cache.update_snapshot(self.snapshot)
+            # volume predicates can NEVER ride a chain (PV reservations need
+            # committed state); affinity CAN — its stale mask (snapshot lacks
+            # the chain's uncommitted winners) is repaired post-kernel against
+            # stale_winners, the same overlay that validates same-batch winners
+            affinity_only = not self._has_filter_extenders() and all(
+                not (_pod_has_conflict_volumes(p) or _pod_has_pvc(p)
+                     or _pod_has_attach_volumes(p)) for p in pods)
+            chain_intact = chain_seq is not None and (
+                chain_seq() if callable(chain_seq)
+                else self.cache.mutation_seq == chain_seq)
+            chaining = (chain is not None
+                        and (chain.residual_free or chain.affinity_chainable)
+                        and DEFAULT_FEATURE_GATE.enabled(
+                            "SchedulerDeviceChaining")
+                        and chain_intact
+                        and not self._static_likely
+                        and self.mirror.device_ready()
+                        and affinity_only)
             if chaining:
-                return None  # host scores would lag the uncommitted chain
-            self._apply_prioritize_extenders(pods, batch, static)
-        elif static is not None:
-            if chaining:
-                return None
-            batch.set_static_scores(*static)
-        if chaining and (spread_present or soft_present) and \
-                not self._chain_carries(chain, batch, spread_sig, soft_sig):
-            # the predecessor's carried counts don't structurally match
-            # this batch's tables — relaunch sequentially from host truth
-            return None
-        if chaining and not self.mirror.device_ready():
-            return None  # tensorize grew the column axis; chain handle stale
-        if gang_units is None and self.class_scan:
-            # the incremental class-indexed scan: per-(template, score-row)
-            # masked-score rows in the carry, one column refresh per winner
-            # (kernels/batch.py _schedule_batch_classes). Spread groups,
-            # soft credits, and nominated reservations ride the carry /
-            # phantom overlay, so EVERY non-gang batch takes the fast path
-            batch.enable_class_scan()
-        if chaining:
-            node_cfg, usage = self.mirror.device_cfg(), chain.new_usage
-            self.chained_launches += 1
-        else:
-            node_cfg, usage = self.mirror.device_cfg_usage()
-        sharded = False
-        spec_stats = None
-        spec_inputs = None
-        if gang_units is not None:
-            from .kernels.gang import gang_schedule_batch
-            assign_d, scores_d, new_usage = gang_schedule_batch(
-                node_cfg, usage, batch.device(self.mirror.mesh),
-                self._gang_device_table(gang_units, batch), nom_dev)
-        elif batch._class_tables is not None \
-                and sharding_mod.use_shard_map(self.mirror.mesh,
-                                               self.mirror.t.capacity):
-            # the sharded drain's hot path: per-shard filter+score with a
-            # cross-shard argmax (kernels/batch.py schedule_batch_sharded)
-            # — bit-identical decisions to the single-device class scan
-            from .kernels.batch import schedule_batch_sharded
-            sharded = True
-            if self.sched_metrics is not None:
-                self.sched_metrics.sharded_batches.inc()
-            assign_d, scores_d, new_usage = schedule_batch_sharded(
-                self.mirror.mesh, node_cfg, usage,
-                batch.device(self.mirror.mesh), nom_dev)
-        elif self.speculative and batch._class_tables is not None:
-            # speculative cohort assignment (kernels/speculative.py):
-            # vmapped K-pod cohort proposals against the frozen class
-            # table, exact collision detection, serial whole-cohort
-            # repair — bit-identical decisions to the serial scan, with
-            # per-cohort stats folded into metrics by schedule_finish
-            from .kernels.speculative import (_SPEC_MIN_PLAIN,
-                                              cohort_width,
-                                              schedule_batch_speculative)
-            w = cohort_width(batch.req.shape[0])
-            batch.set_speculative(w)
-            # contention gate: a batch that is mostly non-plain trips
-            # the structural fence on (nearly) every cohort, so the
-            # election + exact collision checks are pure overhead —
-            # measured over the ACTIVE prefix (pads are trivially plain
-            # and would inflate the fraction)
-            frac = (float(batch.spec_plain[:len(pods)].mean())
-                    if pods else 0.0)
-            if frac < _SPEC_MIN_PLAIN:
-                batch.spec_plain = None
-                batch.cohort_id = None
-                assign_d, scores_d, new_usage = schedule_batch(
-                    node_cfg, usage, batch.device(self.mirror.mesh),
-                    nom_dev)
+                self.mirror.apply_chained(self.snapshot, dirty)
+                self.topology.apply(self.snapshot, dirty)
+                if dirty:
+                    # keep the scorer's gate fresh on the chained path too: if
+                    # this drain's own commits introduced score-contributing
+                    # carriers, static_scores below turns non-None and refuses
+                    # the chain — matching the sequential path's scoring
+                    self.scorer.set_cluster_has_affinity_pods(
+                        self.topology.has_score_carriers())
             else:
-                dev = batch.device(self.mirror.mesh)
-                assign_d, scores_d, new_usage, spec_stats = \
-                    schedule_batch_speculative(node_cfg, usage, dev,
-                                               nom_dev, width=w)
-                if self.spec_oracle:
-                    spec_inputs = (node_cfg, usage, dev, nom_dev)
-        else:
-            assign_d, scores_d, new_usage = schedule_batch(
-                node_cfg, usage, batch.device(self.mirror.mesh), nom_dev)
-        if self.sched_metrics is not None and self.mirror.mesh is not None:
-            # padding added for shard divisibility is VISIBLE (KTPU005):
-            # the gauge tracks the mirror's current shard-pad rows
-            self.sched_metrics.mirror_shard_pad_rows.set(
-                self.mirror.shard_pad_rows)
-        return PendingBatch(pods=pods, profiles=profiles, batch=batch,
-                            sharded=sharded,
-                            packed=pack_results(assign_d, scores_d),
-                            new_usage=new_usage,
-                            residual_free=residual_free,
-                            affinity_chainable=affinity_chainable,
-                            chained=chaining,
-                            usage_epoch=self.mirror.usage_epoch,
-                            gang_units=gang_units,
-                            spread_sig=spread_sig, soft_sig=soft_sig,
-                            spec_stats=spec_stats,
-                            spec_inputs=spec_inputs,
-                            inscan_cover=(affinity_chainable
-                                          and topo_cover != "fallback"))
+                # the dirty list is consumed either way — a chain refusal must
+                # still apply it, or the mirror would never see these updates
+                # (update_snapshot won't return them again)
+                self.mirror.apply(self.snapshot, dirty)
+                self.topology.apply(self.snapshot, dirty)
+                if dirty:
+                    self.scorer.set_cluster_has_affinity_pods(
+                        self.topology.has_score_carriers())
+                if chain is not None:
+                    return None
+        with self._stage("tensorize", pods=len(pods)):
+            extra_mask, profiles, extra_group = self._residual_mask(pods)
+            residual_free = extra_mask is None and not any(
+                helpers.pod_host_ports(p) or _pod_has_conflict_volumes(p)
+                for p in pods)
+            affinity_chainable = affinity_only and not any(
+                helpers.pod_host_ports(p) for p in pods)
+            #: gang units present -> the all-or-nothing kernel decides this
+            #: batch. Gang batches CHAIN like singleton batches: the kernel's
+            #: trial/commit carry isolates uncommitted (rejected-gang) state,
+            #: so its post-batch usage is exactly committed-gang placements —
+            #: each of which the commit path assumes (bind or reservation)
+            gang_units = self.gang.batch_groups(pods) \
+                if self.gang is not None else None
+            batch = PodBatchTensors(pods, self.mirror, self.terms,
+                                    extra_mask=extra_mask,
+                                    extra_group=extra_group,
+                                    seq_base=self._seq_base)
+            self._seq_base += len(pods)
+            w = self.scorer.weights
+            batch.resource_weights[0] = w.get("LeastRequestedPriority", 1)
+            batch.resource_weights[1] = w.get("BalancedResourceAllocation", 1)
+            # gang batches skip the in-scan spread/topology tables — the
+            # gang kernel's trial/commit scan does not carry them; repair
+            # (with whole-gang demotion) validates affinity interactions,
+            # matching the pre-in-scan semantics. Soft credit tables DO ride
+            # gang batches (trial/committed accumulators in the gang carry —
+            # what lifted the soft_gang sub-batching), and nominated
+            # reservations ride both kernels as the same phantom overlay (a
+            # mixed batch's singletons must not steal a preemptor's freed
+            # space).
+            spread_sig = None
+            topo_cover = "fallback"
+            if gang_units is None:
+                spread_sig = self._assign_spread_groups(pods, batch)
+                topo_cover = self._assign_topology_terms(pods, batch, profiles)
+            soft_sig = self._assign_soft_terms(pods, batch)
+            spread_present = spread_sig is not None
+            soft_present = soft_sig is not None
+        with self._stage("dispatch", pods=len(pods)):
+            nom_dev = self._nominated_device()
+            if nom_dev is not None:
+                # each pod's own nominated row, from the EXACT snapshot the
+                # reservation tensor was built from (pod.status and even the
+                # live map may lag) — subtraction and tensor can never desync
+                for i, pod in enumerate(pods):
+                    row = self._nom_rows_by_key.get(pod.metadata.key())
+                    if row is not None:
+                        batch.nom_row[i] = row
+            static = self.scorer.static_scores(pods, batch)
+            has_prio_ext = any(e.config.prioritize_verb for e in self.extenders)
+            # hysteresis: while host-computed static scores are in play, later
+            # launches refuse the chain up front instead of discarding work.
+            # In-scan spread/soft tables no longer force the flush: their
+            # running counts CHAIN as carried device state (gated below), so
+            # the old recompute-from-batch-start invalidation is gone
+            self._static_likely = static is not None or has_prio_ext
+            if has_prio_ext:
+                if chaining:
+                    return None  # host scores would lag the uncommitted chain
+                self._apply_prioritize_extenders(pods, batch, static)
+            elif static is not None:
+                if chaining:
+                    return None
+                batch.set_static_scores(*static)
+            if chaining and (spread_present or soft_present) and \
+                    not self._chain_carries(chain, batch, spread_sig, soft_sig):
+                # the predecessor's carried counts don't structurally match
+                # this batch's tables — relaunch sequentially from host truth
+                return None
+            if chaining and not self.mirror.device_ready():
+                # tensorize grew the column axis; chain handle stale
+                return None
+            if gang_units is None and self.class_scan:
+                # the incremental class-indexed scan: per-(template, score-row)
+                # masked-score rows in the carry, one column refresh per winner
+                # (kernels/batch.py _schedule_batch_classes). Spread groups,
+                # soft credits, and nominated reservations ride the carry /
+                # phantom overlay, so EVERY non-gang batch takes the fast path
+                batch.enable_class_scan()
+            if chaining:
+                node_cfg, usage = self.mirror.device_cfg(), chain.new_usage
+                self.chained_launches += 1
+            else:
+                node_cfg, usage = self.mirror.device_cfg_usage()
+            sharded = False
+            spec_stats = None
+            spec_inputs = None
+            if gang_units is not None:
+                from .kernels.gang import gang_schedule_batch
+                assign_d, scores_d, new_usage = gang_schedule_batch(
+                    node_cfg, usage, batch.device(self.mirror.mesh),
+                    self._gang_device_table(gang_units, batch), nom_dev)
+            elif batch._class_tables is not None \
+                    and sharding_mod.use_shard_map(self.mirror.mesh,
+                                                   self.mirror.t.capacity):
+                # the sharded drain's hot path: per-shard filter+score with a
+                # cross-shard argmax (kernels/batch.py schedule_batch_sharded)
+                # — bit-identical decisions to the single-device class scan
+                from .kernels.batch import schedule_batch_sharded
+                sharded = True
+                if self.sched_metrics is not None:
+                    self.sched_metrics.sharded_batches.inc()
+                assign_d, scores_d, new_usage = schedule_batch_sharded(
+                    self.mirror.mesh, node_cfg, usage,
+                    batch.device(self.mirror.mesh), nom_dev)
+            elif self.speculative and batch._class_tables is not None:
+                # speculative cohort assignment (kernels/speculative.py):
+                # vmapped K-pod cohort proposals against the frozen class
+                # table, exact collision detection, serial whole-cohort
+                # repair — bit-identical decisions to the serial scan, with
+                # per-cohort stats folded into metrics by schedule_finish
+                from .kernels.speculative import (_SPEC_MIN_PLAIN,
+                                                  cohort_width,
+                                                  schedule_batch_speculative)
+                w = cohort_width(batch.req.shape[0])
+                batch.set_speculative(w)
+                # contention gate: a batch that is mostly non-plain trips
+                # the structural fence on (nearly) every cohort, so the
+                # election + exact collision checks are pure overhead —
+                # measured over the ACTIVE prefix (pads are trivially plain
+                # and would inflate the fraction)
+                frac = (float(batch.spec_plain[:len(pods)].mean())
+                        if pods else 0.0)
+                if frac < _SPEC_MIN_PLAIN:
+                    batch.spec_plain = None
+                    batch.cohort_id = None
+                    assign_d, scores_d, new_usage = schedule_batch(
+                        node_cfg, usage, batch.device(self.mirror.mesh),
+                        nom_dev)
+                else:
+                    dev = batch.device(self.mirror.mesh)
+                    assign_d, scores_d, new_usage, spec_stats = \
+                        schedule_batch_speculative(node_cfg, usage, dev,
+                                                   nom_dev, width=w)
+                    if self.spec_oracle:
+                        spec_inputs = (node_cfg, usage, dev, nom_dev)
+            else:
+                assign_d, scores_d, new_usage = schedule_batch(
+                    node_cfg, usage, batch.device(self.mirror.mesh), nom_dev)
+            if self.sched_metrics is not None and self.mirror.mesh is not None:
+                # padding added for shard divisibility is VISIBLE (KTPU005):
+                # the gauge tracks the mirror's current shard-pad rows
+                self.sched_metrics.mirror_shard_pad_rows.set(
+                    self.mirror.shard_pad_rows)
+            return PendingBatch(pods=pods, profiles=profiles, batch=batch,
+                                sharded=sharded,
+                                packed=pack_results(assign_d, scores_d),
+                                new_usage=new_usage,
+                                residual_free=residual_free,
+                                affinity_chainable=affinity_chainable,
+                                chained=chaining,
+                                usage_epoch=self.mirror.usage_epoch,
+                                gang_units=gang_units,
+                                spread_sig=spread_sig, soft_sig=soft_sig,
+                                spec_stats=spec_stats,
+                                spec_inputs=spec_inputs,
+                                inscan_cover=(affinity_chainable
+                                              and topo_cover != "fallback"))
 
     def _chain_carries(self, chain: "PendingBatch", batch: PodBatchTensors,
                        spread_sig: Optional[Tuple],
@@ -1786,22 +1783,13 @@ class BatchScheduler:
 
     def schedule_finish(self, pending: "PendingBatch") -> List[ScheduleResult]:
         """Back half: fetch results, host repair, adopt chained usage."""
-        import time as _time
         from .kernels.batch import unpack_results
-        tr = self.tracer if self.tracer is not None \
-            and self.tracer.enabled else None
-        t_sw = tr.now() if tr is not None else 0.0
-        t0 = _time.perf_counter()
-        assign, scores = unpack_results(pending.packed)
-        fetch_wait = _time.perf_counter() - t0
-        self.phase_stats["scan_wait_s"] += fetch_wait
+        with self._stage("scan_wait", pods=len(pending.pods)) as scan_wait:
+            assign, scores = unpack_results(pending.packed)
         if pending.sharded and self.sched_metrics is not None:
             # the fetch drains the cross-shard argmax pipeline: this is
             # the wall time spent synchronizing the mesh for this batch
-            self.sched_metrics.shard_sync_seconds.observe(fetch_wait)
-        if tr is not None:
-            tr.record("scheduler", "scan_wait", t_sw, tr.now(),
-                      pods=len(pending.pods))
+            self.sched_metrics.shard_sync_seconds.observe(scan_wait.seconds)
         if pending.spec_stats is not None:
             self._account_speculative(pending, assign)
         out: List[ScheduleResult] = []
@@ -1817,23 +1805,24 @@ class BatchScheduler:
             for r in out:
                 if r.node_name is None:
                     r.retry = True
-        t1 = _time.perf_counter()
         moved = False
-        if not (pending.inscan_cover and not pending.stale_winners):
-            moved = self._repair_batch(
-                out, pending.profiles, pending.stale_winners,
-                # no serial reassignment for gang batches: the reassigner
-                # is blind to the gang's ICI-domain pin, so a "repaired"
-                # member could land outside the slice — demote-and-retry
-                # instead, and atomicity below demotes its gang with it
-                batch=None if pending.gang_units else pending.batch)
-        # else: the kernel's in-scan tables already enforced every
-        # in-batch (anti-)affinity interaction (both directions + waived
-        # co-location) and the batch carries no ports/volumes/extenders —
-        # the overlay walk would re-prove what the scan decided
-        self.phase_stats["repair_s"] += _time.perf_counter() - t1
-        if pending.gang_units:
-            self._enforce_gang_atomicity(out, pending.gang_units)
+        with self._stage("repair", pods=len(pending.pods)):
+            if not (pending.inscan_cover and not pending.stale_winners):
+                moved = self._repair_batch(
+                    out, pending.profiles, pending.stale_winners,
+                    # no serial reassignment for gang batches: the
+                    # reassigner is blind to the gang's ICI-domain pin, so
+                    # a "repaired" member could land outside the slice —
+                    # demote-and-retry instead, and atomicity below
+                    # demotes its gang with it
+                    batch=None if pending.gang_units else pending.batch)
+            # else: the kernel's in-scan tables already enforced every
+            # in-batch (anti-)affinity interaction (both directions +
+            # waived co-location) and the batch carries no ports/volumes/
+            # extenders — the overlay walk would re-prove what the scan
+            # decided
+            if pending.gang_units:
+                self._enforce_gang_atomicity(out, pending.gang_units)
         if moved and pending.batch.anti_dom is not None:
             # the in-scan (anti-)affinity counters counted a winner the
             # repair moved/demoted: pods the scan left unassigned may have

@@ -15,26 +15,55 @@ SCHEDULING_LATENCY_BUCKETS = (0.0005, 0.001, 0.002, 0.004, 0.008, 0.016,
                               2.048, 4.096, 8.192)
 
 
+#: every `operation` of scheduler_scheduling_duration_seconds, one per
+#: stage site of scheduler.py and core.py. Parents (their leaves nest
+#: inside them): algorithm and commit in the served cycle; launch, fetch
+#: and commit in the pipelined drain. Leaves, in the order a cycle meets
+#: them. The bind transaction has its own family,
+#: scheduler_binding_duration_seconds (the reference's BindingLatency):
+#: inside commit before assume where the bind is synchronous, on a binder
+#: thread beside the next cycle where it is not (an HTTP client), and
+#: then bind_backlog is the scheduling thread waiting for the oldest of
+#: max_inflight_binds transactions.
+STAGE_PARENTS = ("algorithm", "commit", "launch", "fetch")
+STAGE_LEAVES = ("pop_wait", "refresh", "tensorize", "dispatch",
+                "scan_wait", "repair", "assume", "bind_backlog")
+
+
 class SchedulerMetrics:
     def __init__(self, registry: Registry = None):
         self.registry = registry if registry is not None else Registry()
         r = self.registry
         # ref: SchedulingLatency histogram labeled by operation
         # {predicate_evaluation, priority_evaluation, binding, ...}; the
-        # batch analog is per-phase wall time per cycle
+        # batch analog is per-stage wall time per cycle. A cycle's self
+        # time is its e2e sum minus its leaves
         self.scheduling_duration = r.histogram(
             "scheduler_scheduling_duration_seconds",
-            "Scheduling phase latency per batch cycle, by operation",
+            "Scheduling stage latency per batch cycle, by operation",
             buckets=SCHEDULING_LATENCY_BUCKETS)
+        for op in STAGE_PARENTS + STAGE_LEAVES:
+            self.scheduling_duration.declare(operation=op)
         # ref: E2eSchedulingLatency — queue pop to bind committed
         self.e2e_scheduling_duration = r.histogram(
             "scheduler_e2e_scheduling_duration_seconds",
             "End-to-end batch latency from pop to binds committed",
             buckets=SCHEDULING_LATENCY_BUCKETS)
+        self.e2e_scheduling_duration.declare()
         self.binding_duration = r.histogram(
             "scheduler_binding_duration_seconds",
             "Bind transaction latency per batch",
             buckets=SCHEDULING_LATENCY_BUCKETS)
+        self.binding_duration.declare()
+        # what the popped pods waited in the queue, summed once per pop
+        # (queue clock at the pop - the pod's enqueue timestamp), and the
+        # pods it is divided by
+        self.queue_wait_seconds = r.counter(
+            "scheduler_queue_wait_seconds_total",
+            "Seconds popped pods had waited in the scheduling queue")
+        self.queue_popped_pods = r.counter(
+            "scheduler_queue_popped_pods_total",
+            "Pods popped from the scheduling queue")
         # pipelined drain: wall time the commit stage spent on the commit
         # thread — time the drain thread did NOT serialize on (it was
         # tensorizing/dispatching the next batch); the occupancy lens the
@@ -49,6 +78,8 @@ class SchedulerMetrics:
         self.schedule_attempts = r.counter(
             "scheduler_schedule_attempts_total",
             "Scheduling attempts by result")
+        for result in ("scheduled", "unschedulable", "error"):
+            self.schedule_attempts.declare(result=result)
         # ref: PreemptionAttempts / PreemptionVictims; family names use
         # the reference's POST-rename spelling (the originals predate
         # its metrics-naming linter — exactly the KTPU004 contract)
@@ -165,6 +196,16 @@ class SchedulerMetrics:
             "scheduler_speculative_divergences_total",
             "Pods whose speculative decision differed from the serial "
             "oracle replay (expected zero; bit-identity contract)")
+
+    def stage(self, tracer, name: str, ring: bool = True, **attrs):
+        """observability.SpanTracer.stage for one `operation` of the
+        cycle: a leaf also gets the trace annotation sched.<name>, a
+        parent none (it would cover the host time that its leaves leave
+        unexplained)."""
+        return tracer.stage(
+            name, self.scheduling_duration, labels={"operation": name},
+            trace="sched." + name if name in STAGE_LEAVES else None,
+            ring=ring, **attrs)
 
     def observe_queue(self, queue) -> None:
         """Sample the three sub-queue depths (PendingPods gauges)."""

@@ -174,11 +174,13 @@ class SchedulingQueue:
         self.gang = None
         #: observability hooks, installed by the scheduler shell: span
         #: tracer (admit/park/backoff/unschedulable pod milestones), the
-        #: per-pod last-failure attribution store, and the park-cause
-        #: tally counter (scheduler_unschedulable_reasons_total)
+        #: per-pod last-failure attribution store, the park-cause tally
+        #: counter (scheduler_unschedulable_reasons_total), and the
+        #: SchedulerMetrics whose queue-wait counters pop_batch feeds
         self.tracer = None
         self.attribution = None
         self.unsched_reasons = None
+        self.sched_metrics = None
         self.backoff_map = PodBackoffMap(clock)
         self.nominated = NominatedPodMap()
         self._scheduling_cycle = 0
@@ -349,6 +351,8 @@ class SchedulingQueue:
                 return []
             self._scheduling_cycle += 1
             out: List[Pod] = []
+            now = self._clock.now()
+            waited = 0.0  # what the popped pods waited here, summed
             while self._active and len(out) < max_pods:
                 entry = heapq.heappop(self._active)
                 key = entry[3]
@@ -401,6 +405,10 @@ class SchedulingQueue:
                 # from activeQ; in-flight pods live only in the cycle)
                 del self._pod_info[key]
                 out.append(info.pod)
+                waited += now - info.timestamp
+            if out and self.sched_metrics is not None:
+                self.sched_metrics.queue_wait_seconds.inc(waited)
+                self.sched_metrics.queue_popped_pods.inc(len(out))
             if on_pop is not None and out:
                 on_pop(len(out))
             return out

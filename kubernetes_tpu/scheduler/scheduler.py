@@ -76,10 +76,13 @@ class Scheduler:
         from .framework import Framework
         from .metrics import SchedulerMetrics
         self.metrics = metrics if metrics is not None else SchedulerMetrics()
-        # span tracer (observability/tracer.py): pod-lifecycle milestones
-        # sampled 1-in-N by UID, batch/stage spans always on; rides the
-        # scheduler's clock so FakeClock harnesses get deterministic span
-        # logs. Callers share one tracer across components by passing it.
+        # span tracer (observability/tracer.py): the stage timer of every
+        # boundary of the cycle (self._stage) and, while it is enabled,
+        # the flight recorder with pod-lifecycle milestones sampled 1-in-N
+        # by UID; rides the scheduler's clock so FakeClock harnesses get
+        # deterministic span logs. Callers share one tracer across
+        # components by passing it; the served process passes a disabled
+        # one (config.build_scheduler).
         from ..observability import SpanTracer
         self.tracer = tracer if tracer is not None else SpanTracer(clock=clock)
         self.client = client
@@ -182,7 +185,15 @@ class Scheduler:
         self._commit_settles = False
         self.cache = Cache(clock=clock)
         self.queue = SchedulingQueue(clock=clock)
-        self.informers = informer_factory or SharedInformerFactory(client)
+        if informer_factory is None:
+            from ..utils.metrics import InformerMetrics
+            try:  # the informers' families ride this scheduler's /metrics
+                im = InformerMetrics(self.metrics.registry)
+            except ValueError:
+                # a sibling scheduler shares this registry: keep our own
+                im = InformerMetrics()
+            informer_factory = SharedInformerFactory(client, metrics=im)
+        self.informers = informer_factory
         pvc_lister, pv_by_name, pv_all, sc_lister = self._volume_listers()
         from ..api.policy import PodDisruptionBudget
         from .volumebinder import VolumeBinder
@@ -335,6 +346,7 @@ class Scheduler:
         #: contributes park causes, the drain the explain() diagnosis
         self.attribution = UnschedulableAttribution(clock=clock)
         self.queue.tracer = self.tracer
+        self.queue.sched_metrics = self.metrics
         self.queue.attribution = self.attribution
         self.queue.unsched_reasons = self.metrics.unschedulable_reasons
         self.algorithm.tracer = self.tracer
@@ -642,9 +654,12 @@ class Scheduler:
         cycle = self.queue.scheduling_cycle
         def _mark_in_flight(n: int) -> None:
             self._in_flight = n
-        pods = self.queue.pop_batch(max_pods or self._drain_cap(),
-                                    timeout=timeout,
-                                    on_pop=_mark_in_flight)
+        cap = max_pods or self._drain_cap()
+        # under a run-loop thread the number of empty polls follows real
+        # time: not a span of the (same-seed identical) flight recorder
+        with self._stage("pop_wait", ring=False):
+            pods = self.queue.pop_batch(cap, timeout=timeout,
+                                        on_pop=_mark_in_flight)
         pods = self._skip_assumed(pods)
         if not pods:
             self._in_flight = 0
@@ -692,34 +707,28 @@ class Scheduler:
         a gang member fails its whole gang that way."""
         return self.cache.without_assumed(pods)
 
+    def _stage(self, name: str, **attrs):
+        """The timer of one boundary of the cycle (SchedulerMetrics.stage):
+        scheduler_scheduling_duration_seconds{operation=name}, for a leaf
+        the trace annotation sched.<name>, and the flight recorder's span
+        where a harness attached one."""
+        return self.metrics.stage(self.tracer, name, **attrs)
+
     def _schedule_batch_locked(self, pods: List[Pod], cycle: int
                                ) -> List[ScheduleResult]:
-        import time as _time
         from ..utils.trace import Trace
         trace = Trace("schedule_batch", pods=len(pods), cycle=cycle)
-        tr = self.tracer
-        ts0 = tr.now() if tr.enabled else 0.0
-        t0 = _time.perf_counter()
-        results = self.algorithm.schedule(pods)
+        with self._stage("algorithm", pods=len(pods), cycle=cycle) as algo:
+            results = self.algorithm.schedule(pods)
         trace.step("batch decided (tensorize + kernel + repair)")
-        ts1 = tr.now() if tr.enabled else 0.0
-        t1 = _time.perf_counter()
-        self._commit_results(results, cycle)
+        with self._stage("commit", pods=len(pods), cycle=cycle) as commit:
+            self._commit_results(results, cycle)
         trace.step("results committed (volumes + plugins + bind + assume)")
-        t2 = _time.perf_counter()
-        if tr.enabled:
-            ts2 = tr.now()
-            tr.record("scheduler", "algorithm", ts0, ts1,
-                      pods=len(pods), cycle=cycle)
-            tr.record("scheduler", "commit", ts1, ts2,
-                      pods=len(pods), cycle=cycle)
         # per-attempt step tracing, logged only when slow (ref: utiltrace
         # in generic_scheduler.go:185 with the same 100ms threshold)
         trace.log_if_long(100.0)
         m = self.metrics
-        m.scheduling_duration.observe(t1 - t0, operation="algorithm")
-        m.scheduling_duration.observe(t2 - t1, operation="commit")
-        m.e2e_scheduling_duration.observe(t2 - t0)
+        m.e2e_scheduling_duration.observe(algo.seconds + commit.seconds)
         m.batch_size.observe(len(pods))
         m.observe_queue(self.queue)
         return results
@@ -863,8 +872,10 @@ class Scheduler:
                 if carry:
                     pods, carry = carry, []
                 else:
-                    pods = self.queue.pop_batch(self._drain_cap(), timeout=0,
-                                                on_pop=_mark)
+                    cap = self._drain_cap()
+                    with self._stage("pop_wait", ring=False):
+                        pods = self.queue.pop_batch(cap, timeout=0,
+                                                    on_pop=_mark)
                     kept = self._skip_assumed(pods)
                     if len(kept) < len(pods):
                         _mark(len(kept) - len(pods))
@@ -922,31 +933,30 @@ class Scheduler:
                         # commit thread is still assuming into
                         commit_fut.result()
                         commit_fut = None
-                    tl0 = self.tracer.now() if self.tracer.enabled else 0.0
-                    if prev is not None:
-                        with self._algo_lock:
-                            pending = self.algorithm.schedule_launch(
-                                pods, chain=prev[0],
-                                chain_seq=self._chain_intact)
-                    if pending is None:
-                        # pipeline flush: settle every in-flight stage,
-                        # then relaunch sequentially from host truth
+                    with self._stage("launch", pods=len(pods),
+                                     cycle=cycle) as launch:
                         if prev is not None:
-                            commit_fut = self._finish_pipelined(
-                                prev[0], prev[1], commit_fut)
-                            prev = None
-                        if commit_fut is not None:
-                            commit_fut.result()
-                            commit_fut = None
-                        self._pipe_anchor()
-                        with self._algo_lock:
-                            pending = self.algorithm.schedule_launch(pods)
-                    if self.tracer.enabled:
-                        self.tracer.record(
-                            "scheduler", "launch", tl0, self.tracer.now(),
-                            pods=len(pods), cycle=cycle,
-                            chained=bool(pending is not None
-                                         and pending.chained))
+                            with self._algo_lock:
+                                pending = self.algorithm.schedule_launch(
+                                    pods, chain=prev[0],
+                                    chain_seq=self._chain_intact)
+                        if pending is None:
+                            # pipeline flush: settle every in-flight
+                            # stage, then relaunch sequentially from host
+                            # truth
+                            if prev is not None:
+                                commit_fut = self._finish_pipelined(
+                                    prev[0], prev[1], commit_fut)
+                                prev = None
+                            if commit_fut is not None:
+                                commit_fut.result()
+                                commit_fut = None
+                            self._pipe_anchor()
+                            with self._algo_lock:
+                                pending = self.algorithm.schedule_launch(
+                                    pods)
+                        launch.attrs["chained"] = bool(
+                            pending is not None and pending.chained)
                 if prev is not None:
                     commit_fut = self._finish_pipelined(prev[0], prev[1],
                                                         commit_fut)
@@ -970,7 +980,6 @@ class Scheduler:
         results to the commit stage (returns the new commit future). The
         PREDECESSOR's commit is joined first: this batch's repair
         validates against its final winners and losses."""
-        import time as _time
         # commit thread -> drain signal: a stage still running when its
         # successor's scan finished means the hub side is the bottleneck
         # — the adaptive cap halves the next bulk batch until it catches
@@ -993,15 +1002,9 @@ class Scheduler:
                 # drop device usage so the next launch re-uploads host
                 # truth (and this batch's own adopt is epoch-refused)
                 self.algorithm.mirror.invalidate_usage()
-        tf0 = self.tracer.now() if self.tracer.enabled else 0.0
-        t0 = _time.perf_counter()
-        with self._algo_lock:
+        with self._stage("fetch", pods=len(pending.pods),
+                         cycle=cycle) as fetch, self._algo_lock:
             results = self.algorithm.schedule_finish(pending)
-        t1 = _time.perf_counter()
-        self.metrics.scheduling_duration.observe(t1 - t0, operation="fetch")
-        if self.tracer.enabled:
-            self.tracer.record("scheduler", "fetch", tf0, self.tracer.now(),
-                               pods=len(pending.pods), cycle=cycle)
         if any(r.retry for r in results):
             # losers the chained usage already counted: in-flight chained
             # successors must retry their unassigned pods, not park them
@@ -1013,8 +1016,8 @@ class Scheduler:
             r.node_name is None for r in results)
         if self._commit_overlaps():
             return self._commit_pool.submit(self._commit_stage, results,
-                                            cycle, t0)
-        self._commit_stage(results, cycle, t0)
+                                            cycle, fetch.start)
+        self._commit_stage(results, cycle, fetch.start)
         return None
 
     def _commit_stage(self, results: List[ScheduleResult], cycle: int,
@@ -1025,25 +1028,20 @@ class Scheduler:
         invalidates chained device usage; the epoch bump is folded into
         the pipeline's phantom flag so in-flight chained batches retry
         their unassigned pods. Returns the number of assumes."""
-        import time as _time
         epoch_before = self.algorithm.mirror.usage_epoch
-        tc0 = self.tracer.now() if self.tracer.enabled else 0.0
-        t1 = _time.perf_counter()
+        commit = self._stage("commit", pods=len(results), cycle=cycle)
         try:
-            return self._commit_results(results, cycle)
+            with commit:
+                return self._commit_results(results, cycle)
         finally:
             if self.algorithm.mirror.usage_epoch != epoch_before:
                 self._pipe_phantom = True
                 self.robustness.commit_rollbacks.inc()
-            t2 = _time.perf_counter()
             m = self.metrics
-            m.scheduling_duration.observe(t2 - t1, operation="commit")
-            m.commit_overlap_duration.observe(t2 - t1)
-            m.e2e_scheduling_duration.observe(t2 - t_start)
-            if self.tracer.enabled:
-                self.tracer.record("scheduler", "commit", tc0,
-                                   self.tracer.now(), pods=len(results),
-                                   cycle=cycle)
+            m.commit_overlap_duration.observe(commit.seconds)
+            # e2e of a pipelined batch: its fetch's start -> its commit's end
+            m.e2e_scheduling_duration.observe(
+                commit.start + commit.seconds - t_start)
             with self._count_lock:
                 self._in_flight -= len(results)
 
@@ -1057,7 +1055,6 @@ class Scheduler:
         The reference assumes *before* its async bind goroutine so the next
         scheduleOne sees the pod; here bind is synchronous within the same
         cycle, so assume-after-bind exposes the same states to observers."""
-        from ..state.store import ConflictError, NotFoundError
         from .framework import PluginContext, Status
         fresh: List[ScheduleResult] = []
         for res in bound:
@@ -1175,10 +1172,8 @@ class Scheduler:
                 continue
             fresh.append(res)
         bound = fresh
-        import time as _time
         if self._async_bind and self._bind_pool is not None:
             return self._assume_then_bind_async(bound)
-        t_bind = _time.perf_counter()
         if self._bind_extender is not None:
             # extender-managed binding (ref: scheduler.go:411 GetBinder):
             # the extender performs the API write; the local clone feeds
@@ -1188,19 +1183,27 @@ class Scheduler:
             # no confirmation ever arrives and the assumed usage expires on
             # the cache TTL, the reference's self-heal for lost binds
             outs = []
-            for res in bound:
-                try:
-                    self._bind_extender.bind(res.pod, res.node_name)
-                    clone = serde.deepcopy_obj(res.pod)
-                    clone.spec.node_name = res.node_name
-                    outs.append(clone)
-                except Exception as e:
-                    outs.append(e)
+            with self._bind_stage(len(bound)):
+                for res in bound:
+                    try:
+                        self._bind_extender.bind(res.pod, res.node_name)
+                        clone = serde.deepcopy_obj(res.pod)
+                        clone.spec.node_name = res.node_name
+                        outs.append(clone)
+                    except Exception as e:
+                        outs.append(e)
         else:
             outs = self._bind_items_with_retry(
                 [(res.pod.metadata.namespace, res.pod.metadata.name,
                   res.node_name) for res in bound])
-        self.metrics.binding_duration.observe(_time.perf_counter() - t_bind)
+        with self._stage("assume", pods=len(bound)):
+            return self._assume_bound(bound, outs)
+
+    def _assume_bound(self, bound: List[ScheduleResult], outs: list) -> int:
+        """The cache's half of a synchronous bind: assume every pod the
+        hub bound, requeue or drop every pod it refused. Returns the
+        number of assumes."""
+        from ..state.store import ConflictError, NotFoundError
         nom_live = bool(self.queue.nominated.by_node())
         n_assumed = 0
         for res, out in zip(bound, outs):
@@ -1279,44 +1282,41 @@ class Scheduler:
         """Assume local clones NOW (the batch analog of scheduler.go:382's
         assume-releases-the-loop), ship the bulk bind from the binder
         thread. Returns the number of assumes (chain bookkeeping)."""
-        import time as _time
         n_assumed = 0
         nom_live = bool(self.queue.nominated.by_node())
         pairs = []  # (result, assumed clone)
-        for res in bound:
-            out = serde.shallow_bind_clone(res.pod)
-            out.spec.node_name = res.node_name
-            if nom_live:
-                self.queue.nominated.delete(out)
-            try:
-                self._tracked_assume(out)
-                n_assumed += 1
-            except ValueError:
-                if self.cache.assigned_node(
-                        out.metadata.key()) == res.node_name:
-                    pass  # already counted once on the right node
-                else:
-                    self.algorithm.mirror.invalidate_usage()
-                    continue
-            pairs.append((res, out))
-            self.drf.charge(out)
-            with self._count_lock:
-                self.scheduled_count += 1
-            self.metrics.schedule_attempts.inc(result="scheduled")
-            self.tracer.pod_event("scheduler", "bound", out,
-                                  node=res.node_name)
-            self.attribution.discard(out.metadata.key())
+        with self._stage("assume", pods=len(bound)):
+            for res in bound:
+                out = serde.shallow_bind_clone(res.pod)
+                out.spec.node_name = res.node_name
+                if nom_live:
+                    self.queue.nominated.delete(out)
+                try:
+                    self._tracked_assume(out)
+                    n_assumed += 1
+                except ValueError:
+                    if self.cache.assigned_node(
+                            out.metadata.key()) == res.node_name:
+                        pass  # already counted once on the right node
+                    else:
+                        self.algorithm.mirror.invalidate_usage()
+                        continue
+                pairs.append((res, out))
+                self.drf.charge(out)
+                with self._count_lock:
+                    self.scheduled_count += 1
+                self.metrics.schedule_attempts.inc(result="scheduled")
+                self.tracer.pod_event("scheduler", "bound", out,
+                                      node=res.node_name)
+                self.attribution.discard(out.metadata.key())
         if not pairs:
             return n_assumed
         items = [(res.pod.metadata.namespace, res.pod.metadata.name,
                   res.node_name) for res, _ in pairs]
 
         def job():
-            t0 = _time.perf_counter()
             try:
                 outs = self._bind_items_with_retry(items)
-                self.metrics.binding_duration.observe(
-                    _time.perf_counter() - t0)
                 self._reconcile_bind_outcomes(pairs, outs)
             finally:
                 with self._count_lock:
@@ -1327,12 +1327,16 @@ class Scheduler:
         # becomes the drain's pacing (and _backpressure's shrink signal)
         self._bind_futures = [f for f in self._bind_futures
                               if not f.done()]
-        while len(self._bind_futures) >= self.max_inflight_binds:
-            oldest = self._bind_futures.pop(0)
-            try:
-                oldest.result()
-            except Exception:
-                pass
+        if len(self._bind_futures) >= self.max_inflight_binds:
+            # the scheduling thread blocked on the hub's bind backlog
+            # (how often follows binder-thread timing: not in the ring)
+            with self._stage("bind_backlog", ring=False):
+                while len(self._bind_futures) >= self.max_inflight_binds:
+                    oldest = self._bind_futures.pop(0)
+                    try:
+                        oldest.result()
+                    except Exception:
+                        pass
         with self._count_lock:
             self._binds_inflight += 1
         self._bind_futures.append(self._bind_pool.submit(job))
@@ -1350,13 +1354,16 @@ class Scheduler:
         returns the error in every slot; the caller's forget/requeue
         machinery self-heals exactly as for any failed bind."""
         from ..utils import backoff
-        tb0 = self.tracer.now() if self.tracer.enabled else 0.0
-        try:
+        with self._bind_stage(len(items)):
             return self._bind_items_inner(items, backoff)
-        finally:
-            if self.tracer.enabled:
-                self.tracer.record("scheduler", "bind_txn", tb0,
-                                   self.tracer.now(), pods=len(items))
+
+    def _bind_stage(self, pods: int):
+        """A thread blocked on the hub's bind (the scheduling thread
+        where the bind is synchronous, a binder thread over HTTP): the
+        reference's BindingLatency, scheduler_binding_duration_seconds
+        (no operation label), and sched.bind_txn on the trace."""
+        return self.tracer.stage("bind_txn", self.metrics.binding_duration,
+                                 trace="sched.bind_txn", pods=pods)
 
     def _bind_items_inner(self, items, backoff) -> list:
         pc = self.client.pods()

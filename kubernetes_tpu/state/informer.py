@@ -16,6 +16,7 @@ import time
 from collections import defaultdict
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple, Type
 
+from ..observability.tracer import NULL_TRACER
 from ..utils.backoff import BackoffPolicy
 from ..utils.clock import Clock, REAL_CLOCK
 from ..utils.metrics import InformerMetrics
@@ -139,6 +140,7 @@ class SharedInformer:
         self._rc = rc
         self._resource = getattr(rc, "_resource", "")
         self.metrics = metrics if metrics is not None else InformerMetrics()
+        self.metrics.deliver_seconds.declare(resource=self._resource)
         self.indexer = Indexer(index_funcs)
         self._handlers: List[EventHandlers] = []
         self._lock = threading.Lock()
@@ -370,8 +372,24 @@ class SharedInformer:
                 break
             if self._stop.is_set():
                 return None
-            if self._process_event(ev):
-                delivered += 1
+            # one delivery: this event and every event already queued
+            # behind it (a coalesced bind frame arrives as a run of
+            # them), from its read to the last handler's return. This
+            # thread shares the interpreter lock with the scheduling cycle
+            with NULL_TRACER.stage("deliver", self.metrics.deliver_seconds,
+                                   labels={"resource": self._resource},
+                                   trace="informer.deliver"):
+                while True:
+                    if self._process_event(ev):
+                        delivered += 1
+                    try:
+                        ev = watch.events.get_nowait()
+                    except queue_mod.Empty:
+                        break
+                    if ev is None or self._stop.is_set():
+                        break
+            if ev is None:
+                break
         if self._stop.is_set():
             return None
         self.metrics.watch_staleness.set(0.0, resource=self._resource)

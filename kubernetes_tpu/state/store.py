@@ -28,12 +28,15 @@ from __future__ import annotations
 
 import queue
 import os
+import sys
 import threading
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from ..api import serde
+from ..observability.tracer import NULL_TRACER
 
 ADDED = "ADDED"
 MODIFIED = "MODIFIED"
@@ -130,8 +133,9 @@ class Store:
         self._next_watch_id = 0
         self._uid_counter = 0
         self._wal = None
-        #: RobustnessMetrics (optional): WAL append-error and replay
-        #: recovery accounting ride the owner's registry
+        #: utils.metrics.StoreMetrics (optional; RobustnessMetrics is
+        #: one): journal losses and recoveries, lock wait and compaction
+        #: ride the owner's registry
         self.metrics = metrics
         #: the last replay's accounting (state/wal.WalRecovery), None
         #: until a WAL-backed store has replayed at least once
@@ -261,12 +265,33 @@ class Store:
         if self._wal is not None:
             self._wal.drain()
 
+    @contextmanager
+    def _write_lock(self):
+        """self._lock for one bulk write transaction, the wait for it
+        timed (store_lock_wait_seconds) once per outermost acquisition:
+        a thread that already holds the lock waits for nothing."""
+        if self.metrics is None or self._lock._is_owned():
+            with self._lock:
+                yield
+            return
+        with NULL_TRACER.stage("lock_wait", self.metrics.store_lock_wait):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     def compact(self) -> None:
-        """Rewrite the log as one PUT per live object (snapshot analog)."""
+        """Rewrite the log as one PUT per live object (snapshot analog).
+        Every write waits it out: its seconds, objects and bytes go to
+        stderr, an operator's first question after a stall."""
         if self._wal is None:
             return
         from .wal import WalWriter
-        with self._lock:
+        hist = self.metrics.store_compaction \
+            if self.metrics is not None else None
+        with self._write_lock(), \
+                NULL_TRACER.stage("compaction", hist) as compaction:
             path = self._wal.path
             sync = self._wal.sync
             self._wal.close()
@@ -290,6 +315,11 @@ class Store:
             self._wal = WalWriter(path, sync=sync, deferred=not sync,
                                   encoder=serde.encode_cached,
                                   metrics=self.metrics)
+            live = sum(len(bucket) for bucket in self._data.values())
+            size = os.path.getsize(path)
+        print(f"store: compacted the WAL in {compaction.seconds:.3f}s "
+              f"under the lock: {live} live objects, {size} bytes",
+              file=sys.stderr, flush=True)
 
     def close(self) -> None:
         with self._lock:
@@ -399,7 +429,7 @@ class Store:
         independent creates."""
         out: List[Any] = []
         events: List[WatchEvent] = []
-        with self._lock:
+        with self._write_lock():
             for obj in objs:
                 try:
                     stored = self._create_locked(resource, obj)
@@ -516,7 +546,7 @@ class Store:
         #: "BINDS" WAL record — one encode + one append per bind batch
         #: instead of one per pod (each entry carries its own rv for replay)
         slim_batch: List[Any] = []
-        with self._lock:
+        with self._write_lock():
             bucket = self._data.setdefault(resource, {})
             for namespace, name, mutate in items:
                 key = (namespace, name)

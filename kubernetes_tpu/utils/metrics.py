@@ -79,6 +79,15 @@ class Counter(_Metric):
     def __init__(self, name: str, help_text: str = ""):
         super().__init__(name, help_text)
         self._values: Dict[Tuple, float] = {}
+        self._declared: set = set()
+
+    def declare(self, **labels) -> None:
+        """Render this labelled series at 0 from now on, clear()
+        included (Histogram.declare says why)."""
+        key = _label_key(labels)
+        with self._lock:
+            self._declared.add(key)
+            self._values.setdefault(key, 0.0)
 
     def inc(self, n: float = 1.0, **labels) -> None:
         key = _label_key(labels)
@@ -91,7 +100,7 @@ class Counter(_Metric):
 
     def clear(self) -> None:
         with self._lock:
-            self._values.clear()
+            self._values = dict.fromkeys(self._declared, 0.0)
 
     def snapshot(self) -> Dict[Tuple, float]:
         """Label key -> value copy (the aggregator's merge input)."""
@@ -165,13 +174,26 @@ class Histogram(_Metric):
         self.buckets = tuple(buckets)
         # label key -> (bucket counts, sum, count)
         self._series: Dict[Tuple, list] = {}
+        self._declared: set = set()
+
+    def declare(self, **labels) -> None:
+        """Render this series at 0 from now on, clear() included: a
+        labelled series otherwise appears with its first observation,
+        and a scrape that brackets an interval needs it at both edges."""
+        key = _label_key(labels)
+        with self._lock:
+            self._declared.add(key)
+            self._series.setdefault(key, self._zero())
+
+    def _zero(self) -> list:
+        return [[0] * (len(self.buckets) + 1), 0.0, 0]
 
     def observe(self, v: float, **labels) -> None:
         key = _label_key(labels)
         with self._lock:
             s = self._series.get(key)
             if s is None:
-                s = [[0] * (len(self.buckets) + 1), 0.0, 0]
+                s = self._zero()
                 self._series[key] = s
             for i, b in enumerate(self.buckets):
                 if v <= b:
@@ -194,7 +216,7 @@ class Histogram(_Metric):
 
     def clear(self) -> None:
         with self._lock:
-            self._series.clear()
+            self._series = {key: self._zero() for key in self._declared}
 
     def quantile(self, q: float, **labels) -> float:
         """Approximate quantile with linear interpolation inside the
@@ -316,17 +338,66 @@ class InformerMetrics:
         self.repoints = r.counter(
             "informer_repoints_total",
             "Informer upstreams swapped by repoint(), by resource")
+        #: one delivery: a watch event read off the stream, and every
+        #: event already queued behind it, applied to the indexer and
+        #: handed to every handler. Declared at 0 per informer
+        self.deliver_seconds = r.histogram(
+            "informer_deliver_seconds",
+            "Watch event read to its handlers' return, per run of "
+            "queued events, by resource",
+            buckets=WIRE_CODEC_BUCKETS)
 
 
-class RobustnessMetrics:
-    """Failure-handling metric families: retried/abandoned API writes
-    (utils/backoff.retry), gang-atomic evictions (nodelifecycle), and
-    chaos-injected faults (chaos/injector). Registered into the caller's
-    registry so they ride the same /metrics exposition as the component
-    that owns them."""
+class StoreMetrics:
+    """What a state.Store reports of itself: the journal's losses and
+    recoveries, the wait for its lock and its compactions. The served
+    hub mounts one on its /metrics (cmd/kube_apiserver)."""
 
     def __init__(self, registry: Optional["Registry"] = None):
         self.registry = registry if registry is not None else Registry()
+        r = self.registry
+        #: records the deferred WAL worker could NOT write — silent data
+        #: loss at the next replay unless someone is watching this
+        self.wal_append_errors = r.counter(
+            "wal_append_errors_total",
+            "WAL records dropped by a failed append on the writer worker")
+        #: torn/corrupt-tail recovery accounting, accumulated across every
+        #: replay (store open + restart) this process performed
+        self.wal_recovery_records_replayed = r.counter(
+            "wal_recovery_records_replayed_total",
+            "Verified WAL records replayed across store opens/restarts")
+        self.wal_recovery_records_dropped = r.counter(
+            "wal_recovery_records_dropped_total",
+            "Complete-but-corrupt WAL records discarded at replay "
+            "(CRC mismatch or unparseable body)")
+        self.wal_recovery_truncated_bytes = r.counter(
+            "wal_recovery_truncated_bytes_total",
+            "Bytes cut off the journal tail by truncate-on-open")
+        #: asking for Store._lock -> holding it, once per outermost
+        #: acquisition of a bulk write path (create_bulk, bulk_apply,
+        #: compact): one observation per transaction, never per object
+        self.store_lock_wait = r.histogram(
+            "store_lock_wait_seconds",
+            "Wait for the store lock per bulk write transaction",
+            buckets=WIRE_CODEC_BUCKETS)
+        self.store_lock_wait.declare()
+        #: Store.compact() with the lock held: every write waits it out
+        self.store_compaction = r.histogram(
+            "store_compaction_seconds",
+            "WAL compaction (rewrite of every live object) under the "
+            "store lock")
+        self.store_compaction.declare()
+
+
+class RobustnessMetrics(StoreMetrics):
+    """Failure-handling metric families: retried/abandoned API writes
+    (utils/backoff.retry), gang-atomic evictions (nodelifecycle), and
+    chaos-injected faults (chaos/injector), beside the store's own.
+    Registered into the caller's registry so they ride the same /metrics
+    exposition as the component that owns them."""
+
+    def __init__(self, registry: Optional["Registry"] = None):
+        super().__init__(registry)
         r = self.registry
         #: transient API-write failures retried with backoff, by
         #: component/op — what the bare `except: pass` blocks used to hide
@@ -358,23 +429,6 @@ class RobustnessMetrics:
             "scheduler_pipelined_commit_rollbacks_total",
             "Pipelined commit stages that lost winners and invalidated "
             "chained device usage")
-        #: records the deferred WAL worker could NOT write — silent data
-        #: loss at the next replay unless someone is watching this
-        self.wal_append_errors = r.counter(
-            "wal_append_errors_total",
-            "WAL records dropped by a failed append on the writer worker")
-        #: torn/corrupt-tail recovery accounting, accumulated across every
-        #: replay (store open + restart) this process performed
-        self.wal_recovery_records_replayed = r.counter(
-            "wal_recovery_records_replayed_total",
-            "Verified WAL records replayed across store opens/restarts")
-        self.wal_recovery_records_dropped = r.counter(
-            "wal_recovery_records_dropped_total",
-            "Complete-but-corrupt WAL records discarded at replay "
-            "(CRC mismatch or unparseable body)")
-        self.wal_recovery_truncated_bytes = r.counter(
-            "wal_recovery_truncated_bytes_total",
-            "Bytes cut off the journal tail by truncate-on-open")
         #: leadership changes (a fresh acquire by a non-holder), by
         #: election name — the reference's leader_election_master_status
         #: flaps collapsed to a transition counter
@@ -492,6 +546,10 @@ class APIServerMetrics:
         self.request_duration = r.histogram(
             "apiserver_request_duration_seconds",
             "Request latency for non-watch requests, by verb")
+        # the scheduling path's two writes, at 0 from the start: a scrape
+        # that brackets an interval needs the series at both edges
+        for resource in ("pods", "bindings"):
+            self.request_duration.declare(verb="POST", resource=resource)
         #: currently-open watch streams (the long-running exemption's
         #: population — what the inflight limits deliberately don't cap)
         self.watch_streams = r.gauge(
@@ -519,6 +577,12 @@ class APIServerMetrics:
             "apiserver_wire_encode_seconds",
             "Payload encode latency, by encoding",
             buckets=WIRE_CODEC_BUCKETS)
+        self.wire_encode_seconds.declare(encoding="json")
+        #: pods carried by successful bind transactions: the hub's own
+        #: per-pod denominator (watch_events counts a pod once a watcher)
+        self.pods_bound = r.counter(
+            "apiserver_pods_bound_total",
+            "Pods bound by bind requests that the store accepted")
         #: watch frames served from the per-(event, encoding) byte cache
         #: instead of re-serializing per registered watcher
         self.watch_frame_cache_hits = r.counter(

@@ -11,6 +11,7 @@ import http.client
 import json
 import threading
 import time
+import urllib.error
 import urllib.request
 
 import pytest
@@ -488,11 +489,21 @@ class TestMaxInflight:
             # the read pool is untouched: a plain GET still succeeds
             assert client.nodes().list() == []
             # watch=false is NOT a watch: it must go through the pool
-            # (and succeed here, since the pool is idle)
-            with urllib.request.urlopen(
-                    f"{srv.address}/api/v1/nodes?watch=false",
-                    timeout=5) as resp:
-                assert resp.status == 200
+            # (and succeed here, since the pool is idle — once the LIST
+            # above has given its seat back, which its handler does after
+            # the client already holds the response)
+            deadline = time.monotonic() + 5
+            while True:
+                try:
+                    with urllib.request.urlopen(
+                            f"{srv.address}/api/v1/nodes?watch=false",
+                            timeout=5) as resp:
+                        assert resp.status == 200
+                    break
+                except urllib.error.HTTPError as e:
+                    if e.code != 429 or time.monotonic() > deadline:
+                        raise
+                    time.sleep(0.01)
             for w in watches:
                 w.stop()
         finally:
