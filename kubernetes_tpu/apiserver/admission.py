@@ -15,6 +15,7 @@ object-count evaluator (count/{resource} for everything else).
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional
 
 from ..api.core import LimitRange, Pod, ResourceQuota
@@ -265,8 +266,12 @@ class WebhookDispatcher:
     follows its failurePolicy: Fail denies the request (the v1 default),
     Ignore skips the webhook."""
 
-    def __init__(self, client):
+    def __init__(self, client, around_call=nullcontext):
         self.client = client
+        #: context manager factory around each remote round trip: the
+        #: apiserver passes its create gate's `released`, so a slow
+        #: webhook never holds the single-writer section
+        self._around_call = around_call
 
     # ---- mutating (returns the possibly-patched object)
 
@@ -360,9 +365,10 @@ class WebhookDispatcher:
                 data=_json.dumps(review).encode(),
                 headers={"Content-Type": "application/json"},
                 method="POST")
-            with urlrequest.urlopen(
+            with self._around_call(), urlrequest.urlopen(
                     req, timeout=max(1, wh.timeout_seconds)) as r:
-                body = _json.loads(r.read())
+                body = r.read()
+            body = _json.loads(body)
             resp = body.get("response")
             if not isinstance(resp, dict):
                 # a 200 without a usable response is a BROKEN webhook, not

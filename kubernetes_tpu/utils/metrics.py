@@ -583,6 +583,18 @@ class APIServerMetrics:
         self.pods_bound = r.counter(
             "apiserver_pods_bound_total",
             "Pods bound by bind requests that the store accepted")
+        #: the create path's single-writer section (server._CreateGate):
+        #: asked -> entered, one observation a create request, and the
+        #: requests that found it taken; contended / count is how often
+        #: the section engaged. Both at 0 from the start.
+        self.create_gate_wait = r.histogram(
+            "apiserver_create_gate_wait_seconds",
+            "Wait of a create request for the single-writer section")
+        self.create_gate_wait.declare()
+        self.create_gate_contended = r.counter(
+            "apiserver_create_gate_contended_total",
+            "Create requests that found the single-writer section taken")
+        self.create_gate_contended.declare()
         #: watch frames served from the per-(event, encoding) byte cache
         #: instead of re-serializing per registered watcher
         self.watch_frame_cache_hits = r.counter(
