@@ -3,12 +3,20 @@ fake nodes, created in an order drawn from the seed, and an endless
 stream of pods drawn from the pod mix. One variant = one file under
 benchmarks/variants/, found by name. Every seed gives the same nodes and
 the same kinds of pods, in another order: the seed never changes the
-amount of work."""
+amount of work.
+
+What else a configuration's file may name, each a file found by that
+name and each with what stood here before as the default: its node
+(`"node_variant"`: benchmarks/nodes/<name>.py) and the module that
+decides `correct` for it (`"reference"`: benchmarks/references/<name>.py).
+"""
 
 import importlib.util
 import json
 import os
 import random
+
+from . import reference
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_DIR = os.path.dirname(HERE)
@@ -25,6 +33,11 @@ def load_module(path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_named(folder, name):
+    """benchmarks/<folder>/<name>.py, the file a configuration names."""
+    return load_module(os.path.join(BENCH_DIR, folder, f"{name}.py"))
 
 
 def load_json(*parts):
@@ -45,19 +58,38 @@ def load_cell(workload):
             load_json(BENCH_DIR, "traffic", f"{cell['traffic']}.json"))
 
 
-def make_nodes(config, n_nodes, seed):
+def load_reference(config):
+    """The module that decides `correct` for this configuration (its
+    contract: harness/reference.py)."""
+    if "reference" not in config:
+        return reference
+    return load_named("references", config["reference"])
+
+
+def plain_node(i, config):
+    """scheduler_perf's fake node: one shape, allocatable = capacity, a
+    hostname and a zone label, no taint."""
     shape = config["node"]
     alloc = {"cpu": shape["cpu"], "memory": shape["memory"],
              "pods": str(shape["pods"])}
-    order = list(range(n_nodes))
-    random.Random(seed ^ 0x0DE5).shuffle(order)
-    return [{
+    return {
         "apiVersion": "v1", "kind": "Node",
         "metadata": {"name": f"node-{i}", "labels": {
             HOSTNAME: f"node-{i}", ZONE: f"zone-{i % shape['zones']}"}},
         "status": {"capacity": dict(alloc), "allocatable": dict(alloc),
                    "conditions": [{"type": "Ready", "status": "True"}]},
-    } for i in order]
+    }
+
+
+def make_nodes(config, n_nodes, seed):
+    """node-0 ... in an order drawn from the seed, each built by the
+    configuration's `node_variant` (`build(i, config) -> manifest`)."""
+    build = plain_node
+    if "node_variant" in config:
+        build = load_named("nodes", config["node_variant"]).build
+    order = list(range(n_nodes))
+    random.Random(seed ^ 0x0DE5).shuffle(order)
+    return [build(i, config) for i in order]
 
 
 class PodStream:
@@ -71,8 +103,8 @@ class PodStream:
         self._rng = random.Random(seed)
         self._config = dict(config, seed=seed)
         mix = config["pod_mix"]
-        self._builders = [load_module(os.path.join(
-            BENCH_DIR, "variants", f"{m['variant']}.py")).build for m in mix]
+        self._builders = [load_named("variants", m["variant"]).build
+                          for m in mix]
         self._shares = [float(m["share"]) for m in mix]
         self._next = 0
 

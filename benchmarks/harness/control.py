@@ -1,7 +1,8 @@
-"""The control of `correct`: the plain reference put in the program's
-place and computed below the precision the configurations state, its
-binds judged by the very compare() and correct() of harness/verdict.py
-that judge a measured run. It has to come out as not correct.
+"""The control of `correct`: the configuration's plain reference put in
+the program's place and computed below the precision the configurations
+state, its binds judged by the very compare() and correct() of
+harness/verdict.py that judge a measured run. It has to come out as not
+correct.
 
 The configurations state upstream's arithmetic (int64 floors, float64
 fractions). Down the ladder from there, on the source's one pod shape:
@@ -28,7 +29,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_DIR = os.path.dirname(HERE)
 sys.path.insert(0, BENCH_DIR)
 
-from harness import cluster, reference, verdict  # noqa: E402
+from harness import cluster, verdict  # noqa: E402
 
 
 def run_control(config, seed, n_pods, precision, n_nodes=None):
@@ -36,12 +37,14 @@ def run_control(config, seed, n_pods, precision, n_nodes=None):
     said). What a run hands compare() is made here from the control's own
     decisions: each pod acknowledged in order, listed with its node and a
     PodScheduled condition, seen once on the watch; no process to fail."""
+    ref = cluster.load_reference(config)
+    objects = [o["manifest"] for o in config.get("setup_objects", [])]
     nodes = cluster.make_nodes(config, n_nodes or config["nodes"], seed)
     pods = cluster.PodStream(config, seed).take(n_pods)
-    low = reference.Reference(nodes, precision)
+    low = ref.Reference(nodes, precision, objects)
     listed, watch_node, created_rv = [], {}, {}
     for rv, m in enumerate(pods, 1):
-        pod = reference.PodFacts(m)
+        pod = ref.PodFacts(m)
         created_rv[pod.name] = rv
         node = low.decide(pod)
         listed.append({**m, "spec": {**m["spec"], "nodeName": node or ""},
@@ -53,9 +56,9 @@ def run_control(config, seed, n_pods, precision, n_nodes=None):
             low.bind(pod, node)
     said = {}
     compared = verdict.compare(
-        nodes, pods, created_rv, watch_node, [], listed,
+        ref, nodes, pods, created_rv, watch_node, [], listed,
         {verdict.SCHEDULED: len(watch_node)}, [0, 0], "",
-        say=lambda phase, **fields: said.update(fields))
+        say=lambda phase, **fields: said.update(fields), objects=objects)
     return compared, verdict.correct(compared), said
 
 

@@ -28,6 +28,51 @@ compared.
 int8: the fractions requested / allocatable held in steps of 1/127);
 that is the control (decide() then serves as the scheduler put in the
 program's place), never the check.
+
+**The contract of a reference.** A configuration's file names the module
+that decides `correct` for it: `"reference": "<name>"` is
+benchmarks/references/<name>.py, and a file without the key has this
+one (`harness.cluster.load_reference`). Such a module holds
+
+  PodFacts(manifest), with `anti` (its required hostname anti-affinity
+      terms) and `extra_words` (see below)
+  Reference(nodes, precision="exact", objects=()), with fits, scores,
+      judge, decide, bind, over_allocatable; `objects` are the
+      manifests of the configuration's `setup_objects`
+  replay(nodes, pods_in_order, bound_node, precision="exact", objects=())
+      returning the keys it returns here
+
+and imports nothing of `kubernetes_tpu`. It may import this module and
+extend its classes, so that a new predicate or priority is a subclass
+and not a copy: `replay` is a class method that builds the class it is
+called on and that class's `Facts`, so `replay = Reference.replay` under
+the subclasses is the whole of it.
+
+**It reads by whitelist.** `PodFacts.reads` and `Reference.reads` list,
+path by path, every key of a pod and of a node manifest that this
+module reads or knows to be the same on every node (an image no node
+lists, a containerPort that is not a hostPort). Any other key that says
+something raises `ValueError` with its path (`admit`): nodeSelector,
+nodeName, node and pod affinity, preferred terms, tolerations, priority,
+volumes, hostPort, topologySpreadConstraints, initContainers, overhead,
+a request or an allocatable other than cpu, memory and pods,
+matchExpressions or namespaces in a term, taints, unschedulable, node
+images, and whatever else a later variant thinks of: a variant is never
+judged by a reference that does not know what it carries. So is a
+topology key other than hostname, a namespace other than `default` (a
+term matches inside one namespace and this module holds one), a node
+condition other than Ready=True, and any set-up object (`read_objects`).
+A key whose value is what the API's omitempty drops (null, "", 0, false,
+[], {}) says nothing and is let through: `priority: 0` is no priority.
+A reference that extends this one adds to `reads` the keys it answers
+for (`extended`).
+
+**The scan's least bytes are not here.** harness/roofline.py holds the
+one byte model (24 B a node and 4 B for each word more) over two facts
+a reference exposes for a pod: `len(pod.anti)` and `pod.extra_words`,
+the count of further f32 words a node that the chip has to read to
+decide this pod under the predicates and priorities the reference
+added (0 here).
 """
 
 import numpy as np
@@ -52,32 +97,124 @@ def quantity(q):
     return int(q)
 
 
+REQUIRED = "requiredDuringSchedulingIgnoredDuringExecution"
+_ANTI = "spec.affinity.podAntiAffinity"
+
+
+def says_nothing(value):
+    """What the API's omitempty drops: null, "", 0, false, [], {}."""
+    return value is None or (
+        isinstance(value, (str, int, float, list, dict)) and not value)
+
+
+def admit(at, reads, who, path=""):
+    """Walk a manifest against a whitelist {path: keys}: raise, naming
+    the path, on a key that says something and is not listed. A list is
+    walked item by item under its own path; a listed key whose path has
+    no entry of its own is taken whole (labels, matchLabels)."""
+    if isinstance(at, list):
+        for item in at:
+            admit(item, reads, who, path)
+        return
+    if not isinstance(at, dict) or path not in reads:
+        return
+    known = reads[path]
+    for key, value in at.items():
+        if key in known:
+            if isinstance(value, (dict, list)):
+                admit(value, reads, who, f"{path}.{key}" if path else key)
+        elif not says_nothing(value):
+            raise ValueError(f"{path}.{key}".lstrip(".") + f": the reference "
+                             f"{who} does not read it, and a decision can "
+                             f"turn on it")
+
+
+def extended(reads, more):
+    """`reads` with the keys of `more` added path by path: what a
+    reference that extends this one answers for."""
+    out = {path: set(keys) for path, keys in reads.items()}
+    for path, keys in more.items():
+        out.setdefault(path, set()).update(keys)
+    return out
+
+
 class PodFacts:
     """What the reference reads from a pod manifest."""
     __slots__ = ("name", "cpu", "mem", "labels", "anti")
 
+    #: path -> the keys there that this class reads, or knows to be the
+    #: same on every node; any other key that says something is refused
+    reads = {
+        "": {"apiVersion", "kind", "metadata", "spec"},
+        "metadata": {"name", "namespace", "labels"},
+        "spec": {"containers", "affinity"},
+        "spec.containers": {"name", "image", "ports", "resources"},
+        "spec.containers.ports": {"containerPort"},
+        "spec.containers.resources": {"requests", "limits"},
+        "spec.containers.resources.requests": {"cpu", "memory"},
+        "spec.containers.resources.limits": {"cpu", "memory"},
+        "spec.affinity": {"podAntiAffinity"},
+        _ANTI: {REQUIRED},
+        f"{_ANTI}.{REQUIRED}": {"labelSelector", "topologyKey"},
+        f"{_ANTI}.{REQUIRED}.labelSelector": {"matchLabels"},
+    }
+    #: f32 words a node, beyond the six every pod needs and one for each
+    #: term of `anti`, that the chip has to read to decide this pod
+    #: (harness/roofline.py); a reference that adds a predicate counts
+    #: its words here
+    extra_words = 0
+
     def __init__(self, manifest):
-        self.name = manifest["metadata"]["name"]
-        self.labels = manifest["metadata"].get("labels", {})
+        who = type(self).__module__
+        admit(manifest, self.reads, who)
+        meta = manifest["metadata"]
+        if meta.get("namespace", "default") != "default":
+            raise ValueError(f"metadata.namespace {meta['namespace']!r}: "
+                             f"the reference {who} holds one namespace")
+        self.name = meta["name"]
+        self.labels = meta.get("labels", {})
         self.cpu = self.mem = 0
         for c in manifest["spec"]["containers"]:
             req = c.get("resources", {}).get("requests", {})
             self.cpu += milli(req.get("cpu", "0"))
             self.mem += quantity(req.get("memory", "0"))
         self.anti = []
-        terms = manifest["spec"].get("affinity", {}).get(
-            "podAntiAffinity", {}).get(
-            "requiredDuringSchedulingIgnoredDuringExecution", [])
-        for t in terms:
+        anti = (manifest["spec"].get("affinity") or {}).get(
+            "podAntiAffinity") or {}
+        for t in anti.get(REQUIRED) or []:
             if t["topologyKey"] != HOSTNAME:
-                raise ValueError("the reference holds hostname "
-                                 "anti-affinity only")
+                raise ValueError(
+                    f"{_ANTI}.{REQUIRED}.topologyKey {t['topologyKey']!r}: "
+                    f"the reference {who} holds hostname anti-affinity only")
             self.anti.append(tuple(sorted(
                 t["labelSelector"]["matchLabels"].items())))
 
 
 class Reference:
-    def __init__(self, nodes, precision="exact"):
+    #: what it reads from a pod manifest
+    Facts = PodFacts
+    #: path -> the keys of a node manifest it reads (see PodFacts.reads):
+    #: nothing under `spec`, so no taint and no `unschedulable`
+    reads = {
+        "": {"apiVersion", "kind", "metadata", "spec", "status"},
+        "metadata": {"name", "labels"},
+        "spec": set(),
+        "status": {"capacity", "allocatable", "conditions"},
+        "status.capacity": {"cpu", "memory", "pods"},
+        "status.allocatable": {"cpu", "memory", "pods"},
+        "status.conditions": {"type", "status"},
+    }
+
+    def __init__(self, nodes, precision="exact", objects=()):
+        who = type(self).__module__
+        for n in nodes:
+            admit(n, self.reads, who)
+            for c in n["status"].get("conditions", []):
+                if (c.get("type"), c.get("status")) != ("Ready", "True"):
+                    raise ValueError(
+                        f"status.conditions {c}: the reference {who} "
+                        f"holds nodes that are Ready and nothing else")
+        self.read_objects(objects)
         self.names = [n["metadata"]["name"] for n in nodes]
         self.row = {name: i for i, name in enumerate(self.names)}
         alloc = [n["status"]["allocatable"] for n in nodes]
@@ -95,6 +232,14 @@ class Reference:
         #: labels -> (selectors known then, those of them the labels match)
         self._match_memo = {}
         self.precision = precision
+
+    def read_objects(self, objects):
+        """The configuration's set-up objects (a Service, a PriorityClass):
+        this class reads none, so it takes none."""
+        if objects:
+            raise ValueError(
+                f"set-up objects {[o.get('kind') for o in objects]}: the "
+                f"reference {type(self).__module__} reads none")
 
     # ------------------------------------------------------------ fit
 
@@ -223,6 +368,38 @@ class Reference:
         return int(((self.cpu > self.cap_cpu) | (self.mem > self.cap_mem)
                     | (self.pods > self.cap_pods)).sum())
 
+    @classmethod
+    def replay(cls, nodes, pods_in_order, bound_node, precision="exact",
+               objects=()):
+        """Replay binds in decision order. pods_in_order: manifests,
+        creation order; bound_node: name -> node the hub lists. Returns
+        the numbers compared: the widest score gap, how many binds did not
+        fit, how many pods have no node, and nodes over allocatable at the
+        end."""
+        ref = cls(nodes, precision, objects)
+        gap_max = misfit = unbound = gapped = 0
+        worst = None
+        for m in pods_in_order:
+            pod = cls.Facts(m)
+            node = bound_node.get(pod.name)
+            if not node:
+                unbound += 1
+                continue
+            fit, gap = ref.judge(pod, node)
+            if not fit:
+                misfit += 1
+                if node not in ref.row:
+                    continue
+            elif gap > 0:
+                gapped += 1
+                if gap > gap_max:
+                    gap_max, worst = gap, (pod.name, node)
+            ref.bind(pod, node)
+        return {"score_gap_max": gap_max, "binds_with_gap": gapped,
+                "binds_that_do_not_fit": misfit, "pods_without_node": unbound,
+                "nodes_over_allocatable": ref.over_allocatable(),
+                "replayed": len(pods_in_order), "worst": worst}
+
 
 def _dtype(precision):
     if precision == "bfloat16":
@@ -231,31 +408,4 @@ def _dtype(precision):
     return {"float32": np.float32}[precision]
 
 
-def replay(nodes, pods_in_order, bound_node, precision="exact"):
-    """Replay binds in decision order. pods_in_order: manifests, creation
-    order; bound_node: name -> node the hub lists. Returns the numbers
-    compared: the widest score gap, how many binds did not fit, how many
-    pods have no node, and nodes over allocatable at the end."""
-    ref = Reference(nodes, precision)
-    gap_max = misfit = unbound = gapped = 0
-    worst = None
-    for m in pods_in_order:
-        pod = PodFacts(m)
-        node = bound_node.get(pod.name)
-        if not node:
-            unbound += 1
-            continue
-        fit, gap = ref.judge(pod, node)
-        if not fit:
-            misfit += 1
-            if node not in ref.row:
-                continue
-        elif gap > 0:
-            gapped += 1
-            if gap > gap_max:
-                gap_max, worst = gap, (pod.name, node)
-        ref.bind(pod, node)
-    return {"score_gap_max": gap_max, "binds_with_gap": gapped,
-            "binds_that_do_not_fit": misfit, "pods_without_node": unbound,
-            "nodes_over_allocatable": ref.over_allocatable(),
-            "replayed": len(pods_in_order), "worst": worst}
+replay = Reference.replay

@@ -14,7 +14,11 @@
      (20 runs of one matmul program with 5 ms of host sleep between),
      that busy time agrees with the plain sum of the module events, and
      that cutting the trace to its first half (as a traced run cuts it
-     to the window between its two marks) leaves half the runs.
+     to the window between its two marks) leaves half the runs;
+  4. the seams hold (benchmarks/tests/test_seams.py, numpy, seconds: no
+     tier-1 test guards them yet, PERF.md Open question 0): defaults
+     byte for byte, the reference's whitelists, an extended reference,
+     a node builder and set-up objects, the scan's byte model.
 """
 
 import json
@@ -70,10 +74,11 @@ def check_rehearsal():
 
 
 def check_reference_catches():
-    from harness import cluster, reference
+    from harness import cluster
     with open(os.path.join(BENCH_DIR, "configs",
                            "sched-perf-5000n-basic.json")) as f:
         config = json.load(f)
+    reference = cluster.load_reference(config)
     nodes = cluster.make_nodes(config, 50, 5)
     pods = cluster.PodStream(config, 5).take(400)
     ref = reference.Reference(nodes)
@@ -99,6 +104,16 @@ def check_reference_catches():
         out2["nodes_over_allocatable"] == 1, out2
     return (f"reference: mis-bound pod reads gap {out['score_gap_max']}, "
             f"over-full node reads {out2['binds_that_do_not_fit']} misfits")
+
+
+def check_seams():
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         os.path.join(BENCH_DIR, "tests", "test_seams.py")], cwd=REPO,
+        capture_output=True, text=True, timeout=600)
+    tail = proc.stdout.strip().splitlines()[-1] if proc.stdout else ""
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-1000:]
+    return f"seams: {tail.strip('= ')}"
 
 
 def check_trace():
@@ -131,7 +146,8 @@ def main():
     if len(sys.argv) == 3 and sys.argv[1] == "--record":
         return record(sys.argv[2])
     t = time.monotonic()
-    for check in (check_reference_catches, check_trace, check_rehearsal):
+    for check in (check_reference_catches, check_seams, check_trace,
+                  check_rehearsal):
         print("ok:", check(), flush=True)
     print(f"selfcheck passed in {time.monotonic() - t:.0f} s")
 

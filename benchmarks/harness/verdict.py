@@ -5,6 +5,7 @@ the program's place) hand it the same facts and get the same verdict.
 
 The facts, as plain data:
 
+  ref          the configuration's reference (cluster.load_reference)
   nodes        the node manifests
   taken        every pod manifest handed out, in order
   created_rv   name -> resourceVersion the hub gave the create (> 0
@@ -15,19 +16,18 @@ The facts, as plain data:
   scheduler    the scheduler's /metrics at the end
   exit_codes   of the children
   sched_err    the scheduler's stderr
+  objects      the manifests of the configuration's set-up objects
 
 Every limit is exact: 0."""
 
 import time
 
-from . import reference
-
 SCHEDULED = 'scheduler_schedule_attempts_total{result="scheduled"}'
 ERRORS = 'scheduler_schedule_attempts_total{result="error"}'
 
 
-def compare(nodes, taken, created_rv, watch_node, rebinds, listed,
-            scheduler, exit_codes, sched_err, say=None):
+def compare(ref, nodes, taken, created_rv, watch_node, rebinds, listed,
+            scheduler, exit_codes, sched_err, say=None, objects=()):
     """{name: {"value": v, "limit": 0}} of every number compared."""
     by_name = {p["metadata"]["name"]: p for p in listed}
     bound_node, no_condition, not_listed = {}, 0, 0
@@ -50,7 +50,7 @@ def compare(nodes, taken, created_rv, watch_node, rebinds, listed,
     # the order of the decisions: creation order (see reference.py)
     acked.sort(key=lambda m: created_rv[m["metadata"]["name"]])
     t = time.monotonic()
-    rep = reference.replay(nodes, acked, bound_node)
+    rep = ref.replay(nodes, acked, bound_node, objects=objects)
     if say is not None:
         say("reference", seconds=time.monotonic() - t, **rep)
     watch_differs = sum(

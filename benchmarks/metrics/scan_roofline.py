@@ -1,6 +1,9 @@
 """Share of the HBM roofline the scan reaches: the least time the chip
-could take for the pods scheduled in the traced slice (harness/roofline.py,
-from shapes alone) over the device time of the scan programs."""
+could take for the pods scheduled in the traced slice (harness/roofline.py
+over the facts of the run's last pods as the configuration's reference
+reads them, from shapes alone) over the device time of the scan programs.
+No scan in the slice: nothing to read. A scan and no byte count: a fault
+of the harness, raised and never passed over in silence."""
 
 import os
 
@@ -16,7 +19,10 @@ def read(ctx, spec):
     pods = ctx.get("slice_pods_scheduled")
     if seconds is None or not pods:
         return None
+    per_pod_node = ctx.get("scan_bytes_per_pod_node")
+    if not per_pod_node:
+        raise ValueError(f"scan_roofline: {pods} pods scanned in the slice "
+                         f"and no scan_bytes_per_pod_node ({per_pod_node!r})")
     least = roofline.scan_least_seconds(
-        ctx["device"]["kind"], pods, ctx["nodes"],
-        ctx.get("anti_terms_per_pod", 0.0))
+        ctx["device"]["kind"], pods, ctx["nodes"], per_pod_node)
     return 100.0 * least / seconds

@@ -8,14 +8,17 @@ Everything that belongs to one cell, configuration, pod variant, traffic
 mix or metric is a file found by the name BENCHMARK.json gives it (see
 benchmarks/README.md); this file holds none of those names.
 
-A run: start a real kube_apiserver (CPU-pinned, WAL and validation on)
-and the scheduler through harness/sched_entry.py, the one owner of the
-chip; create the configuration's nodes, its existing pods and the mix's
-warm bursts; ramp the mix's loop; measure for --seconds on this
-process's monotonic clock with binds read from its own watch of pods;
-stop creating and wait for the pods created in the window; read the
-hub's LIST; stop both children; replay every bind in the plain
-reference (harness/verdict.py, harness/reference.py); print one JSON line.
+A run: start a real kube_apiserver (JAX_PLATFORMS=cpu, so it never
+takes the chip; no CPU affinity is set; WAL and validation on); create
+the configuration's nodes and its set-up objects; start the scheduler
+through harness/sched_entry.py, the one owner of the chip; create the
+existing pods and the mix's warm bursts; ramp the mix's loop; measure
+for --seconds on this process's monotonic clock with binds read from
+its own watch of pods; stop creating and wait for the pods created in
+the window; read the hub's LIST; stop both children; replay every bind
+in the configuration's plain reference (harness/verdict.py over
+harness/reference.py, or over the module the configuration names);
+print one JSON line.
 
 This process never imports JAX while a child holds the chip. Without
 --rehearse a scheduler that names any platform but `tpu` ends the run
@@ -39,7 +42,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-from harness import cluster, reference, trace_reduce, verdict  # noqa: E402
+from harness import cluster, roofline, trace_reduce, verdict  # noqa: E402
 from harness.children import (Child, free_port, parse_metrics,  # noqa: E402
                               proc_cpu_s, scrape)
 from harness.sched_entry import MARK                        # noqa: E402
@@ -71,6 +74,7 @@ class Run:
         if self.rehearse:
             self.config.update(self.config.get("rehearse", {}))
             self.mix.update(self.mix.get("rehearse", {}))
+        self.reference = cluster.load_reference(self.config)
         self.device = None
         self.workdir = tempfile.mkdtemp(prefix="ktpu-bench-")
         self.ctrl = os.path.join(self.workdir, "ctrl")
@@ -199,6 +203,10 @@ class Run:
         self.start_hub()
         nodes = self.nodes = cluster.make_nodes(cfg, cfg["nodes"], a.seed)
         self.client.create_all("/api/v1/nodes", nodes)
+        objects = []
+        for o in cfg.get("setup_objects", []):
+            self.client.create_all(o["path"], [o["manifest"]])
+            objects.append(o["manifest"])
         # nodes first, scheduler second: its first LIST holds every node,
         # so no pod is ever decided over a part of the cluster
         self.start_scheduler()
@@ -317,11 +325,11 @@ class Run:
 
         # ---- correct: the hub's LIST against the plain reference
         compared = verdict.compare(
-            self.nodes, stream.taken,
+            self.reference, self.nodes, stream.taken,
             {name: r[1] for name, r in obs.pods.items()},
             {name: r[3] for name, r in obs.pods.items()},
             obs.rebinds, listed, final["scrape"]["kube_scheduler"],
-            [sched_rc, hub_rc], sched_err, say=self.say)
+            [sched_rc, hub_rc], sched_err, say=self.say, objects=objects)
         correct = verdict.correct(compared)
 
         # ---- per-layer numbers (a traced run)
@@ -337,9 +345,10 @@ class Run:
             s0, s1, _ = slice_marks
             ctx["slice_pods_scheduled"] = s1.get(verdict.SCHEDULED, 0) \
                 - s0.get(verdict.SCHEDULED, 0)
-            ctx["anti_terms_per_pod"] = sum(
-                len(reference.PodFacts(m).anti) for m in stream.taken[-512:]
-            ) / max(1, len(stream.taken[-512:]))
+            last = stream.taken[-512:]
+            ctx["scan_bytes_per_pod_node"] = sum(
+                roofline.scan_bytes_per_node(self.reference.PodFacts(m))
+                for m in last) / max(1, len(last))
             if reduced is not None:
                 device_out["busy_s"] = reduced["busy_s"]
                 device_out["window_s"] = reduced["window_s"]
@@ -356,6 +365,8 @@ class Run:
                          mark_spans_s=reduced["mark_spans_s"],
                          busy_s=reduced["busy_s"],
                          slice_pods_scheduled=ctx["slice_pods_scheduled"],
+                         scan_bytes_per_pod_node=ctx[
+                             "scan_bytes_per_pod_node"],
                          programs=breakdown["programs"])
         metrics = {}
         for name, spec in self.metrics_for(section):
