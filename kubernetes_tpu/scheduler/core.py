@@ -261,6 +261,15 @@ class _RepairReassigner:
                 yield name
 
 
+def _required_pod_affinity(pod: Pod):
+    """The pod's required podAffinity terms (empty where it has none)."""
+    aff = pod.spec.affinity
+    if aff is None or aff.pod_affinity is None:
+        return ()
+    return aff.pod_affinity \
+        .required_during_scheduling_ignored_during_execution or ()
+
+
 def _pod_has_conflict_volumes(pod: Pod) -> bool:
     for v in pod.spec.volumes:
         if v.gce_persistent_disk or v.aws_elastic_block_store or v.rbd or v.iscsi:
@@ -393,6 +402,9 @@ class BatchScheduler:
         #: trace and flight recorder as the shell's commit/bind stages
         self.tracer = NULL_TRACER
         self._fallback_streak: Dict[str, int] = {}
+        #: an in-scan fallback was counted since the last launch (some
+        #: are counted before it, when the shell sizes the batch)
+        self._batch_fell_back = False
         #: (pod-list, plan) from the most recent _soft_plan: the drain's
         #: soft_batch_limit and the launch's _assign_soft_terms see the
         #: SAME list object when the batch wasn't truncated, so the O(P)
@@ -415,12 +427,23 @@ class BatchScheduler:
     def refresh(self) -> None:
         dirty = self.cache.update_snapshot(self.snapshot)
         self.mirror.apply(self.snapshot, dirty)
-        self.topology.apply(self.snapshot, dirty)
+        self._topology_apply(dirty)
         if dirty:
             # precise score gating: required-anti-only clusters never
             # produce an inter-pod priority contribution
             self.scorer.set_cluster_has_affinity_pods(
                 self.topology.has_score_carriers())
+
+    def _topology_apply(self, dirty) -> None:
+        """TopologyIndex.apply under its own part of `refresh`. An index
+        that no (anti-)affinity carrier or term has switched on yet only
+        scans the dirty nodes for one; that, and the one pass over the
+        whole snapshot that switches it on, stay in `refresh`."""
+        if not self.topology.active:
+            self.topology.apply(self.snapshot, dirty)
+            return
+        with self._stage("topology_apply", nodes=len(dirty)):
+            self.topology.apply(self.snapshot, dirty)
 
     # ------------------------------------------------------- residual host path
 
@@ -573,15 +596,25 @@ class BatchScheduler:
         if not sig_reps:
             return extra, profiles, \
                 (None if filter_extenders else pod_sig)
-        # pass 2: one vectorized affinity evaluation for ALL templates
-        # (topology.required_masks — numpy or device matmuls by size), plus
-        # the per-node volume loop only for templates that carry volumes.
-        # Profile resolution is memoized ACROSS batches by template
-        # signature, invalidated by the topology index's profile_epoch
-        # (new terms, zero-crossing match/anti-carry counts — the only
-        # state a resolved profile depends on)
+        with self._stage("affinity_masks", templates=len(sig_reps)):
+            self._template_rows(pods, extra, pod_sig, profiles,
+                                list(sig_index), sig_reps)
+        return extra, profiles, (None if filter_extenders else pod_sig)
+
+    def _template_rows(self, pods: List[Pod], extra: np.ndarray,
+                       pod_sig: np.ndarray,
+                       profiles: Dict[int, AffinityProfile],
+                       sigs: List[Tuple], sig_reps: List[Pod]) -> None:
+        """Pass 2 of _residual_mask: one vectorized affinity evaluation
+        for ALL templates (topology.required_masks — numpy or device
+        matmuls by size), plus the per-node volume loop only for
+        templates that carry volumes, laid on the pods' rows of `extra`.
+        Profile resolution is memoized ACROSS batches by template
+        signature, invalidated by the topology index's profile_epoch
+        (new terms, zero-crossing match/anti-carry counts — the only
+        state a resolved profile depends on)."""
         sig_profiles = [self._cached_profile(sig, p)
-                        for sig, p in zip(sig_index, sig_reps)]
+                        for sig, p in zip(sigs, sig_reps)]
         constrained = [u for u, pr in enumerate(sig_profiles)
                        if pr.constrained]
         aff_rows: Dict[int, np.ndarray] = {}
@@ -590,6 +623,13 @@ class BatchScheduler:
                 [sig_profiles[u] for u in constrained])
             for j, u in enumerate(constrained):
                 aff_rows[u] = rows[j]
+        if self.sched_metrics is not None:
+            m = self.sched_metrics
+            m.constraint_templates.inc(len(sig_reps))
+            if constrained:
+                m.constraint_terms.inc(self.topology.last_masks_terms)
+                m.affinity_evaluations.inc(
+                    stage="masks", route=self.topology.last_masks_route)
         vol_rows = [self._volume_row(rep) for rep in sig_reps]
         # templates whose residual row is provably all-True collapse back
         # to "no extra row" (id -1): one .all() per TEMPLATE keeps the
@@ -613,7 +653,6 @@ class BatchScheduler:
                 profiles[i] = sig_profiles[u]
             if inert_u[u]:
                 pod_sig[i] = -1
-        return extra, profiles, (None if filter_extenders else pod_sig)
 
     def _cached_profile(self, sig: Tuple, pod: Pod) -> AffinityProfile:
         """required_profile memoized by template signature across batches
@@ -749,6 +788,64 @@ class BatchScheduler:
             for p in pods)
 
     def soft_batch_limit(self, pods: List[Pod]) -> int:
+        """How many of these pods may schedule in ONE kernel batch and
+        still get the serial reference's decisions: the cut before a pod
+        whose required affinity an earlier pod of the batch can widen
+        (_affinity_growth_cut), and the soft-score limit below."""
+        cut = self._affinity_growth_cut(pods)
+        if cut < len(pods):
+            self._count_inscan_fallback("aff_growth")
+            return min(cut, self._soft_score_limit(pods[:cut]))
+        self._end_inscan_streak("aff_growth")
+        return self._soft_score_limit(pods)
+
+    def _affinity_growth_cut(self, pods: List[Pod]) -> int:
+        """Index of the first pod whose required affinity term an earlier
+        pod of this batch can carry into a domain it was not in when the
+        batch was popped; len(pods) where there is none.
+
+        A batch's mask rows are taken at its start. For required
+        ANTI-affinity and for a waived affinity term the scan keeps the
+        winners' counts itself; a term that is not waived only ever
+        gains matches, so its row stays right unless a winner lands
+        where the term had no match: then the serial reference, which
+        sees each bind, admits nodes the row excludes. A winner that
+        matches the term can do that unless it requires the same term
+        itself and the term has a match already (it then lands only
+        where the term matches). scheduler_perf's pod-affinity pods all
+        require the term they match, so their batches are never cut. The
+        pods from the cut on are popped into the next batch, whose rows
+        see the binds before it."""
+        if not any(_required_pod_affinity(p) for p in pods):
+            return len(pods)
+        idx = self.topology
+        #: residual signature -> (terms it reads unwaived, terms a bind of
+        #: it can carry into a new domain)
+        memo: Dict[Tuple, Tuple[frozenset, frozenset]] = {}
+        widened: set = set()
+        for i, pod in enumerate(pods):
+            sig = self._residual_sig(pod)
+            hit = memo.get(sig)
+            if hit is None:
+                reads, anchored = set(), set()
+                for t in _required_pod_affinity(pod):
+                    term = idx.ensure_match(
+                        t.topology_key, idx._resolved_ns(t, pod),
+                        t.label_selector)
+                    matched = idx.match_domains(term.tid) > 0
+                    if matched or not term.matches_pod(pod):
+                        reads.add(term.tid)
+                    if matched:
+                        anchored.add(term.tid)
+                hit = memo[sig] = (frozenset(reads),
+                                   frozenset(idx.match_set(pod) - anchored))
+            reads, widens = hit
+            if i and not reads.isdisjoint(widened):
+                return i
+            widened |= widens
+        return len(pods)
+
+    def _soft_score_limit(self, pods: List[Pod]) -> int:
         """How many of these pods may schedule in ONE kernel batch without
         visible soft-score drift. Preferred inter-pod (anti-)affinity
         scores change with every in-batch winner; the serial reference
@@ -900,6 +997,7 @@ class BatchScheduler:
         streak."""
         if self.sched_metrics is not None:
             self.sched_metrics.topo_inscan_fallbacks.inc(reason=reason)
+        self._batch_fell_back = True
         streak = self._fallback_streak.get(reason, 0)
         if streak == 0:
             import logging
@@ -1185,11 +1283,17 @@ class BatchScheduler:
                 tmpl_carry.append(carry)
             tmpl_of[i] = t
         if not any(tmpl_pref):
-            # no batch member carries preferred terms: only the frozen
-            # symmetric-credit drift remains, which the static rows cover
-            # (the same contract as the old chunk trigger) — required-only
-            # batches keep the incremental class-scan fast path
-            return None
+            # no batch member carries preferred terms: the one soft score
+            # an in-batch winner can still move is the hard-affinity
+            # symmetric credit. Where every reader of it is pinned to one
+            # domain (the self-affine groups of a controller) the credit
+            # is flat over the reader's nodes whatever lands, the static
+            # rows are exact, and the batch keeps the class scan without
+            # credit tables; any other reader gets its channels in-scan
+            chan_list = self._unpinned_hard_credits(tmpl_pods, tmpl_carry)
+            channels = {k: s for s, k in enumerate(chan_list)}
+            tmpl_carry = [[c for c in carry if (c[0], c[1]) in channels]
+                          for carry in tmpl_carry]
         if not chan_list:
             return None  # no in-batch credit can move: static rows suffice
         # canonical template order (repr: residual sigs mix None/str/tuple
@@ -1249,6 +1353,50 @@ class BatchScheduler:
                 # chain signature (soft_base row r must mean the same
                 # template on both sides of a chained launch)
                 "tmpl_sigs": tuple(tkeys)}
+
+    def _unpinned_hard_credits(self, tmpl_pods: List[Pod],
+                               tmpl_carry: List[List[Tuple[str, int, float]]]
+                               ) -> List[Tuple[str, int]]:
+        """The ("ca", term) channels of a batch without preferred terms
+        that some template reads over nodes of more than one domain: the
+        credits an earlier winner of the same batch can move an argmax
+        with (the serial reference re-scores after every bind).
+
+        A template that matches a carried term t reads its credit. The
+        read is flat, and needs no channel, when the template is pinned
+        to one domain of t's topology key: it requires a term q on that
+        key whose matches lie in at most one domain now and stay there,
+        because every template of the batch that matches q requires q
+        too (it lands only where q already matches; with no match yet,
+        the first lands anywhere and the scan's waiver tables hold the
+        rest to its domain). scheduler_perf's pod-affinity pods are all
+        of this kind: each colour requires, matches and carries its own
+        term."""
+        idx = self.topology
+        required = [{tid for kind, tid, _w in carry if kind == "ca"}
+                    for carry in tmpl_carry]
+        carried = set().union(*required) if required else set()
+        if not carried:
+            return []
+        msets = [idx.match_set(rep) for rep in tmpl_pods]
+        stays: Dict[int, bool] = {}
+
+        def stays_in_one_domain(q: int) -> bool:
+            hit = stays.get(q)
+            if hit is None:
+                hit = stays[q] = idx.match_domains(q) <= 1 and all(
+                    q in required[j] for j, ms in enumerate(msets)
+                    if q in ms)
+            return hit
+
+        keep = set()
+        for r, ms in enumerate(msets):
+            for t in carried & ms:
+                tk = idx.term(t).tk
+                if not any(idx.term(q).tk == tk and stays_in_one_domain(q)
+                           for q in required[r]):
+                    keep.add(("ca", t))
+        return sorted(keep)
 
     def _assign_soft_terms(self, pods: List[Pod],
                            batch: PodBatchTensors) -> Optional[Tuple]:
@@ -1532,7 +1680,7 @@ class BatchScheduler:
                         and affinity_only)
             if chaining:
                 self.mirror.apply_chained(self.snapshot, dirty)
-                self.topology.apply(self.snapshot, dirty)
+                self._topology_apply(dirty)
                 if dirty:
                     # keep the scorer's gate fresh on the chained path too: if
                     # this drain's own commits introduced score-contributing
@@ -1545,7 +1693,7 @@ class BatchScheduler:
                 # still apply it, or the mirror would never see these updates
                 # (update_snapshot won't return them again)
                 self.mirror.apply(self.snapshot, dirty)
-                self.topology.apply(self.snapshot, dirty)
+                self._topology_apply(dirty)
                 if dirty:
                     self.scorer.set_cluster_has_affinity_pods(
                         self.topology.has_score_carriers())
@@ -1590,6 +1738,10 @@ class BatchScheduler:
             soft_sig = self._assign_soft_terms(pods, batch)
             spread_present = spread_sig is not None
             soft_present = soft_sig is not None
+            if self._batch_fell_back:
+                self._batch_fell_back = False
+                if self.sched_metrics is not None:
+                    self.sched_metrics.topo_inscan_fallback_batches.inc()
         with self._stage("dispatch", pods=len(pods)):
             nom_dev = self._nominated_device()
             if nom_dev is not None:
@@ -1600,7 +1752,14 @@ class BatchScheduler:
                     row = self._nom_rows_by_key.get(pod.metadata.key())
                     if row is not None:
                         batch.nom_row[i] = row
-            static = self.scorer.static_scores(pods, batch)
+            if self.scorer.interpod_carriers():
+                with self._stage("affinity_scores", pods=len(pods)):
+                    static = self.scorer.static_scores(pods, batch)
+                if self.sched_metrics is not None:
+                    self.sched_metrics.affinity_evaluations.inc(
+                        stage="scores", route="host")
+            else:
+                static = self.scorer.static_scores(pods, batch)
             has_prio_ext = any(e.config.prioritize_verb for e in self.extenders)
             # hysteresis: while host-computed static scores are in play, later
             # launches refuse the chain up front instead of discarding work.

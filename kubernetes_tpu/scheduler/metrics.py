@@ -28,6 +28,18 @@ SCHEDULING_LATENCY_BUCKETS = (0.0005, 0.001, 0.002, 0.004, 0.008, 0.016,
 STAGE_PARENTS = ("algorithm", "commit", "launch", "fetch")
 STAGE_LEAVES = ("pop_wait", "refresh", "tensorize", "dispatch",
                 "scan_wait", "repair", "assume", "bind_backlog")
+#: what the inter-pod (anti-)affinity machinery takes of a leaf, each
+#: nested in the leaf named: topology_apply in refresh (TopologyIndex.
+#: apply, the (term, domain) counts kept as binds land), affinity_masks
+#: in tensorize (the second pass of core._residual_mask: template
+#: profiles, TopologyIndex.required_masks, the rows laid on the pods),
+#: affinity_scores in dispatch (scorer.static_scores while the cluster
+#: holds inter-pod score carriers). A batch that needs none of them
+#: enters none, so a cluster without such pods reads 0 in all three.
+STAGE_PARTS = ("topology_apply", "affinity_masks", "affinity_scores")
+#: every reason of scheduler_topo_inscan_fallbacks_total
+INSCAN_FALLBACK_REASONS = ("term_cap", "kmax", "soft_terms", "soft_kmax",
+                           "soft_gang", "aff_growth")
 
 
 class SchedulerMetrics:
@@ -42,7 +54,7 @@ class SchedulerMetrics:
             "scheduler_scheduling_duration_seconds",
             "Scheduling stage latency per batch cycle, by operation",
             buckets=SCHEDULING_LATENCY_BUCKETS)
-        for op in STAGE_PARENTS + STAGE_LEAVES:
+        for op in STAGE_PARENTS + STAGE_LEAVES + STAGE_PARTS:
             self.scheduling_duration.declare(operation=op)
         # ref: E2eSchedulingLatency — queue pop to bind committed
         self.e2e_scheduling_duration = r.histogram(
@@ -110,13 +122,47 @@ class SchedulerMetrics:
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048,
                      4096))
         # in-scan (anti-)affinity fallbacks, by reason {term_cap, kmax,
-        # soft_terms, soft_kmax, soft_gang}: batches the kernel tables
-        # could not cover take the repair-overlay / sub-chunked path
-        # instead — a capped code path must be visible, never silent
+        # soft_terms, soft_kmax, soft_gang, aff_growth}: batches the
+        # kernel tables could not cover take the repair-overlay /
+        # sub-chunked path instead (aff_growth: cut short before a pod
+        # whose required affinity an earlier pod of the batch can widen)
+        # — a capped code path must be visible, never silent
         self.topo_inscan_fallbacks = r.counter(
             "scheduler_topo_inscan_fallbacks_total",
             "Batches that fell back from the in-scan topology/soft-credit "
             "tables, by reason")
+        for reason in INSCAN_FALLBACK_REASONS:
+            self.topo_inscan_fallbacks.declare(reason=reason)
+        # the same without the reason, once a batch however many of its
+        # caps overflowed: over the cycles, the share of batches that
+        # left the scan
+        self.topo_inscan_fallback_batches = r.counter(
+            "scheduler_topo_inscan_fallback_batches_total",
+            "Batches in which at least one in-scan fallback was counted")
+        self.topo_inscan_fallback_batches.declare()
+        # what a batch's constraint machinery was sized by: the distinct
+        # residual templates it resolved (the U of required_masks'
+        # [U, N] rows) and the distinct terms those rows read (its T)
+        self.constraint_templates = r.counter(
+            "scheduler_constraint_templates_total",
+            "Constraint templates (distinct residual signatures) resolved, "
+            "summed over batches")
+        self.constraint_templates.declare()
+        self.constraint_terms = r.counter(
+            "scheduler_constraint_terms_total",
+            "Distinct (anti-)affinity terms read by the batches' template "
+            "mask rows, summed over batches")
+        self.constraint_terms.declare()
+        # which way a batch's affinity rows were computed: host numpy or
+        # the device matmuls of kernels/affinity.py (the score rows have
+        # the host route alone)
+        self.affinity_evaluations = r.counter(
+            "scheduler_affinity_evaluations_total",
+            "Batches whose affinity mask or score rows were computed, by "
+            "stage and route")
+        for stage, route in (("masks", "host"), ("masks", "device"),
+                             ("scores", "host")):
+            self.affinity_evaluations.declare(stage=stage, route=route)
         # serving-mode adaptive drain: the batch cap the sizing policy
         # chose per cycle (grows with queue depth, shrinks under commit/
         # bind backpressure or a priority-lane express batch)
@@ -199,12 +245,13 @@ class SchedulerMetrics:
 
     def stage(self, tracer, name: str, ring: bool = True, **attrs):
         """observability.SpanTracer.stage for one `operation` of the
-        cycle: a leaf also gets the trace annotation sched.<name>, a
-        parent none (it would cover the host time that its leaves leave
-        unexplained)."""
+        cycle: a leaf, and a part of one, also gets the trace annotation
+        sched.<name>, a parent none (it would cover the host time that
+        its leaves leave unexplained)."""
         return tracer.stage(
             name, self.scheduling_duration, labels={"operation": name},
-            trace="sched." + name if name in STAGE_LEAVES else None,
+            trace="sched." + name
+            if name in STAGE_LEAVES + STAGE_PARTS else None,
             ring=ring, **attrs)
 
     def observe_queue(self, queue) -> None:

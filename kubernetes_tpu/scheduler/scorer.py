@@ -457,3 +457,11 @@ class ScoreCompiler:
 
     def set_cluster_has_affinity_pods(self, flag: bool) -> None:
         self._cluster_has_affinity_pods = flag
+
+    def interpod_carriers(self) -> bool:
+        """True while InterPodAffinityPriority is weighted and some bound
+        pod carries a term that can credit it: every template of a batch
+        then gets its raw row computed (core times that as
+        affinity_scores)."""
+        return bool(self.weights.get("InterPodAffinityPriority")) \
+            and self._cluster_has_affinity_pods

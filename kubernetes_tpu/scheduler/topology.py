@@ -51,10 +51,16 @@ K_CARRY_PAFF = "carry_paff"  # preferred affinity, weight-summed
 K_CARRY_PANTI = "carry_panti"  # preferred anti-affinity, weight-summed
 
 #: route template evaluation through the device matmul kernel above this
-#: many (templates × terms × nodes) f32 ops. Host BLAS handles hundreds of
-#: MFLOPs faster than an upload + dispatch + fetch round trip to the
-#: device; the MXU wins once distinct selectors per batch grow into the
-#: thousands (threshold not re-derived on the chip)
+#: many (templates × terms × nodes) f32 ops. Measured on one TPU v5e at
+#: the pod-affinity benchmark configuration's size (PR 30, PERF.md
+#: section 6: 400 templates × 400 terms × 8,192 node rows = 1.3e9): the
+#: host route takes 1.0 ms a batch while the templates' cached rows
+#: stand and 4.0 ms with every row rebuilt from the presence vectors;
+#: the device route (stack, upload, three HIGHEST-precision matmuls,
+#: fetch) takes 8.8 ms, after 9.7 s for its first compile. The host wins
+#: there by 2-9x, so the threshold stays above that size; where the
+#: device starts to win (distinct selectors in the thousands) has not
+#: been measured
 DEVICE_EVAL_THRESHOLD = 2_000_000_000
 
 
@@ -184,6 +190,16 @@ class TopologyIndex:
         #: term (one O(cluster) rebuild), not on every uniform batch
         self._active = False
         self._last_snapshot = None
+        #: how the last required_masks call computed its rows ("host" or
+        #: "device") and over how many distinct terms
+        self.last_masks_route = "host"
+        self.last_masks_terms = 0
+
+    @property
+    def active(self) -> bool:
+        """The incremental maintenance is on (a carrier or a term has
+        been seen); before that apply() only looks for one."""
+        return self._active
 
     # ------------------------------------------------------------ interning
 
@@ -470,6 +486,14 @@ class TopologyIndex:
     def term(self, tid: int) -> _Term:
         return self._by_id[tid]
 
+    def match_domains(self, tid: int) -> int:
+        """In how many domains of its topology key the term matches a
+        bound pod now; a term whose matches are not kept yet (never
+        resolved by required_profile) reads as many: not known."""
+        if not self._by_id[tid].match_registered:
+            return len(self._nodes) + 1
+        return len(self._counts[K_MATCH].get(tid, ()))
+
     def required_profile(self, pod: Pod) -> AffinityProfile:
         """Resolve a pod template's required-(anti-)affinity evaluation plan
         (registers match terms as needed)."""
@@ -754,9 +778,12 @@ class TopologyIndex:
                     t_index[k] = len(terms)
                     terms.append(k)
         T = len(terms)
+        self.last_masks_terms = T
+        self.last_masks_route = "host"
         if T == 0:
             return np.ones((U, cap), bool)
         if U * T * cap >= DEVICE_EVAL_THRESHOLD:
+            self.last_masks_route = "device"
             present = np.stack([self.presence_vec(kind, tid)
                                 for kind, tid in terms])
             has_dom = np.stack([self.has_dom_vec(self._by_id[tid].tk)

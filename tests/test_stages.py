@@ -233,13 +233,23 @@ class TestServedCycleStages:
             release.wait(10)
             return inner(items, backoff)
         sched._bind_items_inner = slow
+        stage = sched._stage
+
+        def at_the_backlog(name, **kwargs):
+            # the held bind is let go by the scheduling thread itself, as
+            # it arrives at the backlog: however long the cycle before
+            # took (a first compile), the bind is still in flight then
+            if name == "bind_backlog":
+                release.set()
+            return stage(name, **kwargs)
+        sched._stage = at_the_backlog
         try:
             assert len(sched.schedule_pending(max_pods=6, timeout=1.0)) == 6
             d = sched.metrics.scheduling_duration
             assert d.count(operation="bind_backlog") == 0
+            assert not release.is_set()
             # the second cycle decides its batch, then finds the first
             # bind still in flight and waits for it
-            threading.Timer(0.5, release.set).start()
             assert len(sched.schedule_pending(max_pods=6, timeout=1.0)) == 6
             assert d.count(operation="bind_backlog") == 1
             assert d.sum(operation="bind_backlog") > 0.0
@@ -476,7 +486,12 @@ NEW_RATIOS = [
     "informer_deliver_ms_per_pod", "hub_create_ms_per_pod",
     "hub_bind_ms_per_pod", "hub_watch_encode_ms_per_pod",
     "hub_lock_wait_ms_per_pod", "hub_compaction_ms_per_pod",
-    "sched_pods_per_cycle"]
+    "sched_pods_per_cycle",
+    # PR 30: the parts of refresh, tensorize and dispatch that the
+    # inter-pod affinity machinery takes, and what sizes them
+    "sched_topology_apply_ms_per_pod", "sched_affinity_masks_ms_per_pod",
+    "sched_affinity_scores_ms_per_pod", "sched_templates_per_cycle",
+    "sched_inscan_fallback_share"]
 NEW_READERS = ["idle_waiting_for_pods_share", "idle_waiting_for_hub_share",
                "idle_unattributed_share"]
 
@@ -512,7 +527,10 @@ def _declared(name):
         bench = json.load(f)
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
     cells = [w["name"] for w in bench["workloads"]]
-    assert entry["workloads"] == cells
+    # an entry without the key holds in every cell; one with it names
+    # cells that exist
+    assert "workloads" not in entry or \
+        set(entry["workloads"]) <= set(cells)
     assert entry["moves"] in [m["name"] for m in bench["end_to_end"]]
     with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
         spec = json.load(f)
