@@ -2233,7 +2233,7 @@ def _spec_kernel_micro(n_pods, n_nodes, widths=(8, 16, 32)):
         algo.refresh()
         batch = algo.schedule_launch(pods).batch
         node_cfg, usage = algo.mirror.device_cfg_usage()
-        dev = batch.device(algo.mirror.mesh)
+        dev = batch.device()
 
         def best_of(fn, *args, reps=7, **kw):
             best, out = 1e9, None
@@ -2249,7 +2249,7 @@ def _spec_kernel_micro(n_pods, n_nodes, widths=(8, 16, 32)):
         sweep = {}
         for k in widths:
             batch.set_speculative(k)
-            dv = batch.device(algo.mirror.mesh)
+            dv = batch.device()
             t_k, out_k = best_of(spec.schedule_batch_speculative,
                                  node_cfg, usage, dv, width=k)
             st = np.asarray(out_k[3])

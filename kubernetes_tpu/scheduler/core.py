@@ -1101,8 +1101,7 @@ class BatchScheduler:
         dom_dev = None
         if self.mirror.mesh is not None:
             dom_dev, _ = idx.term_table_device(
-                tuple(terms), self.mirror.mesh,
-                use_cache=self.topo_table_cache,
+                tuple(terms), use_cache=self.topo_table_cache,
                 dom=dom, n_domains=n_domains)
         tpos = {tid: j for j, tid in enumerate(terms)}
         # per-pod [K] term-index lists (-1 padded): the kernel's cost per
@@ -1801,7 +1800,7 @@ class BatchScheduler:
             if gang_units is not None:
                 from .kernels.gang import gang_schedule_batch
                 assign_d, scores_d, new_usage = gang_schedule_batch(
-                    node_cfg, usage, batch.device(self.mirror.mesh),
+                    node_cfg, usage, batch.device(),
                     self._gang_device_table(gang_units, batch), nom_dev)
             elif batch._class_tables is not None \
                     and sharding_mod.use_shard_map(self.mirror.mesh,
@@ -1815,7 +1814,7 @@ class BatchScheduler:
                     self.sched_metrics.sharded_batches.inc()
                 assign_d, scores_d, new_usage = schedule_batch_sharded(
                     self.mirror.mesh, node_cfg, usage,
-                    batch.device(self.mirror.mesh), nom_dev)
+                    batch.device(), nom_dev)
             elif self.speculative and batch._class_tables is not None:
                 # speculative cohort assignment (kernels/speculative.py):
                 # vmapped K-pod cohort proposals against the frozen class
@@ -1838,10 +1837,9 @@ class BatchScheduler:
                     batch.spec_plain = None
                     batch.cohort_id = None
                     assign_d, scores_d, new_usage = schedule_batch(
-                        node_cfg, usage, batch.device(self.mirror.mesh),
-                        nom_dev)
+                        node_cfg, usage, batch.device(), nom_dev)
                 else:
-                    dev = batch.device(self.mirror.mesh)
+                    dev = batch.device()
                     assign_d, scores_d, new_usage, spec_stats = \
                         schedule_batch_speculative(node_cfg, usage, dev,
                                                    nom_dev, width=w)
@@ -1849,7 +1847,7 @@ class BatchScheduler:
                         spec_inputs = (node_cfg, usage, dev, nom_dev)
             else:
                 assign_d, scores_d, new_usage = schedule_batch(
-                    node_cfg, usage, batch.device(self.mirror.mesh), nom_dev)
+                    node_cfg, usage, batch.device(), nom_dev)
             if self.sched_metrics is not None and self.mirror.mesh is not None:
                 # padding added for shard divisibility is VISIBLE (KTPU005):
                 # the gauge tracks the mirror's current shard-pad rows
@@ -2089,14 +2087,14 @@ class BatchScheduler:
         dom_tab = np.full((K, N), -1, np.int32)
         if dom_rows:
             dom_tab[:len(dom_rows)] = np.stack(dom_rows)
-        put = self.mirror.put_replicated
-        out = {"pod_idx": put(pod_idx), "start": put(start),
-               "end": put(end), "gang_id": put(gang_id),
-               "entry_dom_idx": put(entry_dom), "pin_dom": put(pin_dom),
-               "need": put(need), "greq": put(greq),
-               # node axis shards with the mirror, by the name-keyed rule
-               "dom_tab": self.mirror.put_named("dom_tab", dom_tab)}
-        return out
+        from .kernels.batch import pack_inputs
+        # the entry vectors cross in one buffer; dom_tab's node axis
+        # shards with the mirror, by the name-keyed rule, on its own
+        return pack_inputs(self.mirror.put_named, {
+            "pod_idx": pod_idx, "start": start, "end": end,
+            "gang_id": gang_id, "entry_dom_idx": entry_dom,
+            "pin_dom": pin_dom, "need": need, "greq": greq,
+            "dom_tab": dom_tab})
 
     def _nominated_device(self) -> Optional[dict]:
         """Aggregated nominated-pod reservations as device tensors
@@ -2142,8 +2140,8 @@ class BatchScheduler:
             self._nom_dev = None
         else:
             # node-axis tensors: shard with the mirror's mesh
-            self._nom_dev = {"used": self.mirror.put_nodes(used),
-                             "count": self.mirror.put_nodes(count)}
+            self._nom_dev = {"used": self.mirror.put_named("used", used),
+                             "count": self.mirror.put_named("count", count)}
         #: pod key -> reserved row, exactly as charged into _nom_dev
         self._nom_rows_by_key = rows_by_key
         self._nom_key = key

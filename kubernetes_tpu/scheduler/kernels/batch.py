@@ -36,6 +36,9 @@ Deployment's pods share selectors/tolerations) share a row:
     unique_scores [S, N] f32    +  score_idx [P] int32
 U and S are typically 1-8 where P is thousands, so per-batch upload is
 O(P*R + U*N), a few hundred KB instead of the dense O(P*N) hundreds of MB.
+And every transfer has a fixed price however few bytes it carries, so the
+small replicated arrays of a launch cross in ONE buffer (pack_inputs /
+unpack_inputs, the mirror image of pack_results), not one transfer each.
 
 Scores follow the reference's integer arithmetic (LeastRequested
 least_requested.go:53, BalancedAllocation balanced_resource_allocation.go:77)
@@ -48,6 +51,7 @@ intent (:286-296); parity fixtures compare score classes, not tie order.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Tuple
 
@@ -272,6 +276,7 @@ def filter_score(node_cfg: dict, usage: dict, pod_batch: dict
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The full pods x nodes mask + score matrix against the frozen snapshot
     (no in-batch usage updates). vmap over the pod axis."""
+    pod_batch = unpack_inputs(pod_batch)
     per_pod, unique_masks, unique_scores, rw = _split_batch(pod_batch)
     N = node_cfg["alloc"].shape[0]
     spread_base, zone_of, zinit, spread_w = _spread_tables(pod_batch, N)
@@ -724,6 +729,7 @@ def schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
     credits, and nominated reservations now ride it as carried state.
     The classic per-pod recompute below remains as the one-source parity
     control (KTPU_CLASS_SCAN=0, hand-built batches in tests)."""
+    pod_batch = unpack_inputs(pod_batch)
     if "class_req" in pod_batch:
         return _schedule_batch_classes(node_cfg, usage, pod_batch, nom)
     per_pod, unique_masks, unique_scores, rw = _split_batch(pod_batch)
@@ -1164,6 +1170,7 @@ def schedule_batch_sharded(mesh, node_cfg: dict, usage: dict,
     nominated-reservation overlay, sharded with the mirror rows."""
     from jax.sharding import PartitionSpec as P
     from ..sharding import NODE_AXIS, spec_for
+    pod_batch = unpack_inputs(pod_batch)
     cfg_specs = {k: spec_for(k, jnp.ndim(v)) for k, v in node_cfg.items()}
     usage_specs = {k: spec_for(k, jnp.ndim(v)) for k, v in usage.items()}
     batch_specs = {k: spec_for(k, jnp.ndim(v)) for k, v in pod_batch.items()}
@@ -1193,17 +1200,22 @@ def schedule_batch_sharded(mesh, node_cfg: dict, usage: dict,
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
-def apply_dirty(node_cfg: dict, usage: dict, idx: jnp.ndarray,
-                cfg_rows: dict, usage_rows: dict) -> Tuple[dict, dict]:
+def apply_dirty(node_cfg: dict, usage: dict, rows) -> Tuple[dict, dict]:
     """Scatter O(delta) dirty rows (cache.go:210-246's generation scan,
-    shipped as one packed upload) into the device-resident state. Padded
-    slots carry an OUT-OF-RANGE row index (the mirror pads with
-    `capacity`, one past the last row) and are dropped by the scatter's
-    mode="drop" — a pad row must never alias row 0 or clamp onto the last
-    real row (covered by tests/test_pipeline.py's pad-row fixture)."""
-    new_cfg = {k: node_cfg[k].at[idx].set(cfg_rows[k], mode="drop")
+    shipped as one packed upload) into the device-resident state. `rows`
+    is the PackedInputs (or plain dict) of "idx" [D] plus one [D, ...]
+    row block "<key>_rows" per key of node_cfg and usage (row blocks are
+    replicated, not node-axis data, so no partition rule may match their
+    names). Padded slots carry an OUT-OF-RANGE row index (the mirror
+    pads with `capacity`, one past the last row) and are dropped by the
+    scatter's mode="drop" — a pad row must never alias row 0 or clamp
+    onto the last real row (covered by tests/test_pipeline.py's pad-row
+    fixture)."""
+    rows = unpack_inputs(rows)
+    idx = rows["idx"]
+    new_cfg = {k: node_cfg[k].at[idx].set(rows[k + "_rows"], mode="drop")
                for k in node_cfg}
-    new_usage = {k: usage[k].at[idx].set(usage_rows[k], mode="drop")
+    new_usage = {k: usage[k].at[idx].set(rows[k + "_rows"], mode="drop")
                  for k in usage}
     return new_cfg, new_usage
 
@@ -1219,3 +1231,100 @@ def unpack_results(packed) -> Tuple[jnp.ndarray, jnp.ndarray]:
     import numpy as np
     arr = np.asarray(packed)
     return arr[0], arr[1].view(np.float32)
+
+
+#: segments of the packed buffer start on a multiple of this many 32-bit
+#: words (the chip's 128 lanes), so the static slices that cut it apart
+#: again start on a lane-row edge
+_PACK_ALIGN = 128
+#: a host array larger than this ships on its own: copying it into the
+#: buffer would cost the host more than the transfer it saves
+PACK_MAX_BYTES = 1 << 20
+#: what the buffer can carry, and the letter the layout keeps for it
+_PACK_KINDS = {"float32": "f", "int32": "i", "bool": "b"}
+
+
+@jax.tree_util.register_pytree_with_keys_class
+class PackedInputs:
+    """The host inputs of one launch — the mirror image of pack_results:
+    every small replicated array in ONE int32 buffer (`words`) so a
+    launch costs one host->device transfer for them, not one an array,
+    plus `rest`, the arrays that crossed on their own or were on the
+    device already. `layout` is ((name, kind, shape, offset), ...) of the
+    buffer's segments: static, a function of the arrays' names, dtypes
+    and shapes alone, so it keys the jitted programs exactly as the dict
+    of arrays it replaces did. The kernels never see this class: each
+    jitted entry point calls unpack_inputs first and reads the dict."""
+
+    def __init__(self, words, rest: dict, layout: tuple):
+        self.words, self.rest, self.layout = words, rest, layout
+
+    def tree_flatten_with_keys(self):
+        return ((jax.tree_util.GetAttrKey("words"), self.words),
+                (jax.tree_util.GetAttrKey("rest"), self.rest)), self.layout
+
+    def tree_flatten(self):
+        return (self.words, self.rest), self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        return cls(*children, layout)
+
+
+def pack_inputs(put, arrays: dict) -> PackedInputs:
+    """Ship `arrays` to the device in a bounded number of transfers.
+    `put(name, host_array)` issues one transfer, placed by the name-keyed
+    partition rules (TensorMirror.put_named). What goes where is read off
+    each array, never off a flag:
+
+      - already on the device (a jax.Array: the epoch-cached anti_dom
+        table, chained state): passed through;
+      - placed on the node axis by a rule of sharding.spec_for
+        (unique_masks, unique_scores, spread_base, spread_zone, anti_dom,
+        soft_dom, soft_base, dom_tab): its own transfer, as before — it
+        is large ([U, N]) and sharded under a mesh;
+      - replicated, float32 / int32 / bool and at most PACK_MAX_BYTES:
+        laid into the one int32 buffer (float32 by bit pattern, bool one
+        word an element), which crosses once and replicates under a mesh
+        exactly as each of its arrays did;
+      - anything else replicated: its own transfer."""
+    import numpy as np
+    from jax.sharding import PartitionSpec
+    from ..sharding import spec_for
+    rest, layout, segments, total = {}, [], [], 0
+    for name, a in arrays.items():
+        if isinstance(a, jax.Array):
+            rest[name] = a
+            continue
+        a = np.asarray(a)
+        kind = _PACK_KINDS.get(a.dtype.name)
+        if kind is None or a.nbytes > PACK_MAX_BYTES \
+                or spec_for(name, a.ndim) != PartitionSpec():
+            rest[name] = put(name, a)
+            continue
+        layout.append((name, kind, a.shape, total))
+        segments.append(a)
+        total += -(-a.size // _PACK_ALIGN) * _PACK_ALIGN
+    words = np.zeros((total,), np.int32)
+    for (_, kind, _, off), a in zip(layout, segments):
+        flat = a.ravel()
+        words[off:off + flat.size] = flat if kind == "b" \
+            else flat.view(np.int32)
+    return PackedInputs(put("packed_inputs", words), rest, tuple(layout))
+
+
+def unpack_inputs(inputs) -> dict:
+    """The dict of arrays a kernel reads: PackedInputs cut back into its
+    names by static slices (same dtype, shape and bits as the host
+    arrays that went in), INSIDE the jitted program that consumes them.
+    A plain dict (hand-built batches in tests, bench fixtures) passes
+    through."""
+    if not isinstance(inputs, PackedInputs):
+        return inputs
+    out = dict(inputs.rest)
+    for name, kind, shape, off in inputs.layout:
+        seg = lax.slice(inputs.words, (off,),
+                        (off + math.prod(shape),)).reshape(shape)
+        out[name] = seg if kind == "i" else seg != 0 if kind == "b" \
+            else lax.bitcast_convert_type(seg, jnp.float32)
+    return out

@@ -64,7 +64,7 @@ from jax import lax
 
 from .batch import (COL_CPU, COL_MEM, NEG, _pod_feasible, _pod_score,
                     _soft_raw, _soft_score, _soft_tables, _soft_write,
-                    _split_batch, _tie_penalized)
+                    _split_batch, _tie_penalized, unpack_inputs)
 
 #: entries per scan step (unrolled inside, same op sequence — see
 #: batch.py's step grouping); must divide the bucketed T (a power of two)
@@ -108,6 +108,7 @@ def gang_schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
     schedule_batch takes — a mixed batch's singletons must not steal a
     preemptor's freed space just because a gang member rode along.
     """
+    pod_batch, gang_tab = unpack_inputs(pod_batch), unpack_inputs(gang_tab)
     per_pod, unique_masks, unique_scores, rw = _split_batch(pod_batch)
     N = node_cfg["alloc"].shape[0]
     P = per_pod["seq"].shape[0]

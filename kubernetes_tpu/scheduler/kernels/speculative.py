@@ -81,7 +81,8 @@ from jax import lax
 
 from .batch import (NEG, _NEG_THRESHOLD, _class_col, _class_ctx,
                     _class_pod_step, _class_usage_out, _soft_write,
-                    _tie_penalized, _topo_scatter, schedule_batch)
+                    _tie_penalized, _topo_scatter, schedule_batch,
+                    unpack_inputs)
 
 #: pods per speculative cohort (power of two; clamped to the pod-bucket
 #: size). Wider cohorts amortize more step latency when clean but make
@@ -241,6 +242,7 @@ def schedule_batch_speculative(node_cfg: dict, usage: dict,
     `width` is STATIC (callers pass cohort_width(P)): the cohort width
     is part of the compiled scan's shape, and threading it as a traced
     value would silently reuse whichever width compiled first."""
+    pod_batch = unpack_inputs(pod_batch)
     ctx, carry0, per_pod = _class_ctx(node_cfg, usage, pod_batch, nom)
     P = per_pod["seq"].shape[0]
     K = min(max(1, width), P)

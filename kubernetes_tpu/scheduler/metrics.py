@@ -201,6 +201,16 @@ class SchedulerMetrics:
         self.loop_errors = r.counter(
             "scheduler_loop_errors_total",
             "Exceptions raised out of a scheduling cycle in the run loop")
+        # host->device transfers of the launch path, counted where each
+        # is issued (TensorMirror.put_named: the shell installs this
+        # counter on the algorithm's mirror): the packed batch, the mask
+        # and score tables, the packed dirty-row scatter, and whatever
+        # else a launch ships. Over the cycles, what a launch costs in
+        # the runtime's fixed price a transfer
+        self.host_to_device_transfers = r.counter(
+            "scheduler_host_to_device_transfers_total",
+            "Host-to-device transfers issued by the launch path")
+        self.host_to_device_transfers.declare()
         # ---- sharded drain (mesh execution substrate) ----
         # batches routed through the shard_map kernel (per-shard
         # filter+score, cross-shard argmax) vs the GSPMD/single paths

@@ -210,6 +210,9 @@ class Scheduler:
             extenders=self.extenders, mesh=mesh)
         #: in-scan fallback counters (scheduler_topo_inscan_fallbacks_total)
         self.algorithm.sched_metrics = self.metrics
+        #: scheduler_host_to_device_transfers_total, counted at put_named
+        self.algorithm.mirror.transfers = \
+            self.metrics.host_to_device_transfers
         # speculative cohort assignment (kernels/speculative.py): the
         # constructor argument overrides KTPU_SPECULATIVE (which the
         # BatchScheduler read at construction) — explicit beats ambient

@@ -40,6 +40,7 @@ import numpy as np
 
 from ..api import helpers, wellknown
 from ..api.core import Pod
+from ..utils.metrics import Counter
 from .cache import Snapshot
 from .nodeinfo import NodeInfo
 from .predicates import _pod_qos, _pressure_taint
@@ -172,6 +173,10 @@ class TensorMirror:
         #: resurrect phantom usage that invalidation just dropped.
         self.usage_epoch = 0
         self._usage_lock = threading.Lock()
+        #: host->device transfers put_named has issued. The shell installs
+        #: SchedulerMetrics' scheduler_host_to_device_transfers_total
+        #: here; a bare mirror counts on a counter of its own
+        self.transfers = Counter("scheduler_host_to_device_transfers_total")
 
     def _capacity_for(self, need: int, minimum: int = 128) -> int:
         """Row capacity for `need` nodes: the power-of-two bucket, padded
@@ -327,35 +332,23 @@ class TensorMirror:
 
     def put_named(self, name: str, arr):
         """Host array -> device, placed by the name-keyed partition rules
-        (sharding.spec_for) — plain transfer when no mesh is active."""
+        (sharding.spec_for) — plain transfer when no mesh is active; a
+        name no rule matches replicates. The ONE place the launch path
+        issues a host->device transfer, so the one place they are
+        counted (`transfers`)."""
         from .sharding import put
+        self.transfers.inc()
         return put(self.mesh, name, arr)
-
-    def put_nodes(self, arr):
-        """Host array -> device, sharded over the mesh's node axis (or a
-        plain transfer single-device). For tensors whose NAME carries the
-        rule, prefer put_named."""
-        import jax
-        import jax.numpy as jnp
-        if self.mesh is None:
-            return jnp.asarray(arr)
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        spec = P("nodes") if np.ndim(arr) == 1 else P("nodes", None)
-        return jax.device_put(arr, NamedSharding(self.mesh, spec))
-
-    def put_replicated(self, arr):
-        import jax
-        import jax.numpy as jnp
-        if self.mesh is None:
-            return jnp.asarray(arr)
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        return jax.device_put(arr, NamedSharding(self.mesh, P()))
 
     def device_cfg_usage(self) -> Tuple[dict, dict]:
         """The (node_cfg, usage) pytrees on device. Dirty rows ship as ONE
-        packed scatter (kernels.apply_dirty); full upload only after a
-        capacity/column resize."""
-        import jax.numpy as jnp
+        packed scatter (kernels.apply_dirty): the row index and the row
+        block of every cfg and usage key cross in ONE buffer
+        (kernels.batch.pack_inputs — all are small and replicated, so
+        none is left to a transfer of its own) and are cut apart inside
+        apply_dirty. Full upload, one transfer a key because each is
+        sharded on the node axis, only after a capacity/column resize or
+        invalidate_usage."""
         t = self.t
         if self._device_cfg is None or self._device_usage is None:
             # resize or invalidate_usage: both re-uploaded from host truth
@@ -364,20 +357,19 @@ class TensorMirror:
             self._device_usage = {k: self.put_named(k, v)
                                   for k, v in t.usage_arrays().items()}
         elif self._dirty_rows:
-            from .kernels.batch import apply_dirty
+            from .kernels.batch import apply_dirty, pack_inputs
             idx = np.fromiter(self._dirty_rows, dtype=np.int32,
                               count=len(self._dirty_rows))
             D = _bucket(len(idx), minimum=8)
             # pad with an out-of-range row; apply_dirty drops it
-            pad = np.full((D,), t.capacity, np.int32)
-            pad[:len(idx)] = idx
-            cfg_rows = {k: self.put_replicated(_padded_rows(v, idx, D))
-                        for k, v in t.cfg_arrays().items()}
-            usage_rows = {k: self.put_replicated(_padded_rows(v, idx, D))
-                          for k, v in t.usage_arrays().items()}
+            rows = {"idx": np.full((D,), t.capacity, np.int32)}
+            rows["idx"][:len(idx)] = idx
+            for k, v in (*t.cfg_arrays().items(),
+                         *t.usage_arrays().items()):
+                rows[k + "_rows"] = _padded_rows(v, idx, D)
             self._device_cfg, self._device_usage = apply_dirty(
                 self._device_cfg, self._device_usage,
-                self.put_replicated(pad), cfg_rows, usage_rows)
+                pack_inputs(self.put_named, rows))
         self._dirty_rows.clear()
         return self._device_cfg, self._device_usage
 
@@ -939,81 +931,65 @@ class PodBatchTensors:
         fits = fits & (self.req[i][None, :] <= free).all(axis=1)
         return fits
 
-    def device(self, mesh=None) -> dict:
-        import jax.numpy as jnp
-        from . import sharding
-        if mesh is None:
-            put = jnp.asarray
-
-            def mask_put(name, a):
-                return jnp.asarray(a)
-        else:
-            # pod axes replicate; the mask/score tables' NODE axis shards
-            # with the mirror (each core sees every pod, owns a node
-            # shard) — both resolved by the name-keyed rule table
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            repl = NamedSharding(mesh, P())
-
-            def put(a):
-                return jax.device_put(np.asarray(a), repl)
-
-            def mask_put(name, a):
-                return sharding.put(mesh, name, a)
-        out = {"req": put(self.req),
-               "nonzero_req": put(self.nonzero_req),
-               "mem_pressure_blocked": put(self.mem_pressure_blocked),
-               "active": put(self.active),
-               "seq": put(self.seq),
-               "mask_idx": put(self.mask_idx),
-               "score_idx": put(self.score_idx),
-               "nom_row": put(self.nom_row),
-               "unique_masks": mask_put("unique_masks", self.unique_masks),
-               "unique_scores": mask_put("unique_scores",
-                                         self.unique_scores),
-               "resource_weights": put(self.resource_weights)}
+    def device(self):
+        """The batch on the device, as kernels.batch.PackedInputs: every
+        small replicated array (the pod-axis vectors, the class tables,
+        resource_weights, the spread / topology / soft term lists and
+        the two weights) in ONE buffer so a launch costs a single
+        host->device transfer for them, cut back into the same names
+        inside the jitted kernel (unpack_inputs). What a partition rule
+        places on the node axis (unique_masks, unique_scores,
+        spread_base, spread_zone, anti_dom, soft_dom, soft_base) still
+        crosses on its own: it is large and shards under a mesh; the
+        epoch-cached anti_dom_dev is on the device already.
+        pack_inputs reads which is which off each array."""
+        from .kernels.batch import pack_inputs
+        out = {"req": self.req,
+               "nonzero_req": self.nonzero_req,
+               "mem_pressure_blocked": self.mem_pressure_blocked,
+               "active": self.active,
+               "seq": self.seq,
+               "mask_idx": self.mask_idx,
+               "score_idx": self.score_idx,
+               "nom_row": self.nom_row,
+               "unique_masks": self.unique_masks,
+               "unique_scores": self.unique_scores,
+               "resource_weights": self.resource_weights}
         if self.spread_base is not None:
-            import jax.numpy as jnp
-            out["spread_gidx"] = put(self.spread_gidx)
-            out["spread_match"] = put(self.spread_match)
-            out["spread_base"] = mask_put("spread_base", self.spread_base)
+            out["spread_gidx"] = self.spread_gidx
+            out["spread_match"] = self.spread_match
+            out["spread_base"] = self.spread_base
             # the zone-id vector is node-axis data: it shards with the
             # mirror rows so the shard_map kernel's local slice aligns
-            out["spread_zone"] = mask_put("spread_zone", self.spread_zone)
-            out["spread_zinit"] = put(self.spread_zinit)
-            out["spread_weight"] = jnp.float32(self.spread_weight)
+            out["spread_zone"] = self.spread_zone
+            out["spread_zinit"] = self.spread_zinit
+            out["spread_weight"] = np.float32(self.spread_weight)
         if self.anti_dom is not None:
             # the dom table may already sit on device, epoch-cached and
             # sharded by the topology index (set_topology_terms dom_dev)
             out["anti_dom"] = self.anti_dom_dev \
-                if self.anti_dom_dev is not None \
-                else mask_put("anti_dom", self.anti_dom)
-            out["anti_cnt0"] = put(self.anti_cnt0)
-            out["anti_tids"] = put(self.anti_tids)
-            out["aff_tids"] = put(self.aff_tids)
-            out["match_tids"] = put(self.match_tids)
+                if self.anti_dom_dev is not None else self.anti_dom
+            out["anti_cnt0"] = self.anti_cnt0
+            out["anti_tids"] = self.anti_tids
+            out["aff_tids"] = self.aff_tids
+            out["match_tids"] = self.match_tids
             if self.cmatch_tids is not None:
-                out["cmatch_tids"] = put(self.cmatch_tids)
-                out["canti_tids"] = put(self.canti_tids)
+                out["cmatch_tids"] = self.cmatch_tids
+                out["canti_tids"] = self.canti_tids
         if self.soft_dom is not None:
-            import jax.numpy as jnp
-            out["soft_dom"] = mask_put("soft_dom", self.soft_dom)
-            out["soft_cnt0"] = put(self.soft_cnt0)
-            out["soft_base"] = mask_put("soft_base", self.soft_base)
-            out["soft_base_idx"] = put(self.soft_base_idx)
-            out["soft_read_tids"] = put(self.soft_read_tids)
-            out["soft_read_w"] = put(self.soft_read_w)
-            out["soft_write_tids"] = put(self.soft_write_tids)
-            out["soft_write_w"] = put(self.soft_write_w)
-            out["soft_weight"] = jnp.float32(self.soft_weight)
+            out["soft_dom"] = self.soft_dom
+            out["soft_cnt0"] = self.soft_cnt0
+            out["soft_base"] = self.soft_base
+            out["soft_base_idx"] = self.soft_base_idx
+            out["soft_read_tids"] = self.soft_read_tids
+            out["soft_read_w"] = self.soft_read_w
+            out["soft_write_tids"] = self.soft_write_tids
+            out["soft_write_w"] = self.soft_write_w
+            out["soft_weight"] = np.float32(self.soft_weight)
         if self._class_tables is not None:
-            ct = self._class_tables
-            for k in ("class_req", "class_nz", "class_blocked",
-                      "class_mask_idx", "class_score_idx"):
-                out[k] = put(ct[k])
-            out["class_idx"] = put(ct["class_idx"])
+            out.update(self._class_tables)
         if self.spec_plain is not None:
             # pod-axis cohort vector; replicates by the named rule
             # (sharding._COHORT_REPLICATED)
-            out["spec_plain"] = mask_put("spec_plain", self.spec_plain)
-        return out
+            out["spec_plain"] = self.spec_plain
+        return pack_inputs(self._mirror.put_named, out)

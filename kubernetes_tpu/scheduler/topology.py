@@ -662,12 +662,12 @@ class TopologyIndex:
             self._table_cache[terms] = (self.dom_epoch, cap, dom, n_domains)
         return dom, n_domains
 
-    def term_table_device(self, terms: Tuple[int, ...], mesh,
+    def term_table_device(self, terms: Tuple[int, ...],
                           use_cache: bool = True, dom=None,
                           n_domains: Optional[int] = None):
-        """(padded [T, capacity] dom table ON DEVICE sharded by the
-        name-keyed rules, n_domains) — the device half of term_table for
-        the sharded drain. T is bucketed exactly like
+        """(padded [T, capacity] dom table ON DEVICE sharded over the
+        mirror's mesh by the name-keyed rules, n_domains) — the device
+        half of term_table for the sharded drain. T is bucketed exactly like
         PodBatchTensors.set_topology_terms (power of two, min 8) so the
         cached upload can be handed to it as dom_dev. Epoch-cached with
         the same (dom_epoch, capacity) key as the host table: steady
@@ -676,7 +676,6 @@ class TopologyIndex:
         that already built the host table passes (dom, n_domains) so a
         cache-disabled run (KTPU_TOPO_TABLE_CACHE=0) does not build it
         twice."""
-        from .sharding import put
         from .tensorize import _bucket
         cap = self.mirror.t.capacity
         T = _bucket(len(terms), minimum=8)
@@ -691,7 +690,7 @@ class TopologyIndex:
             dom, n_domains = self.term_table(terms, use_cache=use_cache)
         dom_p = np.full((T, cap), -1, np.int32)
         dom_p[:dom.shape[0]] = dom
-        dev = put(mesh, "anti_dom", dom_p)
+        dev = self.mirror.put_named("anti_dom", dom_p)
         self.table_dev_builds += 1
         if use_cache:
             if len(self._table_dev_cache) > 64:

@@ -132,8 +132,9 @@ def _shapes(tree, sharding, widen=None):
 
     def one(path, a):
         shape = tuple((widen or {}).get(d, d) for d in np.shape(a))
-        s = sharding(path[-1].key, len(shape)) if callable(sharding) \
-            else sharding
+        # a dict's key, or PackedInputs' own field ("words": replicated)
+        s = sharding(getattr(path[-1], "key", None) or path[-1].name,
+                     len(shape)) if callable(sharding) else sharding
         return jax.ShapeDtypeStruct(shape, a.dtype, sharding=s)
     return jax.tree_util.tree_map_with_path(one, tree)
 
@@ -145,11 +146,18 @@ def class_batch():
     from kubernetes_tpu.scheduler.kernels import batch as kb
     args, _ = _capture(_scheduler(), _mixed_pods(16384), kb,
                        "schedule_batch")
-    batch = args[2]
+    batch = kb.unpack_inputs(args[2])
     assert batch["class_req"].ndim == 2 and "anti_dom" in batch \
         and "spread_base" in batch
     assert batch["req"].shape[0] == 16384
     assert batch["unique_masks"].shape[1] == CAPACITY
+    # what a rule places on the node axis crosses on its own, and so do
+    # the 4 MB of anti_cnt0 (128 terms x 8192 hostnames: over
+    # PACK_MAX_BYTES); the pod-axis vectors and the class tables ride
+    # the one buffer
+    assert set(args[2].rest) == {"unique_masks", "unique_scores",
+                                 "anti_dom", "spread_base", "spread_zone",
+                                 "anti_cnt0"}
     return args
 
 
@@ -172,7 +180,7 @@ def test_class_scan_tail_bucket(one_chip):
     from kubernetes_tpu.scheduler.kernels import batch as kb
     args, _ = _capture(_scheduler(), _mixed_pods(1000), kb,
                        "schedule_batch")
-    assert args[2]["req"].shape[0] == 1024
+    assert kb.unpack_inputs(args[2])["req"].shape[0] == 1024
     _compile(kb.schedule_batch, *_shapes(args, one_chip))
 
 
@@ -182,7 +190,8 @@ def test_classic_scan(one_chip):
     from kubernetes_tpu.scheduler.kernels import batch as kb
     args, _ = _capture(_scheduler(class_scan=False), _mixed_pods(16384),
                        kb, "schedule_batch")
-    assert "class_req" not in args[2] and "anti_dom" in args[2]
+    names = kb.unpack_inputs(args[2])
+    assert "class_req" not in names and "anti_dom" in names
     _compile(kb.schedule_batch, *_shapes(args, one_chip))
 
 
@@ -197,7 +206,7 @@ def test_speculative_scan(one_chip):
         p.metadata.labels["app"] = "other"
     args, kwargs = _capture(_scheduler(speculative=True, carriers=False),
                             pods, ks, "schedule_batch_speculative")
-    assert kwargs == {"width": 16} and "spread_base" in args[2]
+    assert kwargs == {"width": 16} and "spread_base" in args[2].rest
     _compile(ks.schedule_batch_speculative, *_shapes(args, one_chip),
              width=16)
 
@@ -216,21 +225,23 @@ def test_gang_scan(one_chip):
     sched.algorithm.gang = _Gangs()
     pods = [bench.make_pod(i) for i in range(16384)]
     args, _ = _capture(sched, pods, kg, "gang_schedule_batch")
-    assert args[3]["dom_tab"].shape[1] == CAPACITY
+    assert args[3].rest["dom_tab"].shape[1] == CAPACITY
     _compile(kg.gang_schedule_batch, *_shapes(args, one_chip))
 
 
 def test_apply_dirty_and_pack_results(class_batch, one_chip):
     import jax
     from kubernetes_tpu.scheduler.kernels.batch import (apply_dirty,
+                                                        pack_inputs,
                                                         pack_results)
     cfg, usage = class_batch[0], class_batch[1]
     D = 1024
-    rows = lambda d: {k: np.zeros((D,) + np.shape(v)[1:], v.dtype)
-                      for k, v in d.items()}
-    _compile(apply_dirty, *_shapes(
-        (cfg, usage, np.zeros((D,), np.int32), rows(cfg), rows(usage)),
-        one_chip))
+    rows = {"idx": np.zeros((D,), np.int32)}
+    for k, v in (*cfg.items(), *usage.items()):
+        rows[k + "_rows"] = np.zeros((D,) + np.shape(v)[1:], v.dtype)
+    rows = pack_inputs(lambda name, a: a, rows)
+    assert rows.rest == {}
+    _compile(apply_dirty, *_shapes((cfg, usage, rows), one_chip))
     _compile(pack_results,
              jax.ShapeDtypeStruct((16384,), np.int32, sharding=one_chip),
              jax.ShapeDtypeStruct((16384,), np.float32, sharding=one_chip))
@@ -337,7 +348,7 @@ def test_sharded_class_scan(class_batch, mesh4):
     place = lambda name, ndim: NamedSharding(mesh4, spec_for(name, ndim))
     shapes = _shapes((cfg, usage, batch), place,
                      widen={CAPACITY: SHARDED_CAPACITY})
-    assert shapes[2]["anti_dom"].shape[1] == SHARDED_CAPACITY
+    assert shapes[2].rest["anti_dom"].shape[1] == SHARDED_CAPACITY
     compiled = _compile(schedule_batch_sharded, mesh4, *shapes)
     text = compiled.as_text()
     # winner election (pmax + pmin), the owner's score broadcast, the

@@ -343,21 +343,27 @@ class TestMirrorGrowAndDirtyScatter:
         # row 0 and the LAST row kept their values (no alias, no clamp)
         assert np.asarray(cfg["valid"])[0] and np.asarray(cfg["valid"])[7]
 
-    def test_apply_dirty_out_of_range_index_is_noop(self):
+    @pytest.mark.parametrize("packed", [False, True],
+                             ids=["plain_dict", "packed_buffer"])
+    def test_apply_dirty_out_of_range_index_is_noop(self, packed):
         """kernels.apply_dirty directly: an all-pad index vector (every
-        slot out of range) must leave the device state untouched."""
+        slot out of range) must leave the device state untouched, as a
+        plain dict of rows and cut out of the one packed buffer."""
         import jax.numpy as jnp
         import numpy as np
-        from kubernetes_tpu.scheduler.kernels.batch import apply_dirty
+        from kubernetes_tpu.scheduler.kernels.batch import (apply_dirty,
+                                                            pack_inputs)
         N, R = 8, 4
         cfg = {"alloc": jnp.arange(N * R, dtype=jnp.float32).reshape(N, R)}
         usage = {"used": jnp.ones((N, R), jnp.float32)}
-        idx = jnp.full((4,), N, jnp.int32)           # all out of range
-        cfg_rows = {"alloc": jnp.full((4, R), -7.0)}  # poison, must drop
-        usage_rows = {"used": jnp.full((4, R), -7.0)}
+        rows = {"idx": np.full((4,), N, np.int32),      # all out of range
+                "alloc_rows": np.full((4, R), -7.0, np.float32),  # poison
+                "used_rows": np.full((4, R), -7.0, np.float32)}
+        if packed:
+            rows = pack_inputs(lambda name, a: jnp.asarray(a), rows)
+            assert rows.rest == {} and len(rows.layout) == 3
         before_cfg = np.asarray(cfg["alloc"]).copy()
         before_usage = np.asarray(usage["used"]).copy()
-        new_cfg, new_usage = apply_dirty(cfg, usage, idx, cfg_rows,
-                                         usage_rows)
+        new_cfg, new_usage = apply_dirty(cfg, usage, rows)
         assert np.array_equal(np.asarray(new_cfg["alloc"]), before_cfg)
         assert np.array_equal(np.asarray(new_usage["used"]), before_usage)

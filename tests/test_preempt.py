@@ -454,7 +454,8 @@ class TestGangDomainFeasibility:
         orig = sched._gang_device_table
 
         def no_cap(units, batch):
-            tab = orig(units, batch)
+            from kubernetes_tpu.scheduler.kernels.batch import unpack_inputs
+            tab = unpack_inputs(orig(units, batch))
             tab.pop("need")
             tab.pop("greq")
             return tab

@@ -491,7 +491,9 @@ NEW_RATIOS = [
     # inter-pod affinity machinery takes, and what sizes them
     "sched_topology_apply_ms_per_pod", "sched_affinity_masks_ms_per_pod",
     "sched_affinity_scores_ms_per_pod", "sched_templates_per_cycle",
-    "sched_inscan_fallback_share"]
+    "sched_inscan_fallback_share",
+    # PR 32: what a launch costs in host-to-device transfers
+    "sched_h2d_transfers_per_cycle"]
 NEW_READERS = ["idle_waiting_for_pods_share", "idle_waiting_for_hub_share",
                "idle_unattributed_share"]
 
