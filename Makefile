@@ -1,8 +1,7 @@
-# Convenience entries (the reference's hack/ equivalents).
+# Convenience entries (the reference's hack/ equivalents). The benchmark is
+# benchmarks/run.py (BENCHMARK.json, benchmarks/README.md); it needs the chip.
 
-.PHONY: lint lint-changed test test-tier1 bench-sharded bench-affinity \
-	bench-preempt bench-tenancy bench-resilience bench-wire \
-	bench-overload bench-speculative
+.PHONY: lint lint-changed test
 
 # full contract lint (tools/ktpulint; exit 1 on findings)
 lint:
@@ -15,66 +14,3 @@ lint-changed:
 # tier-1 suite (what the roadmap's verify line runs)
 test:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow'
-
-# sharded drain bench: device-scaling curve + bit-identity parity on 8
-# virtual CPU devices (no TPU needed; see README "Sharded scheduling")
-bench-sharded:
-	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-		python bench.py sharded
-
-# affinity-shape bench: class-scan vs classic (KTPU_CLASS_SCAN=0) across
-# node/pod/anti/spread/soft/nominated fixtures + sharded parity points
-# for the three newly folded shapes (BENCH_r08's source)
-bench-affinity:
-	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-		python bench.py affinity
-
-# preemption-storm bench: batched victim-pricing kernel vs the serial
-# control (KTPU_PREEMPT_KERNEL=0), kernel-vs-oracle decision parity,
-# whole-gang domain pricing, and the autoscaler slice drill
-# (BENCH_r09's source)
-bench-preempt:
-	JAX_PLATFORMS=cpu python bench.py preempt
-
-# resilience bench: the HTTP + HA + replication chaos soak under a
-# seeded fault schedule (wire resets/latency/drops, torn-WAL restarts,
-# leader kills, lease suppression, one promote drill) vs the fault-free
-# control of the same schedule — failover percentiles, per-class p99
-# bind degradation, invariant sweeps (BENCH_r11's source; recurring)
-bench-resilience:
-	JAX_PLATFORMS=cpu python bench.py resilience
-
-# tenant-isolation bench: one abusive tenant's gang storm vs nine
-# steady tenants with DRF + active-gang quota on, the no-tenancy
-# control (KTPU_DRF=0, no quota), and DRF kernel-vs-oracle ordering
-# parity (BENCH_r10's source)
-bench-tenancy:
-	JAX_PLATFORMS=cpu python bench.py tenancy
-
-# wire bench: the BENCH_r12 round — one-shot drain JSON vs binary with
-# bind-decision parity, sustained streaming soak (creation overlapping
-# the drain) baseline vs binary + replica read fan-out, the latency-knee
-# curve with wire faults on, and the 1M-pending-pod streamed drain.
-# Publishes BENCH_r12.json.
-bench-wire:
-	JAX_PLATFORMS=cpu python bench.py wire > BENCH_r12.json
-	@tail -c 400 BENCH_r12.json; echo
-
-# overload bench: the BENCH_r13 round — tenant LIST/create client storm
-# against a tiny hub, APF on (fair queues + priority levels) vs the
-# storm-free baseline and the no-APF instant-shed control: system-
-# traffic p99 isolation ratio, slow lease renews, per-level 429s,
-# same-seed determinism. Publishes BENCH_r13.json.
-bench-overload:
-	JAX_PLATFORMS=cpu python bench.py overload > BENCH_r13.json
-	@tail -c 400 BENCH_r13.json; echo
-
-# speculative-cohort bench: the BENCH_r14 round — cohort assignment
-# (KTPU_SPECULATIVE=1) vs the serial class scan at the cohort-friendly
-# 2k x 1k and the 50k x 5k wire shapes on uniform/anti-affinity/spread
-# mixes: scan-only + end-to-end speedups, per-variant bind parity,
-# collision/repair rates, cohort-width distribution.
-# Publishes BENCH_r14.json.
-bench-speculative:
-	JAX_PLATFORMS=cpu python bench.py speculative > BENCH_r14.json
-	@tail -c 400 BENCH_r14.json; echo

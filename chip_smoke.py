@@ -22,10 +22,10 @@ pending pods x 5,000 nodes; fake-node shape 4 CPU / 32 Gi / 110 pods):
               The scheduler must have named a TPU at start-up, logged no
               traceback and counted no run-loop error.
   2. parity   With both children gone, this process takes the chip: bind
-              decisions equal the serial oracle on all six bench variants,
-              and the gang / preemption-pricing / speculative / DRF /
-              affinity-template kernels equal their references.
-  3. drain    bench.run_config(5000, 50000, "uniform") through the
+              decisions equal the serial oracle on all six fixture variants,
+              and the gang / preemption-pricing / DRF / affinity-template
+              kernels equal their references.
+  3. drain    fakecluster.run_config(5000, 50000, "uniform") through the
               pipelined drain: all bound, nothing compiled inside the
               timed drain, compile and drain seconds and peak device
               memory printed as smoke output (not benchmark results).
@@ -58,7 +58,7 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 FULL_SIZE = (5000, 40000, 5000, 2500, 2500)
 REHEARSE_SIZE = (100, 800, 100, 50, 50)
 #: the served scheduler's batchSize: the repo's drain default
-#: (bench.BATCH) — at a 50k backlog every full pop lands in the one
+#: (fakecluster.BATCH) — at a 50k backlog every full pop lands in the one
 #: 16384 pod bucket that phase 3 compiles too, so the second process's
 #: compile-cache hits can be seen; a smaller batch would only add buckets
 SERVED_BATCH = 16384
@@ -150,15 +150,16 @@ def scrape(url):
     return out
 
 
-#: bench.make_pod's three request shapes, picked by the seeded generator
+#: fakecluster.make_pod's three request shapes, picked by the seeded
+#: generator
 REQUEST_SHAPES = (("100m", "128Mi"), ("250m", "512Mi"), ("500m", "1Gi"))
 
 
 def seeded_pod(i, variant, rng):
-    """bench.make_pod(i, variant) with its request shape drawn from rng."""
-    import bench
+    """fakecluster.make_pod(i, variant), its request shape drawn from rng."""
+    import fakecluster
     from kubernetes_tpu.api import Quantity
-    pod = bench.make_pod(i, variant)
+    pod = fakecluster.make_pod(i, variant)
     cpu, mem = REQUEST_SHAPES[rng.randrange(len(REQUEST_SHAPES))]
     pod.spec.containers[0].resources.requests = {
         "cpu": Quantity(cpu), "memory": Quantity(mem)}
@@ -166,17 +167,18 @@ def seeded_pod(i, variant, rng):
 
 
 def build_cluster(size, seed):
-    """(nodes, service, pods, kinds): the bench.py fake-node cluster and a
-    seeded pod mix — request shapes drawn from bench.make_pod's three, the
-    constrained pods shuffled together behind the uniform block (so the
-    class scan runs without, then with, in-scan topology/spread carry).
+    """(nodes, service, pods, kinds): the fakecluster.py fake-node cluster
+    and a seeded pod mix — request shapes drawn from fakecluster.make_pod's
+    three, the constrained pods shuffled together behind the uniform block
+    (so the class scan runs without, then with, in-scan topology/spread
+    carry).
     kinds maps pod name -> "uniform" | "node-affinity" |
     "pod-anti-affinity" | "spread"."""
-    import bench
+    import fakecluster
     from kubernetes_tpu import api
     n_nodes, n_uniform, n_nodeaff, n_anti, n_spread = size
     rng = random.Random(seed)
-    nodes = [bench.make_node(i) for i in range(n_nodes)]
+    nodes = [fakecluster.make_node(i) for i in range(n_nodes)]
     # spread pods wear a label of their own; the Service that selects it
     # reaches the scorer through the scheduler's informer-fed lister
     service = api.Service(
@@ -221,7 +223,7 @@ def check_placement(listed, nodes, kinds, n_expected):
         count[nn] = count.get(nn, 0) + 1
         kind = kinds[p.metadata.name]
         if kind == "node-affinity":
-            # bench.make_pod: required zone in zone-0 .. zone-7
+            # fakecluster.make_pod: required zone in zone-0 .. zone-7
             check(int(zone[nn].split("-")[1]) < 8,
                   f"{p.metadata.name} (node-affinity) in {zone[nn]}")
         elif kind == "pod-anti-affinity":
@@ -282,14 +284,14 @@ def phase_served(args, platform, workdir):
         say("served.start", wal=wal_line, scheduler_device=device,
             batch_size=SERVED_BATCH)
 
-        import bench
+        import fakecluster
         from kubernetes_tpu.apiserver import HTTPClient
         from kubernetes_tpu.api import Quantity
         client = HTTPClient(base)
         nodes, service, pods, kinds = build_cluster(size, args.seed)
         if args.inject_unschedulable:
             # a pod no node can hold: the bound-all wait must fail
-            giant = bench.make_pod(len(pods))
+            giant = fakecluster.make_pod(len(pods))
             giant.metadata.name = "never-fits"
             giant.spec.containers[0].resources.requests["cpu"] = \
                 Quantity("64")
@@ -297,8 +299,8 @@ def phase_served(args, platform, workdir):
             kinds["never-fits"] = "uniform"
         t0 = time.time()
         client.services("default").create(service)
-        bench.bulk_create(client.nodes(), nodes)
-        bench.bulk_create(client.pods("default"), pods)
+        fakecluster.bulk_create(client.nodes(), nodes)
+        fakecluster.bulk_create(client.pods("default"), pods)
         created_s = time.time() - t0
         metrics_url = f"http://127.0.0.1:{metrics_port}/metrics"
         scheduled_key = 'scheduler_schedule_attempts_total' \
@@ -372,9 +374,9 @@ def take_device(platform):
 
 
 def kernel_parity(seed):
-    """The gang, preemption-pricing, speculative, DRF and affinity-template
-    kernels against their references, on seeded input (the randomized
-    fixtures of the repo's own tests, reseeded)."""
+    """The gang, preemption-pricing, DRF and affinity-template kernels
+    against their references, on seeded input (the randomized fixtures of
+    the repo's own tests, reseeded)."""
     import numpy as np
     sys.path.insert(0, os.path.join(REPO, "tests"))
     import jax.numpy as jnp
@@ -441,38 +443,6 @@ def kernel_parity(seed):
               and (np.asarray(nv_k) == nv_r).all(),
               f"price_domains differs from its reference (trial {trial})")
     out["preempt"] = f"price_nodes x{priced}, price_domains x6 equal"
-
-    import bench
-    from kubernetes_tpu.scheduler import Scheduler
-    from kubernetes_tpu.state import Client
-
-    def drain(speculative):
-        client = Client(validate=False)
-        sched = Scheduler(client, batch_size=1024, speculative=speculative)
-        sched.algorithm.spec_oracle = speculative   # serial replay on
-        for i in range(256):
-            sched.cache.add_node(bench.make_node(i))
-        rng = random.Random(seed)
-        for i in range(2048):
-            sched.queue.add(client.pods().create(
-                seeded_pod(i, "uniform", rng)))
-        sched.algorithm.refresh()
-        sched.drain_pipelined()
-        return ({p.metadata.name: p.spec.node_name
-                 for p in client.pods().list()}, sched)
-    serial, _ = drain(False)
-    spec, sched = drain(True)
-    m = sched.metrics
-    check(m.speculative_cohorts.value() > 0,
-          "the speculative kernel never ran")
-    check(m.speculative_divergences.value() == 0
-          and not sched.algorithm.spec_divergence_log,
-          f"speculative diverged from speculative_reference: "
-          f"{list(sched.algorithm.spec_divergence_log)[:3]}")
-    check(spec == serial and all(serial.values()),
-          "speculative binds differ from the serial scan's")
-    out["speculative"] = (f"{int(m.speculative_cohorts.value())} cohorts, "
-                          f"0 divergences, binds equal the serial scan")
 
     from test_tenancy import make_pod as tenant_pod
     from kubernetes_tpu.tenancy import DRFAccount
@@ -558,23 +528,23 @@ def kernel_parity(seed):
 
 
 def phase_parity(args):
-    import bench
+    import fakecluster
     size = (200, 50) if args.rehearse else (2000, 500)
     rates = {}
-    for variant in bench.PARITY_VARIANTS:
-        rate, scheduled, _ = bench.measure_parity(variant, *size)
+    for variant in fakecluster.PARITY_VARIANTS:
+        rate, scheduled, _ = fakecluster.measure_parity(variant, *size)
         rates[variant] = rate
         check(scheduled > 0, f"{variant}: the oracle scheduled nothing")
     # the fake node's 4000m / 32Gi are benign divisors; a node with
     # reserved resources puts the integer-floor scores on boundaries the
     # chip's (not correctly rounded) f32 divide misses
     for variant in ("uniform", "pod-affinity"):
-        rate, scheduled, _ = bench.measure_parity(
+        rate, scheduled, _ = fakecluster.measure_parity(
             variant, *size, node_cpu="3900m", node_memory="31Gi")
         rates[f"{variant} @ 3900m/31Gi nodes"] = rate
         check(scheduled > 0, f"{variant}: the oracle scheduled nothing")
     say("parity.decisions", fixture=f"{size[0]} pods x {size[1]} nodes",
-        oracle="serial numpy/int64 replay (bench.measure_parity)",
+        oracle="serial numpy/int64 replay (fakecluster.measure_parity)",
         rates=rates)
     check(set(rates.values()) == {1.0},
           f"bind decisions differ from the serial oracle: {rates}")
@@ -586,18 +556,18 @@ def phase_parity(args):
 
 def phase_drain(args, device):
     import jax
-    import bench
+    import fakecluster
     from kubernetes_tpu.scheduler import compile_log
     n_nodes, n_pods = (100, 1000) if args.rehearse else (5000, 50000)
     compiles = compile_log()
     before = compiles.summary()
-    rate, scheduled, sched, setup_s, elapsed = bench.run_config(
+    rate, scheduled, sched, setup_s, elapsed = fakecluster.run_config(
         n_nodes, n_pods, "uniform", warm_all_buckets=False)
     after = compiles.summary()
-    in_drain = sched.bench_phases["compiles_in_drain"]
+    in_drain = sched.compiles_in_drain
     stats = jax.devices()[0].memory_stats()
     say("drain", nodes=n_nodes, pods=n_pods, bound=scheduled,
-        batch=bench.BATCH, drain_seconds=round(elapsed, 3),
+        batch=fakecluster.BATCH, drain_seconds=round(elapsed, 3),
         setup_seconds=round(setup_s, 1),
         # this process is the second to compile: what it loaded from the
         # cache the scheduler child wrote (hits) vs compiled itself

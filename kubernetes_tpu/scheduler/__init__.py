@@ -42,7 +42,7 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for this process; the
-    entry points (cmd/kube_scheduler, bench.py, chip_smoke.py) call it
+    entry points (cmd/kube_scheduler, chip_smoke.py) call it
     before their first jit. The drain compiles one program per
     power-of-two pod bucket x kernel variant, and every start would
     otherwise pay all of them again. JAX_COMPILATION_CACHE_DIR, when set,

@@ -128,15 +128,6 @@ class PendingBatch:
     #: see schedule_launch's carry-chaining gate
     spread_sig: Optional[Tuple] = None
     soft_sig: Optional[Tuple] = None
-    #: [P/K, 2] int32 device handle of per-cohort (accepted,
-    #: first_collision) stats when this batch ran the speculative cohort
-    #: kernel (kernels/speculative.py); schedule_finish folds it into the
-    #: scheduler_speculative_* counters
-    spec_stats: object = None
-    #: (node_cfg, usage, dev_batch, nom) captured for the divergence
-    #: oracle (KTPU_SPEC_ORACLE=1): schedule_finish replays the serial
-    #: scan on the identical inputs and attributes any mismatch
-    spec_inputs: object = None
 
 
 class _RepairReassigner:
@@ -346,44 +337,17 @@ class BatchScheduler:
         #: band sort so over-share tenants' pods price cheaper (None, or
         #: KTPU_DRF=0, keeps tenant-blind pricing)
         self.drf = None
-        import os as _os
-        #: soft-score sub-batch size, resolved ONCE at construction (like
-        #: KTPU_ALIGN_SPLIT) — re-reading the environment per batch was a
-        #: silent per-drain cost and an unannounced behavior knob
-        self.soft_score_chunk = int(_os.environ.get(
-            "SCHED_SOFT_SCORE_CHUNK", str(self.SOFT_SCORE_CHUNK)))
-        #: KTPU_TOPO_TABLE_CACHE=0 disables the epoch-keyed term-table and
-        #: profile caches (the tier-1 cached==uncached smoke's control)
-        self.topo_table_cache = _os.environ.get(
-            "KTPU_TOPO_TABLE_CACHE", "1") != "0"
-        #: KTPU_CLASS_SCAN=0 pins non-gang batches to the classic per-pod
-        #: kernel — the parity control for the class-indexed fast path
-        #: (bench.py affinity measures class-scan vs classic with it)
-        self.class_scan = _os.environ.get("KTPU_CLASS_SCAN", "1") != "0"
-        #: KTPU_SPECULATIVE=1 routes unsharded class-table batches to the
-        #: speculative cohort kernel (kernels/speculative.py): vmapped
-        #: cohort proposals with exact collision detection and serial
-        #: whole-cohort repair — decisions stay bit-identical to the
-        #: serial class scan (default off; Scheduler(speculative=True)
-        #: sets it too)
-        self.speculative = _os.environ.get("KTPU_SPECULATIVE", "0") != "0"
-        #: KTPU_SPEC_ORACLE=1 replays EVERY speculative batch through the
-        #: serial scan and counts/attributes mismatches (the divergence
-        #: oracle — a measurement harness, not a production mode)
-        self.spec_oracle = _os.environ.get("KTPU_SPEC_ORACLE", "0") != "0"
-        #: bounded attribution log of oracle divergences (newest last);
-        #: expected empty — each entry is a per-pod dict from
-        #: kernels.speculative.divergence_report
-        from collections import deque as _deque
-        self.spec_divergence_log = _deque(maxlen=64)
-        #: per-batch (cohort_width, n_cohorts, n_collided, repaired_pods)
-        #: records — the bench's cohort-size distribution source
-        self.spec_batch_log = _deque(maxlen=256)
-        #: KTPU_PREEMPT_KERNEL=0 pins preemption to the serial per-node
-        #: victim search (preemption.py) — the measured control for the
-        #: batched victim-pricing kernel (kernels/preempt.py)
-        self.preempt_kernel = _os.environ.get(
-            "KTPU_PREEMPT_KERNEL", "1") != "0"
+        #: test controls, each the reference path a tier-1 test compares
+        #: the served one against; no entry point sets them. False turns
+        #: off the epoch-keyed term-table and profile caches
+        #: (tests/test_topo_cache.py)
+        self.topo_table_cache = True
+        #: False pins non-gang batches to the classic per-pod kernel
+        #: (tests/test_class_fastpath.py, test_chip_compile.py)
+        self.class_scan = True
+        #: False pins preemption to the serial per-node victim search of
+        #: preemption.py (tests/test_preempt.py)
+        self.preempt_kernel = True
         #: (node, generation, prio, ...) -> victim units: amortizes the
         #: preemption tensorize across a storm (kernels/preempt.py)
         self._preempt_unit_cache: Dict[Tuple, list] = {}
@@ -763,8 +727,8 @@ class BatchScheduler:
         batch.set_static_scores(
             np.arange(len(pods), dtype=np.int32), base + ext)
 
-    #: max batch size for pods whose soft scores would drift in-batch;
-    #: env-tunable. SelectorSpread and preferred inter-pod (anti-)affinity
+    #: max batch size for pods whose soft scores would drift in-batch.
+    #: SelectorSpread and preferred inter-pod (anti-)affinity
     #: both run IN-SCAN (running group counts / credit accumulators, on
     #: every kernel incl. the gang kernel's trial carry), so sub-chunking
     #: engages only when a batch OVERFLOWS the in-scan caps.
@@ -855,7 +819,7 @@ class BatchScheduler:
         whole batch launches at once; only an overflowing union still
         schedules in SOFT_SCORE_CHUNK sub-batches. Spread beyond the
         in-scan group cap chunks as before."""
-        chunk = self.soft_score_chunk
+        chunk = self.SOFT_SCORE_CHUNK
         if len(pods) <= chunk or chunk <= 0:
             return len(pods)
         if self.scorer.weights.get("InterPodAffinityPriority"):
@@ -1795,8 +1759,6 @@ class BatchScheduler:
             else:
                 node_cfg, usage = self.mirror.device_cfg_usage()
             sharded = False
-            spec_stats = None
-            spec_inputs = None
             if gang_units is not None:
                 from .kernels.gang import gang_schedule_batch
                 assign_d, scores_d, new_usage = gang_schedule_batch(
@@ -1815,36 +1777,6 @@ class BatchScheduler:
                 assign_d, scores_d, new_usage = schedule_batch_sharded(
                     self.mirror.mesh, node_cfg, usage,
                     batch.device(), nom_dev)
-            elif self.speculative and batch._class_tables is not None:
-                # speculative cohort assignment (kernels/speculative.py):
-                # vmapped K-pod cohort proposals against the frozen class
-                # table, exact collision detection, serial whole-cohort
-                # repair — bit-identical decisions to the serial scan, with
-                # per-cohort stats folded into metrics by schedule_finish
-                from .kernels.speculative import (_SPEC_MIN_PLAIN,
-                                                  cohort_width,
-                                                  schedule_batch_speculative)
-                w = cohort_width(batch.req.shape[0])
-                batch.set_speculative(w)
-                # contention gate: a batch that is mostly non-plain trips
-                # the structural fence on (nearly) every cohort, so the
-                # election + exact collision checks are pure overhead —
-                # measured over the ACTIVE prefix (pads are trivially plain
-                # and would inflate the fraction)
-                frac = (float(batch.spec_plain[:len(pods)].mean())
-                        if pods else 0.0)
-                if frac < _SPEC_MIN_PLAIN:
-                    batch.spec_plain = None
-                    batch.cohort_id = None
-                    assign_d, scores_d, new_usage = schedule_batch(
-                        node_cfg, usage, batch.device(), nom_dev)
-                else:
-                    dev = batch.device()
-                    assign_d, scores_d, new_usage, spec_stats = \
-                        schedule_batch_speculative(node_cfg, usage, dev,
-                                                   nom_dev, width=w)
-                    if self.spec_oracle:
-                        spec_inputs = (node_cfg, usage, dev, nom_dev)
             else:
                 assign_d, scores_d, new_usage = schedule_batch(
                     node_cfg, usage, batch.device(), nom_dev)
@@ -1863,8 +1795,6 @@ class BatchScheduler:
                                 usage_epoch=self.mirror.usage_epoch,
                                 gang_units=gang_units,
                                 spread_sig=spread_sig, soft_sig=soft_sig,
-                                spec_stats=spec_stats,
-                                spec_inputs=spec_inputs,
                                 inscan_cover=(affinity_chainable
                                               and topo_cover != "fallback"))
 
@@ -1903,41 +1833,6 @@ class BatchScheduler:
             batch.soft_base = chain.batch.soft_base
         return True
 
-    def _account_speculative(self, pending: "PendingBatch",
-                             assign) -> None:
-        """Fold a speculative batch's per-cohort stats into the
-        scheduler_speculative_* counters and, under the divergence
-        oracle, replay the serial scan on the captured inputs and
-        attribute any mismatch (expected: none — the kernel's contract
-        is bit-identity, and the counter existing is how production
-        proves it rather than assumes it)."""
-        import numpy as np
-        st = np.asarray(pending.spec_stats)          # [n, 2]
-        n = st.shape[0]
-        width = pending.batch.req.shape[0] // max(n, 1)
-        collided = st[:, 0] == 0
-        repaired = int((width - st[collided, 1]).sum())
-        m = self.sched_metrics
-        if m is not None:
-            m.speculative_cohorts.inc(n)
-            m.speculative_collisions.inc(int(collided.sum()))
-            m.speculative_repaired.inc(repaired)
-        # per-batch record for the bench's cohort-size distribution
-        # (counters aggregate across batches; the log keeps the widths)
-        self.spec_batch_log.append(
-            (int(width), int(n), int(collided.sum()), repaired))
-        if pending.spec_inputs is not None:
-            from .kernels.speculative import (divergence_report,
-                                              speculative_reference)
-            node_cfg, usage, dev, nom_dev = pending.spec_inputs
-            ref_assign, _ = speculative_reference(node_cfg, usage, dev,
-                                                  nom_dev)
-            report = divergence_report(assign, ref_assign, width)
-            if report:
-                if m is not None:
-                    m.speculative_divergences.inc(len(report))
-                self.spec_divergence_log.extend(report)
-
     def schedule_finish(self, pending: "PendingBatch") -> List[ScheduleResult]:
         """Back half: fetch results, host repair, adopt chained usage."""
         from .kernels.batch import unpack_results
@@ -1947,8 +1842,6 @@ class BatchScheduler:
             # the fetch drains the cross-shard argmax pipeline: this is
             # the wall time spent synchronizing the mesh for this batch
             self.sched_metrics.shard_sync_seconds.observe(scan_wait.seconds)
-        if pending.spec_stats is not None:
-            self._account_speculative(pending, assign)
         out: List[ScheduleResult] = []
         for i, pod in enumerate(pending.pods):
             row = int(assign[i])

@@ -63,23 +63,15 @@ MAX_PRIORITY = 10.0
 NEG = jnp.float32(-1e30)
 #: pods per scan step (unrolled inside the step, exact serial semantics);
 #: the scan is latency-bound so fewer, fatter steps win — see
-#: schedule_batch. Power of two <= the minimum pod bucket (8).
-#: Topology-carrying batches on the CLASSIC path use their own knob: the
+#: schedule_batch. A power of two, the minimum pod bucket (8): it divides
+#: every P that tensorize._bucket makes.
+#: Topology-carrying batches on the CLASSIC path have their own: the
 #: in-step (anti-)affinity gathers/scatters chain through the carry, so
 #: fat steps buy less there (measured r05: uniform 7.7k->9.7k at G=8;
 #: anti 2.3k->2.1k). The CLASS-INDEXED path (below) made the whole step
-#: cheap enough that one shared fat-step knob covers topology batches too.
-import os as _os
-_STEP_GROUP = int(_os.environ.get("KTPU_SCAN_GROUP", "8"))
-_STEP_GROUP_TOPO = int(_os.environ.get("KTPU_SCAN_GROUP_TOPO", "1"))
-#: sharded scan: pack (score, global row) into ONE int64 key so the
-#: cross-shard winner election is a single pmax instead of the
-#: pmax(score)+pmin(row) pair — halves the per-pod collective count on
-#: the latency-bound scan. Requires jax_enable_x64 (the key is int64);
-#: with x64 off the knob is inert and the two-collective path runs.
-#: Bit-identical winners either way (the key order is exactly
-#: lexicographic (score, -row) — see the packed branch in one_pod).
-_X64_ARGMAX = _os.environ.get("KTPU_X64_ARGMAX", "0") != "0"
+#: cheap enough that the one fat step covers topology batches too.
+_STEP_GROUP = 8
+_STEP_GROUP_TOPO = 1
 
 # column layout (keep in sync with tensorize.py)
 COL_CPU = 0
@@ -502,13 +494,9 @@ def _nom_feas_usage(usage: dict, nom: dict) -> dict:
 
 
 def _class_ctx(node_cfg: dict, usage: dict, pod_batch: dict, nom: dict):
-    """Shared setup for the class-indexed kernels: split the batch,
-    resolve the optional term tables, build the [C, N] masked-score
-    table and the initial carry. ONE copy for the serial scan below and
-    the speculative cohort kernel (kernels/speculative.py) — the
-    speculative kernel's serial-replay branch runs _class_pod_step
-    against this exact carry layout, so its decisions cannot diverge
-    from _schedule_batch_classes. Returns (ctx, carry0, per_pod)."""
+    """Setup of the class-indexed scan: split the batch, resolve the
+    optional term tables, build the [C, N] masked-score table and the
+    initial carry. Returns (ctx, carry0, per_pod)."""
     per_pod, unique_masks, unique_scores, rw = _split_batch(pod_batch)
     N = node_cfg["alloc"].shape[0]
     cls = {k: pod_batch[k] for k in ("class_req", "class_nz",
@@ -555,9 +543,7 @@ def _class_ctx(node_cfg: dict, usage: dict, pod_batch: dict, nom: dict):
 def _class_pod_step(ctx, carry, pod):
     """One pod's serial class-scan step: gather its class's masked-score
     row, apply the carry-dependent terms, argmax, scatter the winner's
-    usage and refresh the winner's COLUMN across all classes. Shared by
-    _schedule_batch_classes and the speculative kernel's repair branch
-    (bit-identity contract, like _topo_bad/_topo_scatter)."""
+    usage and refresh the winner's COLUMN across all classes."""
     node_cfg = ctx["node_cfg"]
     cls = ctx["cls"]
     unique_masks, unique_scores = ctx["unique_masks"], ctx["unique_scores"]
@@ -677,12 +663,10 @@ def _schedule_batch_classes(node_cfg: dict, usage: dict, pod_batch: dict,
     anchor's base tables still applying).
 
     The per-pod step lives in _class_pod_step and the setup in
-    _class_ctx, both shared with the speculative cohort kernel
-    (kernels/speculative.py) so the two paths cannot drift."""
+    _class_ctx."""
     ctx, carry0, per_pod = _class_ctx(node_cfg, usage, pod_batch, nom)
     P = per_pod["seq"].shape[0]
-    want = max(1, _STEP_GROUP)
-    G = min(1 << (want.bit_length() - 1), P)
+    G = min(_STEP_GROUP, P)
 
     def step(carry, podg):
         outs = []
@@ -728,7 +712,7 @@ def schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
     incremental class-indexed scan — spread groups, soft in-scan
     credits, and nominated reservations now ride it as carried state.
     The classic per-pod recompute below remains as the one-source parity
-    control (KTPU_CLASS_SCAN=0, hand-built batches in tests)."""
+    control (`class_scan` off, hand-built batches in tests)."""
     pod_batch = unpack_inputs(pod_batch)
     if "class_req" in pod_batch:
         return _schedule_batch_classes(node_cfg, usage, pod_batch, nom)
@@ -842,11 +826,7 @@ def schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
     # results) cuts the step count G-fold. P is always a power of two
     # >= 8 (tensorize._bucket), so G=8 divides it exactly.
     P = per_pod["seq"].shape[0]
-    # clamp the knob to a power of two dividing P (P is always a power of
-    # two via tensorize._bucket) — an arbitrary env value must degrade,
-    # not crash the reshape below
-    want = max(1, _STEP_GROUP_TOPO if has_topo else _STEP_GROUP)
-    G = min(1 << (want.bit_length() - 1), P)
+    G = min(_STEP_GROUP_TOPO if has_topo else _STEP_GROUP, P)
 
     def step(carry, podg):
         outs = []
@@ -1019,31 +999,9 @@ def _sharded_class_scan(node_cfg: dict, usage: dict, pod_batch: dict,
         penalized = _tie_penalized(masked, rows_g, pod["seq"])
         lmax = jnp.max(penalized)
         lbest = jnp.argmax(penalized).astype(jnp.int32)  # first max, local
-        if _X64_ARGMAX and jax.config.jax_enable_x64:
-            # single-collective winner election: key = (mono(score) -
-            # 2^31) * 2^32 + (INT32_MAX - row). mono() is the standard
-            # sign-flip map of the f32 bit pattern into [0, 2^32) that
-            # preserves float order (negatives reverse-complemented,
-            # positives offset past them), so pmax(key) picks the max
-            # score and, among bit-equal scores, the MIN global row —
-            # exactly the pmax+pmin pair's answer. -0.0 is canonicalized
-            # first: it is ==0.0 to the comparison path but bit-distinct,
-            # the one case where bit order and float order disagree.
-            zmax = jnp.where(lmax == 0.0, jnp.float32(0.0), lmax)
-            b = lax.bitcast_convert_type(zmax, jnp.int32).astype(jnp.int64)
-            mono = jnp.where(b >= 0, b + jnp.int64(0x80000000),
-                             jnp.int64(-1) - b)
-            row_key = (jnp.int64(2147483647)
-                       - (offset + lbest).astype(jnp.int64))
-            key = ((mono - jnp.int64(0x80000000)) * jnp.int64(1 << 32)
-                   + row_key)
-            gkey = lax.pmax(key, NODE_AXIS)
-            best = (jnp.int64(2147483647)
-                    - (gkey % jnp.int64(1 << 32))).astype(jnp.int32)
-        else:
-            gmax = lax.pmax(lmax, NODE_AXIS)
-            best = lax.pmin(jnp.where(lmax == gmax, offset + lbest,
-                                      _INT32_MAX), NODE_AXIS)
+        gmax = lax.pmax(lmax, NODE_AXIS)
+        best = lax.pmin(jnp.where(lmax == gmax, offset + lbest,
+                                  _INT32_MAX), NODE_AXIS)
         lb = best - offset
         owner = (lb >= 0) & (lb < Nl)
         lbc = jnp.clip(lb, 0, Nl - 1)
@@ -1107,8 +1065,7 @@ def _sharded_class_scan(node_cfg: dict, usage: dict, pod_batch: dict,
         sc0 = usage.get("soft_cnt")
         carry0["soft_cnt"] = sc0 if sc0 is not None else soft_cnt0
     P = per_pod["seq"].shape[0]
-    want = max(1, _STEP_GROUP)
-    G = min(1 << (want.bit_length() - 1), P)
+    G = min(_STEP_GROUP, P)
 
     def step(carry, podg):
         outs = []

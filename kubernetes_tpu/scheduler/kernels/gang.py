@@ -54,7 +54,6 @@ plain batch kernel.
 
 from __future__ import annotations
 
-import os as _os
 from typing import Dict, Tuple
 
 import jax
@@ -68,7 +67,7 @@ from .batch import (COL_CPU, COL_MEM, NEG, _pod_feasible, _pod_score,
 
 #: entries per scan step (unrolled inside, same op sequence — see
 #: batch.py's step grouping); must divide the bucketed T (a power of two)
-_STEP_GROUP_GANG = int(_os.environ.get("KTPU_SCAN_GROUP_GANG", "8"))
+_STEP_GROUP_GANG = 8
 
 
 @jax.jit
@@ -254,7 +253,7 @@ def gang_schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
         entries["need"] = gang_tab["need"]
         entries["greq"] = gang_tab["greq"]
     T = entries["pod_idx"].shape[0]
-    G = min(1 << (max(1, _STEP_GROUP_GANG).bit_length() - 1), T)
+    G = min(_STEP_GROUP_GANG, T)
 
     def step(carry, eg):
         outs = []

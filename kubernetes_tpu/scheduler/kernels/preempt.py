@@ -29,7 +29,7 @@ mirrors with the same op order and f32 arithmetic — the parity oracles
 gang_schedule_reference plays for the gang kernel.
 
 Two deliberate modeling divergences from the serial path, which
-``KTPU_PREEMPT_KERNEL=0`` keeps available as the measured control:
+``BatchScheduler.preempt_kernel = False`` keeps as the tests' control:
 
   - victim sets are PREFIXES of the band order; the serial reprieve
     loop may carve non-contiguous sets when re-adding a cheap victim

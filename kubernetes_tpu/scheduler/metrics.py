@@ -231,27 +231,6 @@ class SchedulerMetrics:
         self.mirror_shard_pad_rows = r.gauge(
             "scheduler_mirror_shard_pad_rows",
             "Node-mirror rows added to make the capacity shard-divisible")
-        # ---- speculative cohort assignment (kernels/speculative.py) ----
-        # the speculation rate is a first-class observable: cohorts
-        # attempted, cohorts that collided (and were serially repaired),
-        # pods re-decided by the repair, and oracle-detected divergences
-        # from the serial scan (contract: always zero — a nonzero count
-        # is a kernel bug, attributed in BatchScheduler.spec_divergence_log)
-        self.speculative_cohorts = r.counter(
-            "scheduler_speculative_cohorts_total",
-            "Speculative cohort assignment attempts")
-        self.speculative_collisions = r.counter(
-            "scheduler_speculative_collisions_total",
-            "Speculative cohorts rejected by collision detection and "
-            "replayed serially")
-        self.speculative_repaired = r.counter(
-            "scheduler_speculative_repaired_pods_total",
-            "Pods from the first collision onward whose decisions came "
-            "from the serial repair replay")
-        self.speculative_divergences = r.counter(
-            "scheduler_speculative_divergences_total",
-            "Pods whose speculative decision differed from the serial "
-            "oracle replay (expected zero; bit-identity contract)")
 
     def stage(self, tracer, name: str, ring: bool = True, **attrs):
         """observability.SpanTracer.stage for one `operation` of the

@@ -67,12 +67,11 @@ class Scheduler:
                  disable_preemption: bool = False,
                  framework=None, extenders=None, metrics=None,
                  mesh=None, async_bind: Optional[bool] = None,
-                 adaptive_batch: Optional[bool] = None,
+                 adaptive_batch: bool = False,
                  min_batch: int = MIN_ADAPTIVE_BATCH,
                  lane_priority: int = DEFAULT_LANE_PRIORITY,
                  max_inflight_binds: int = MAX_INFLIGHT_BINDS,
-                 tracer=None,
-                 speculative: Optional[bool] = None):
+                 tracer=None):
         from .framework import Framework
         from .metrics import SchedulerMetrics
         self.metrics = metrics if metrics is not None else SchedulerMetrics()
@@ -131,26 +130,19 @@ class Scheduler:
         #: many-core host. On a GIL-starved small host (<=2 cores, CPU
         #: backend, in-process store) the thread only timeshares against
         #: tensorize, so the stage runs inline — same code, same
-        #: bookkeeping. KTPU_COMMIT_THREAD=0/1 overrides.
+        #: bookkeeping. None until _commit_overlaps decides; a test sets it
         self._commit_async: Optional[bool] = None
         #: serializes the tensorize/launch/finish machinery (drain thread)
         #: against the rare commit-thread re-entries into the algorithm
         #: (explain / preempt refresh the snapshot+mirror)
         self._algo_lock = threading.RLock()
-        import os as _os
-        #: split pops at power-of-two boundaries when the scan pad would
-        #: exceed 25% (see drain_pipelined); KTPU_ALIGN_SPLIT=0 disables
-        self._align_split = _os.environ.get("KTPU_ALIGN_SPLIT", "1") != "0"
         # ---- serving-mode drain policy (adaptive batching + lanes) ----
         #: adaptive sizing: batch cap follows queue depth (small when
         #: shallow so interactive pods never wait out a mega-drain, full
         #: batch_size when deep), priority-lane cohorts pop as their own
         #: express batch, and hub backpressure halves the cap. OFF by
         #: default: one-shot drains keep the fixed batch_size (decision
-        #: parity with the oracle benches). KTPU_ADAPTIVE_BATCH overrides.
-        if adaptive_batch is None:
-            adaptive_batch = _os.environ.get(
-                "KTPU_ADAPTIVE_BATCH", "0") != "0"
+        #: parity with the serial oracle); serving/ passes True.
         self.adaptive_batch = bool(adaptive_batch)
         self.min_batch = max(1, min(min_batch, batch_size))
         self.lane_priority = lane_priority
@@ -213,11 +205,6 @@ class Scheduler:
         #: scheduler_host_to_device_transfers_total, counted at put_named
         self.algorithm.mirror.transfers = \
             self.metrics.host_to_device_transfers
-        # speculative cohort assignment (kernels/speculative.py): the
-        # constructor argument overrides KTPU_SPECULATIVE (which the
-        # BatchScheduler read at construction) — explicit beats ambient
-        if speculative is not None:
-            self.algorithm.speculative = bool(speculative)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         #: the exception that ended the run loop (MAX_LOOP_ERROR_STREAK
@@ -777,11 +764,7 @@ class Scheduler:
 
     def _commit_overlaps(self) -> bool:
         if self._commit_async is None:
-            import os as _os
-            flag = _os.environ.get("KTPU_COMMIT_THREAD")
-            if flag is not None:
-                self._commit_async = flag != "0"
-            elif self._async_bind:
+            if self._async_bind:
                 self._commit_async = True
             else:
                 # a real accelerator's dispatch/fetch waits release the
@@ -790,9 +773,10 @@ class Scheduler:
                 # GIL-starved small host loses to the extra thread. A
                 # backend that fails to initialise raises here: it must
                 # never read as "we are on CPU"
+                import os
                 import jax
                 self._commit_async = jax.default_backend() != "cpu" or \
-                    (_os.cpu_count() or 1) >= 4
+                    (os.cpu_count() or 1) >= 4
         return self._commit_async
 
     def _pipe_anchor(self) -> None:
@@ -889,8 +873,7 @@ class Scheduler:
                     limit = self.algorithm.soft_batch_limit(pods)
                     if limit < len(pods):
                         pods, carry = pods[:limit], pods[limit:]
-                if pods and self._align_split and \
-                        self.algorithm.topo_scan_likely(pods):
+                if pods and self.algorithm.topo_scan_likely(pods):
                     # bucket alignment for TOPOLOGY scans only: the
                     # class-indexed scan cut the per-step cost ~6x (r06),
                     # but topology steps still pay the [K, N] counter

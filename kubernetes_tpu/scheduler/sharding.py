@@ -55,16 +55,6 @@ _NODE_TRAILING = re.compile(
 #: shardable size).
 _TENANT_REPLICATED = ("tenant_usage", "tenant_capacity")
 
-#: speculative-cohort tensors (kernels/speculative.py): the per-pod
-#: plain-pod flag and cohort-id vectors ride the POD axis, which is
-#: replicated everywhere the pod batch is (every shard scans every pod,
-#: owns a node slice), so they REPLICATE like the rest of the per-pod
-#: arrays — named here so the rule is a decision, not an accident of
-#: the fallthrough. The per-cohort stats output is a tiny [P/K, 2]
-#: host-fetched array and never shards.
-_COHORT_REPLICATED = ("spec_plain", "cohort_id")
-
-
 def spec_for(name: str, ndim: int):
     """The PartitionSpec for tensor `name` (first matching rule wins;
     scalars and unmatched names replicate)."""

@@ -730,11 +730,6 @@ class PodBatchTensors:
         self.soft_write_w: Optional[np.ndarray] = None     # [P, Ks] f32
         self.soft_weight = 0.0
 
-        # speculative cohort vectors (set_speculative — only when the
-        # batch routes to kernels/speculative.py)
-        self.spec_plain: Optional[np.ndarray] = None     # [P] bool
-        self.cohort_id: Optional[np.ndarray] = None      # [P] int32
-
     def set_topology_terms(self, dom: np.ndarray, n_domains: int,
                            anti_tids: np.ndarray, aff_tids: np.ndarray,
                            match_tids: np.ndarray,
@@ -852,37 +847,6 @@ class PodBatchTensors:
             "class_mask_idx": mask_idx, "class_score_idx": score_idx,
             "class_idx": class_idx.astype(np.int32)[:P]}
 
-    def set_speculative(self, width: int) -> None:
-        """Mark pods eligible for speculative cohort assignment
-        (kernels/speculative.py) and stamp the cohort-id vector. A pod
-        is PLAIN — safe to speculate on — iff it READS no carry-
-        dependent term: no required/waived (anti-)affinity term lists,
-        no spread group membership, no soft credit read channel, no
-        nominated self-exemption row. Carry WRITERS stay plain (the
-        kernel applies their counter writes with the shared serial
-        helpers); DRF ordering is host-side and never reaches the
-        kernel. Pads are plain: inactive pods never write, so they are
-        trivially serial-equivalent. Must run AFTER every term table and
-        nom_row is installed — the flags are derived from them.
-
-        `cohort_id[i]` is the contiguous cohort the pod speculates in
-        (pod index // width, the kernel's chunking) or -1 where the pod
-        is pinned serial — the divergence oracle's attribution key."""
-        P = self.req.shape[0]
-        plain = self.nom_row < 0
-        if self.anti_dom is not None:
-            plain = plain & (self.anti_tids < 0).all(axis=1)
-            plain = plain & (self.aff_tids < 0).all(axis=1)
-            if self.cmatch_tids is not None:
-                plain = plain & (self.cmatch_tids < 0).all(axis=1)
-        if self.spread_base is not None:
-            plain = plain & (self.spread_gidx < 0)
-        if self.soft_dom is not None:
-            plain = plain & (self.soft_base_idx < 0)
-        self.spec_plain = plain
-        cid = np.arange(P, dtype=np.int32) // np.int32(max(width, 1))
-        self.cohort_id = np.where(plain, cid, np.int32(-1))
-
     def set_spread(self, base: np.ndarray, zone_of: np.ndarray,
                    n_zones: int, weight: float,
                    match: Optional[np.ndarray] = None) -> None:
@@ -988,8 +952,4 @@ class PodBatchTensors:
             out["soft_weight"] = np.float32(self.soft_weight)
         if self._class_tables is not None:
             out.update(self._class_tables)
-        if self.spec_plain is not None:
-            # pod-axis cohort vector; replicates by the named rule
-            # (sharding._COHORT_REPLICATED)
-            out["spec_plain"] = self.spec_plain
         return pack_inputs(self._mirror.put_named, out)

@@ -674,7 +674,7 @@ class TopologyIndex:
         pod churn re-uses one device-resident table across every batch
         of a drain; only a node-topology change re-uploads. A caller
         that already built the host table passes (dom, n_domains) so a
-        cache-disabled run (KTPU_TOPO_TABLE_CACHE=0) does not build it
+        cache-disabled run (`topo_table_cache` off) does not build it
         twice."""
         from .tensorize import _bucket
         cap = self.mirror.t.capacity

@@ -11,8 +11,7 @@ control plane.
 
 The scheduler-side half of serving mode lives in scheduler/scheduler.py
 (adaptive drain batch sizing, priority lanes, hub backpressure —
-`adaptive_batch=True`) and scheduler/queue.py (lane census). The bench
-entry point is `bench.py` (serving section).
+`adaptive_batch=True`) and scheduler/queue.py (lane census).
 """
 
 from .loadgen import ArrivalEvent, CLASS_LABEL, DEFAULT_MIX, LoadGen

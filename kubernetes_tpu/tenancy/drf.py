@@ -29,7 +29,7 @@ The account feeds two consumers:
 
 ``KTPU_DRF=0`` disables both consumers — today's priority-then-FIFO
 drain and tenant-blind pricing stay byte-identical as the measured
-control (the flag pattern of KTPU_CLASS_SCAN / KTPU_PREEMPT_KERNEL).
+control.
 
 Charging is idempotent by pod key (charge at assume/bind, release at
 terminal/delete/bind-failure), so replays and informer echoes can never

@@ -8,8 +8,8 @@ collectives), a program that does not fit one chip's memory, and a sharded
 scan that loses its collectives or puts everything on one device. Nothing
 runs, so nothing here speaks about results or times.
 
-Shapes are the real drain's: the fake-node cluster of bench.py at 5,000
-nodes (mirror capacity 8192), pod buckets 16384 and 1024, every table as
+Shapes are the real drain's: the fake-node cluster of fakecluster.py at
+5,000 nodes (mirror capacity 8192), pod buckets 16384 and 1024, every table as
 `tensorize` lays it out — captured from `BatchScheduler.schedule_launch`
 itself, with the kernel swapped for a spy, so a layout change moves these
 shapes with it. The sharded scan takes the same batch with the node axis
@@ -26,7 +26,7 @@ import os
 import numpy as np
 import pytest
 
-import bench
+import fakecluster
 from kubernetes_tpu import api
 
 N_NODES = 5000           # BASELINE.json's north-star cluster
@@ -75,12 +75,11 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _scheduler(class_scan=True, speculative=False, carriers=True):
+def _scheduler(class_scan=True):
     from kubernetes_tpu.scheduler import Scheduler
     from kubernetes_tpu.scheduler import priorities as prios_mod
     from kubernetes_tpu.state import Client
-    sched = Scheduler(Client(validate=False), batch_size=16384,
-                      speculative=speculative)
+    sched = Scheduler(Client(validate=False), batch_size=16384)
     sched.algorithm.class_scan = class_scan
     svc = api.Service(
         metadata=api.ObjectMeta(name="bench", namespace="default"),
@@ -88,11 +87,11 @@ def _scheduler(class_scan=True, speculative=False, carriers=True):
     sched.algorithm.scorer.listers = prios_mod.SpreadListers(
         services=lambda ns: [svc])
     for i in range(N_NODES):
-        sched.cache.add_node(bench.make_node(i))
+        sched.cache.add_node(fakecluster.make_node(i))
     # bound anti-affinity carriers: every later pod gets a residual mask
     # row, and the in-scan term tables ship
-    for i in range(100 if carriers else 0):
-        p = bench.make_pod(3_000_000 + i, "pod-anti-affinity")
+    for i in range(100):
+        p = fakecluster.make_pod(3_000_000 + i, "pod-anti-affinity")
         p.spec.node_name = f"node-{i}"
         sched.cache.add_pod(p)
     sched.algorithm.refresh()
@@ -103,8 +102,8 @@ def _scheduler(class_scan=True, speculative=False, carriers=True):
 def _mixed_pods(n):
     """Half pod-anti-affinity, half plain, all selected by the spread
     Service: the batch carries topology terms AND spread groups."""
-    return [bench.make_pod(i, "pod-anti-affinity" if i % 2 else "uniform")
-            for i in range(n)]
+    return [fakecluster.make_pod(
+        i, "pod-anti-affinity" if i % 2 else "uniform") for i in range(n)]
 
 
 def _capture(sched, pods, module, name):
@@ -185,7 +184,7 @@ def test_class_scan_tail_bucket(one_chip):
 
 
 def test_classic_scan(one_chip):
-    """The per-pod recompute scan (KTPU_CLASS_SCAN=0, the parity
+    """The per-pod recompute scan (`class_scan` off, the parity
     control): same batch, no class tables."""
     from kubernetes_tpu.scheduler.kernels import batch as kb
     args, _ = _capture(_scheduler(class_scan=False), _mixed_pods(16384),
@@ -193,22 +192,6 @@ def test_classic_scan(one_chip):
     names = kb.unpack_inputs(args[2])
     assert "class_req" not in names and "anti_dom" in names
     _compile(kb.schedule_batch, *_shapes(args, one_chip))
-
-
-def test_speculative_scan(one_chip):
-    from kubernetes_tpu.scheduler.kernels import speculative as ks
-    # a carrier-free cluster, and every other pod outside the spread
-    # Service's selector: those are PLAIN (they read no carry) and
-    # speculate, the rest take the in-kernel serial repair — under the
-    # contention gate's plain fraction the router would not speculate
-    pods = [bench.make_pod(i) for i in range(16384)]
-    for p in pods[::2]:
-        p.metadata.labels["app"] = "other"
-    args, kwargs = _capture(_scheduler(speculative=True, carriers=False),
-                            pods, ks, "schedule_batch_speculative")
-    assert kwargs == {"width": 16} and "spread_base" in args[2].rest
-    _compile(ks.schedule_batch_speculative, *_shapes(args, one_chip),
-             width=16)
 
 
 def test_gang_scan(one_chip):
@@ -223,7 +206,7 @@ def test_gang_scan(one_chip):
             return [(list(range(g, g + 16)), api.wellknown.LABEL_ZONE,
                      True, None) for g in range(0, len(pods), 16)]
     sched.algorithm.gang = _Gangs()
-    pods = [bench.make_pod(i) for i in range(16384)]
+    pods = [fakecluster.make_pod(i) for i in range(16384)]
     args, _ = _capture(sched, pods, kg, "gang_schedule_batch")
     assert args[3].rest["dom_tab"].shape[1] == CAPACITY
     _compile(kg.gang_schedule_batch, *_shapes(args, one_chip))
@@ -276,13 +259,13 @@ def test_price_nodes(one_chip):
     def build():
         infos = {}
         for i in range(16):
-            ni = NodeInfo(bench.make_node(i))
+            ni = NodeInfo(fakecluster.make_node(i))
             for j in range(5 + (i == 0) * 3):      # widest row: 8 units
-                p = bench.make_pod(100 * i + j)
+                p = fakecluster.make_pod(100 * i + j)
                 p.spec.node_name, p.spec.priority = f"node-{i}", j
                 ni.add_pod(p)
             infos[f"node-{i}"] = ni
-        pod = bench.make_pod(9999)
+        pod = fakecluster.make_pod(9999)
         pod.spec.priority = 100
         pod.spec.containers[0].resources.requests["cpu"] = \
             api.Quantity("3900m")
@@ -302,16 +285,16 @@ def test_price_domains(one_chip):
     def build():
         infos, cands = {}, []
         for i in range(16):
-            ni = NodeInfo(bench.make_node(i))
+            ni = NodeInfo(fakecluster.make_node(i))
             for j in range(8 if i < 2 else 1):     # widest domain: 16 units
-                p = bench.make_pod(100 * i + j)
+                p = fakecluster.make_pod(100 * i + j)
                 p.spec.node_name, p.spec.priority = f"node-{i}", j % 7
                 ni.add_pod(p)
             infos[f"node-{i}"] = ni
             cands.append((f"node-{i}", ni, f"slice-{i // 2}"))
         members = []
         for m in range(4):
-            p = bench.make_pod(9000 + m)
+            p = fakecluster.make_pod(9000 + m)
             p.spec.priority = 100
             p.spec.containers[0].resources.requests["cpu"] = \
                 api.Quantity("3900m")

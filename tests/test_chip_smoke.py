@@ -34,7 +34,7 @@ def test_rehearse_runs_every_phase_on_the_cpu():
     assert phases["served.start"]["wal"].endswith("native=True")
     assert phases["served.bound"]["bound"] == phases["served.bound"]["pods"]
     assert set(phases["parity.decisions"]["rates"].values()) == {1.0}
-    assert {"gang", "preempt", "speculative", "drf", "affinity",
+    assert {"gang", "preempt", "drf", "affinity",
             "scores"} <= set(phases["parity.kernels"])
     assert phases["drain"]["compiles_in_timed_drain"] == 0
     # unset JAX_COMPILATION_CACHE_DIR: both processes cache under the

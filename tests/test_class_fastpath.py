@@ -6,8 +6,8 @@ they are now carried state / a phantom overlay of the class-indexed scan.
 These tests pin:
 
   - ROUTING: such batches build class tables (core no longer demotes),
-  - PARITY: class-scan decisions == classic kernel (KTPU_CLASS_SCAN=0
-    control, bit-identical) == the serial numpy oracle (predicates/
+  - PARITY: class-scan decisions == classic kernel (`class_scan` off,
+    the control, bit-identical) == the serial numpy oracle (predicates/
     priorities replayed pod-by-pod with the kernel's tie-break), on
     randomized >=100-pod fixtures with node add/delete/relabel churn
     between batches,
@@ -390,7 +390,7 @@ class TestSoftGangFallbackCounter:
             cache.add_node(mk_node(i))
         sched = BatchScheduler(cache, weights=dict(WEIGHTS))
         sched.sched_metrics = SchedulerMetrics()
-        sched.soft_score_chunk = 8
+        sched.SOFT_SCORE_CHUNK = 8
         sched.gang = object()   # soft_batch_limit only checks presence
         return sched
 

@@ -1,7 +1,7 @@
 """Overload drill (ISSUE 19): the client-storm chaos leg.
 
-Unit coverage for APF itself lives in tests/test_flowcontrol.py; the
-full storm-vs-control measurement is bench.py overload (BENCH_r13).
+Unit coverage for APF itself lives in tests/test_flowcontrol.py (the
+BENCH_r13 record is a pre-chip CPU run of a harness that is gone).
 Here we pin the drill's CONTRACTS:
 
 - flag-off schedules are byte-identical to pre-overload PRs' schedules

@@ -21,7 +21,7 @@ tests pin, per scan flavour, on batches captured from
 import numpy as np
 import pytest
 
-import bench
+import fakecluster
 from kubernetes_tpu import api
 from kubernetes_tpu.scheduler import sharding
 
@@ -61,8 +61,6 @@ def _device_as_before(b, mesh):
     if b._class_tables is not None:
         for k, v in b._class_tables.items():
             out[k] = sharding.put(mesh, k, v)
-    if b.spec_plain is not None:
-        out["spec_plain"] = put("spec_plain")
     return out
 
 
@@ -75,12 +73,11 @@ class _Gangs:
                 for g in range(0, len(pods), 4)]
 
 
-def _scheduler(variant, speculative=False, mesh=None, spread=False):
+def _scheduler(variant, mesh=None, spread=False):
     from kubernetes_tpu.scheduler import Scheduler
     from kubernetes_tpu.scheduler import priorities as prios_mod
     from kubernetes_tpu.state import Client
-    sched = Scheduler(Client(validate=False), batch_size=256,
-                      speculative=speculative, mesh=mesh)
+    sched = Scheduler(Client(validate=False), batch_size=256, mesh=mesh)
     if spread:
         svc = api.Service(
             metadata=api.ObjectMeta(name="bench", namespace="default"),
@@ -88,11 +85,11 @@ def _scheduler(variant, speculative=False, mesh=None, spread=False):
         sched.algorithm.scorer.listers = prios_mod.SpreadListers(
             services=lambda ns: [svc])
     for i in range(N_NODES):
-        sched.cache.add_node(bench.make_node(i))
+        sched.cache.add_node(fakecluster.make_node(i))
     # bound carriers of the variant's terms, so that the batch's rows
     # and term tables have something to read
     for i in range(12 if variant != "uniform" else 0):
-        p = bench.make_pod(3_000_000 + i, variant)
+        p = fakecluster.make_pod(3_000_000 + i, variant)
         p.spec.node_name = f"node-{i}"
         sched.cache.add_pod(p)
     sched.algorithm.refresh()
@@ -119,9 +116,6 @@ FLAVOURS = {
                     "soft_write_w", "soft_weight"}),
     "gang": (dict(variant="uniform"), "uniform", "gang",
              "gang_schedule_batch", set()),
-    "speculative": (dict(variant="uniform", speculative=True), "uniform",
-                    "speculative", "schedule_batch_speculative",
-                    {"spec_plain", "class_idx"}),
     "sharded": (dict(variant="pod-anti-affinity", mesh=4, spread=True),
                 "pod-anti-affinity", "batch", "schedule_batch_sharded",
                 {"anti_dom", "spread_zone", "class_idx"}),
@@ -154,7 +148,7 @@ def _launch(flavour):
     setattr(mod, name, spy)
     try:
         pending = sched.algorithm.schedule_launch(
-            [bench.make_pod(i, variant) for i in range(N_PODS)])
+            [fakecluster.make_pod(i, variant) for i in range(N_PODS)])
     finally:
         setattr(mod, name, kernel)
     assert pending is not None and "args" in got, \
@@ -261,14 +255,14 @@ def test_a_plain_launch_after_a_bind_issues_at_most_six_transfers():
     # on /metrics at 0 from process start
     assert f"{series} 0.0" in sched.metrics.registry.expose().splitlines()
     algo = sched.algorithm
-    first = [bench.make_pod(i) for i in range(8)]
+    first = [fakecluster.make_pod(i) for i in range(8)]
     for r in algo.schedule(first):
         assert r.node_name is not None
         r.pod.spec.node_name = r.node_name
         sched.cache.assume_pod(r.pod)
     full_upload = counter.value()
     assert full_upload >= 8          # cfg and usage, one transfer a key
-    pending = algo.schedule_launch([bench.make_pod(100 + i)
+    pending = algo.schedule_launch([fakecluster.make_pod(100 + i)
                                     for i in range(8)])
     issued = counter.value() - full_upload
     # the packed dirty-row scatter, the packed batch, unique_masks and

@@ -208,8 +208,8 @@ def test_perf_smoke_pipelined_parity_200x1000():
     1000 pods through the PIPELINED drain — commit stage on its own
     thread, device usage chained across batches — must schedule every
     pod and make bit-identical decisions to the serial path
-    (schedule_pending run to exhaustion), the same parity bar bench.py's
-    oracle holds the full shape to."""
+    (schedule_pending run to exhaustion), the same parity bar
+    fakecluster.measure_parity's oracle holds the full shape to."""
     n_nodes, n_pods, batch = 200, 1000, 256
     client_a, sched_a = build(n_nodes, n_pods, batch_size=batch)
     while sched_a.schedule_pending(timeout=0):

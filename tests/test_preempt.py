@@ -4,8 +4,8 @@ whole-gang preemption, and the capacity-aware gang domain reduction.
 The contract under test (ISSUE 15): the device kernel's decisions
 (winner node + victim set) are bit-identical to the serial numpy oracle
 on randomized fixtures mixing priorities, PDBs, gang victims, and
-nominated pods; KTPU_PREEMPT_KERNEL=0 keeps the reference's serial
-reprieve path as the measured control; gang members route to whole-gang
+nominated pods; `preempt_kernel` off keeps the reference's serial
+reprieve path as the control; gang members route to whole-gang
 preemption (one ICI domain priced for minMember placements, nominations
 across every freed node) instead of being skipped.
 """
@@ -282,7 +282,7 @@ class TestRouting:
         return cache
 
     def test_kernel_and_serial_agree_on_reference_fixture(self):
-        """The routing flag: default (kernel) and KTPU_PREEMPT_KERNEL=0
+        """The routing flag: default (kernel) and `preempt_kernel` off
         (serial control) produce the same plan on the reference's
         min-victim fixture."""
         plans = {}

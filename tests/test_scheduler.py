@@ -11,6 +11,7 @@ import time
 import numpy as np
 import pytest
 
+import fakecluster
 from kubernetes_tpu import api
 from kubernetes_tpu.api import Quantity
 from kubernetes_tpu.scheduler import (BatchScheduler, Cache, Scheduler,
@@ -879,17 +880,16 @@ class TestPreemption:
 
 
 class TestDecisionParity:
-    def test_batch_matches_serial_oracle(self):
+    @pytest.mark.parametrize("variant", fakecluster.PARITY_VARIANTS)
+    def test_batch_matches_serial_oracle(self, variant):
         """The north star's bind-decision-parity claim, measured: the batch
         path's decisions equal a serial python oracle replaying the
         reference's per-pod loop (predicates + priorities + the kernel's
         tie-break) over the same fixture in the same order — on every
-        hard-constraint variant."""
-        import bench
-        for variant in ("uniform", "node-affinity", "taints"):
-            rate, _, _ = bench.measure_parity(variant, n_pods=120,
-                                              n_nodes=40)
-            assert rate == 1.0, f"{variant} parity {rate:.4f} < 1.0"
+        fixture variant."""
+        rate, _, _ = fakecluster.measure_parity(variant, n_pods=120,
+                                                n_nodes=40)
+        assert rate == 1.0, f"{variant} parity {rate:.4f} < 1.0"
 
 
 class TestEndToEnd:
@@ -1240,6 +1240,34 @@ class TestAlignSplitGate:
         assert sched.topo_scan_likely([plain])
 
 
+class TestCommitOverlaps:
+    """Where the pipelined drain's commit stage runs is decided from what
+    the scheduler can observe, and from nothing else: an asynchronous
+    bind, a backend that is not the CPU, or at least 4 cores put it on
+    its own thread; a small CPU-only host keeps it inline."""
+
+    @pytest.mark.parametrize("async_bind, backend, cores, threaded", [
+        (True, "cpu", 1, True),
+        (False, "cpu", 2, False),
+        (False, "cpu", 8, True),
+        (False, "tpu", 1, True),
+    ])
+    def test_table(self, monkeypatch, async_bind, backend, cores,
+                   threaded):
+        import os
+        import jax
+        monkeypatch.setenv("KTPU_COMMIT_THREAD", "0" if threaded else "1")
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(os, "cpu_count", lambda: cores)
+        sched = Scheduler(Client(), batch_size=8, async_bind=async_bind)
+        try:
+            assert sched._commit_async is None
+            assert sched._commit_overlaps() is threaded
+            assert sched._commit_async is threaded   # decided once
+        finally:
+            sched.stop()
+
+
 class TestRunLoopFailures:
     """A failed scheduling cycle is counted, keeps its pods, and — when it
     repeats — ends the loop, instead of a traceback loop under a green
@@ -1324,7 +1352,7 @@ class TestRunLoopFailures:
         """drain_pipelined used to swallow the commit thread's exception
         at the end of the drain."""
         _, sched = self._cluster()
-        monkeypatch.setenv("KTPU_COMMIT_THREAD", "1")
+        sched._commit_async = True
 
         def boom(results, cycle):
             raise RuntimeError("commit failed")

@@ -19,6 +19,7 @@ import math
 
 import pytest
 
+from kubernetes_tpu import api
 from kubernetes_tpu.api.core import Pod
 from kubernetes_tpu.api.meta import ObjectMeta
 from kubernetes_tpu.api.scheduling import PodGroup, PodGroupSpec
@@ -336,6 +337,60 @@ class TestAdaptiveCapUnit:
 
 
 # ------------------------------------------------------- chaos soak
+
+
+class TestDrainCapContention:
+    """_drain_cap's contention pressure — preemption-attempt
+    deltas and the express-band occupancy EWMA each shrink BULK caps one
+    notch (express caps stay exempt: urgency wins over pacing)."""
+
+    def _sched(self):
+        from kubernetes_tpu.scheduler import Scheduler
+        from kubernetes_tpu.state import Client
+        return Scheduler(Client(validate=False), batch_size=1024,
+                         adaptive_batch=True, min_batch=16,
+                         async_bind=False)
+
+    def _pod(self, name, priority):
+        return api.Pod(
+            metadata=api.ObjectMeta(name=name, namespace="default"),
+            spec=api.PodSpec(priority=priority,
+                             containers=[api.Container(name="c",
+                                                       image="img")]))
+
+    def test_preemption_delta_shrinks_one_cycle(self):
+        sched = self._sched()
+        for i in range(1500):
+            sched.queue.add(self._pod(f"p{i}", 0))
+        assert sched._drain_cap() == 1024
+        before = sched.metrics.backpressure_shrinks.value()
+        sched.metrics.preemption_attempts.inc()
+        # the delta since the last sized cycle is live contention: one
+        # halving, logged as a pressure unit
+        assert sched._drain_cap() == 512
+        assert sched.metrics.backpressure_shrinks.value() == before + 1
+        assert sched.batch_cap_log[-1][2] == 1
+        # no new attempts -> the pressure unit is gone next cycle
+        assert sched._drain_cap() == 1024
+
+    def test_express_occupancy_ewma_shrinks_bulk(self):
+        sched = self._sched()
+        for i in range(100):
+            sched.queue.add(self._pod(f"b{i}", 0))
+        for i in range(100):
+            sched.queue.add(self._pod(f"hi{i}", sched.lane_priority))
+        # express cycle: lane-sized cap, NEVER shrunk, EWMA goes hot
+        assert sched._drain_cap() == 128
+        assert sched._express_ewma > 0.05
+        got = sched.queue.pop_batch(128, timeout=0)
+        assert sum(1 for p in got if (p.spec.priority or 0) > 0) == 100
+        # bulk cycles right after the express burst: one EWMA shrink
+        # unit while hot, decaying back to the exact depth policy
+        caps = [sched._drain_cap() for _ in range(6)]
+        assert caps[0] == 64            # pow2ceil(72)=128, one halving
+        assert caps[3] == 128           # EWMA decayed below the knee
+        assert caps[-1] == 128
+        assert sched.metrics.backpressure_shrinks.value() > 0
 
 
 @pytest.mark.slow

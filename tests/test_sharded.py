@@ -95,16 +95,20 @@ def _fixture(client_cls, variant, n_nodes=24, n_pods=96):
     return client, nodes, pods
 
 
-def _drain(mesh, variant, batch_size=32, n_nodes=24, n_pods=96):
+def _drain(mesh, variant, batch_size=32, n_nodes=24, n_pods=96,
+           commit_async=None):
     """mesh=1 is the EXPLICIT single-device baseline (resolve_mesh maps
     n<=1 to no mesh without consulting KTPU_MESH — a mesh-flipped
-    environment must not contaminate the bit-identity control)."""
+    environment must not contaminate the bit-identity control).
+    `commit_async` pins the commit stage inline (False) or onto its
+    thread (True); None leaves it to Scheduler._commit_overlaps."""
     from kubernetes_tpu import api
     from kubernetes_tpu.api import Quantity
     from kubernetes_tpu.scheduler import Scheduler
     from kubernetes_tpu.state import Client
     client, nodes, pods = _fixture(Client, variant, n_nodes, n_pods)
     sched = Scheduler(client, batch_size=batch_size, mesh=mesh)
+    sched._commit_async = commit_async
     for n in nodes:
         sched.cache.add_node(n)
     if variant == "nominated":
@@ -161,17 +165,15 @@ def test_drain_independent_of_commit_thread_timing(variant, monkeypatch):
     pod."""
     import time as _time
     from kubernetes_tpu.scheduler import Scheduler
-    monkeypatch.setenv("KTPU_COMMIT_THREAD", "0")
-    _, inline, _ = _drain(1, variant)
-    monkeypatch.setenv("KTPU_COMMIT_THREAD", "1")
-    _, threaded, _ = _drain(1, variant)
+    _, inline, _ = _drain(1, variant, commit_async=False)
+    _, threaded, _ = _drain(1, variant, commit_async=True)
     tracked = Scheduler._tracked_assume
 
     def slow_assume(self, pod):
         _time.sleep(0.002)
         tracked(self, pod)
     monkeypatch.setattr(Scheduler, "_tracked_assume", slow_assume)
-    _, slowed, _ = _drain(1, variant)
+    _, slowed, _ = _drain(1, variant, commit_async=True)
     assert inline == threaded
     assert inline == slowed
 

@@ -264,11 +264,12 @@ class TestInScanParity:
         import sys
         sys.path.insert(0, os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
-        import bench
-        rate, _, extra = bench.measure_parity("spread", 300, 60)
+        import fakecluster
+        rate, _, extra = fakecluster.measure_parity("spread", 300, 60)
         assert rate == 1.0, f"spread parity {rate}"
         assert extra["batch_imbalance"] <= extra["oracle_imbalance"] + 1
-        rate_a, _, _ = bench.measure_parity("pod-anti-affinity", 300, 60)
+        rate_a, _, _ = fakecluster.measure_parity(
+            "pod-anti-affinity", 300, 60)
         assert rate_a >= 0.99, f"anti-affinity parity {rate_a}"
 
 
@@ -519,7 +520,7 @@ class TestInScanSoftCredits:
         for i in range(4):
             cache.add_node(self._mk_node(i))
         sched = BatchScheduler(cache, weights=dict(self.WEIGHTS))
-        sched.soft_score_chunk = 8
+        sched.SOFT_SCORE_CHUNK = 8
         pods = [self._mk_pod(i) for i in range(24)]
         # 3 distinct preferred terms: the in-scan tables cover the batch,
         # so the old 256-style sub-chunking is lifted
@@ -533,7 +534,7 @@ class TestInScanSoftCredits:
             cache.add_node(self._mk_node(i))
         sched = BatchScheduler(cache, weights=dict(self.WEIGHTS))
         sched.sched_metrics = SchedulerMetrics()
-        sched.soft_score_chunk = 8
+        sched.SOFT_SCORE_CHUNK = 8
         pods = []
         for i in range(sched.SOFT_TERM_CAP + 8):
             p = self._mk_pod(i)

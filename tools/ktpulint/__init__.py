@@ -22,6 +22,7 @@ Rules:
     KTPU004 metric-naming         _total/_seconds suffixes + resolution
     KTPU005 silent-cap            *_CAP/*_LIMIT clamp with no counter
     KTPU006 lock-order            acquires-while-holding cycles
+    KTPU007 environment-read      os.environ / os.getenv outside cmd/
 
 Suppress inline (reason MANDATORY — a bare disable is itself an error):
 

@@ -49,11 +49,12 @@ def import_aliases(tree: ast.Module) -> Dict[str, str]:
     return aliases
 
 
-def resolve_call(node: ast.Call, aliases: Dict[str, str]) -> Optional[str]:
-    """The call target's fully-dotted origin, or None when the base is
-    not an imported name (a local/instance receiver is someone else's
-    problem — this keeps `rng.random()` from matching `random.random`)."""
-    name = dotted_name(node.func)
+def resolve_name(expr: ast.AST, aliases: Dict[str, str]) -> Optional[str]:
+    """A Name/Attribute chain's fully-dotted origin, or None when the
+    base is not an imported name (a local/instance receiver is someone
+    else's problem — this keeps `rng.random()` from matching
+    `random.random`)."""
+    name = dotted_name(expr)
     if name is None:
         return None
     first, _, rest = name.partition(".")
@@ -61,6 +62,11 @@ def resolve_call(node: ast.Call, aliases: Dict[str, str]) -> Optional[str]:
     if origin is None:
         return None
     return f"{origin}.{rest}" if rest else origin
+
+
+def resolve_call(node: ast.Call, aliases: Dict[str, str]) -> Optional[str]:
+    """The call target's fully-dotted origin (see resolve_name)."""
+    return resolve_name(node.func, aliases)
 
 
 def enclosing_map(tree: ast.Module, kinds) -> Dict[ast.AST, ast.AST]:
@@ -579,7 +585,54 @@ class LockOrder(Rule):
         return out
 
 
+# --------------------------------------------------------------- KTPU007
+
+class EnvironmentRead(Rule):
+    """An environment variable read inside the library selects the
+    program that runs without any caller, test or configuration file
+    saying so: an exported `KTPU_CLASS_SCAN=0` once changed what every
+    benchmark cell timed. Deployment settings (paths, addresses,
+    credentials) enter through `cmd/`'s flags and files; a component
+    takes the rest as arguments. Flags `os.environ.get`, `os.environ[...]`
+    and `os.getenv` under kubernetes_tpu/, except in `cmd/`,
+    `utils/certs.py` and for `JAX_COMPILATION_CACHE_DIR` (where the
+    compile cache lives is the deployment's to say)."""
+
+    id = "KTPU007"
+    title = "environment-read"
+
+    EXEMPT_PREFIXES = ("kubernetes_tpu/cmd/",)
+    EXEMPT_PATHS = ("kubernetes_tpu/utils/certs.py",)
+    EXEMPT_VARIABLES = {"JAX_COMPILATION_CACHE_DIR"}
+
+    def check(self, module: Module) -> List[Finding]:
+        if not module.path.startswith("kubernetes_tpu/") \
+                or module.path.startswith(self.EXEMPT_PREFIXES) \
+                or module.path in self.EXEMPT_PATHS:
+            return []
+        aliases = import_aliases(module.tree)
+        out: List[Finding] = []
+        for node in ast.walk(module.tree):
+            if isinstance(node, ast.Call) and resolve_call(
+                    node, aliases) in ("os.environ.get", "os.getenv"):
+                key = node.args[0] if node.args else None
+            elif isinstance(node, ast.Subscript) \
+                    and isinstance(node.ctx, ast.Load) \
+                    and resolve_name(node.value, aliases) == "os.environ":
+                key = node.slice
+            else:
+                continue
+            name = key.value if isinstance(key, ast.Constant) else None
+            if name in self.EXEMPT_VARIABLES:
+                continue
+            out.append(Finding(
+                module.path, node.lineno, self.id,
+                f"environment read ({name or 'computed name'}) outside "
+                "cmd/: take it as an argument or a configuration key"))
+        return out
+
+
 ALL_RULES = (SwallowedException, WallClock, UnseededRandom, MetricNaming,
-             SilentCap, LockOrder)
+             SilentCap, LockOrder, EnvironmentRead)
 
 RULE_INDEX = {r.id: r for r in ALL_RULES}
