@@ -42,6 +42,10 @@ INSCAN_FALLBACK_REASONS = ("term_cap", "kmax", "soft_terms", "soft_kmax",
                            "soft_gang", "aff_growth")
 
 
+#: every `cache` of scheduler_node_vector_rebuilds_total
+NODE_VECTOR_CACHES = ("terms", "scores", "zones")
+
+
 class SchedulerMetrics:
     def __init__(self, registry: Registry = None):
         self.registry = registry if registry is not None else Registry()
@@ -211,6 +215,24 @@ class SchedulerMetrics:
             "scheduler_host_to_device_transfers_total",
             "Host-to-device transfers issued by the launch path")
         self.host_to_device_transfers.declare()
+        # cached node vectors (tensorize.NodeVectorCache behind
+        # TermCompiler and ScoreCompiler, and ScoreCompiler's zone ids)
+        # catch up with the mirror by the rows written since they were
+        # last true; the shell installs both counters on the algorithm's
+        # mirror. Rows: every row of every such vector recomputed, by
+        # patch or by full walk (a bind-only cycle: rows that took a pod
+        # x vectors in use). Rebuilds: the full walks, by cache; none in
+        # a window without a node event
+        self.node_vector_rows_recomputed = r.counter(
+            "scheduler_node_vector_rows_recomputed_total",
+            "Rows of cached node vectors recomputed")
+        self.node_vector_rows_recomputed.declare()
+        self.node_vector_rebuilds = r.counter(
+            "scheduler_node_vector_rebuilds_total",
+            "Full walks over the nodes to rebuild a cached node vector, "
+            "by cache")
+        for cache in NODE_VECTOR_CACHES:
+            self.node_vector_rebuilds.declare(cache=cache)
         # ---- sharded drain (mesh execution substrate) ----
         # batches routed through the shard_map kernel (per-shard
         # filter+score, cross-shard argmax) vs the GSPMD/single paths

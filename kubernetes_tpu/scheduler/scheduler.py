@@ -205,6 +205,12 @@ class Scheduler:
         #: scheduler_host_to_device_transfers_total, counted at put_named
         self.algorithm.mirror.transfers = \
             self.metrics.host_to_device_transfers
+        #: scheduler_node_vector_{rows_recomputed,rebuilds}_total, counted
+        #: where a cached node vector catches up with the mirror
+        self.algorithm.mirror.vector_rows_recomputed = \
+            self.metrics.node_vector_rows_recomputed
+        self.algorithm.mirror.vector_rebuilds = \
+            self.metrics.node_vector_rebuilds
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         #: the exception that ended the run loop (MAX_LOOP_ERROR_STREAK

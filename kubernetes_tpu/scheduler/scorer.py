@@ -38,9 +38,13 @@ from ..api.core import Pod
 from ..api.meta import controller_ref
 from . import priorities as prios
 from .nodeinfo import NodeInfo
-from .tensorize import TensorMirror, TermCompiler, _canon_tolerations
+from .tensorize import (NodeVectorCache, TensorMirror, TermCompiler,
+                        _canon_tolerations)
 
 MAXP = float(prios.MAX_PRIORITY)
+#: templates ScoreCompiler._pod_has_spread_selectors remembers; past it
+#: the memo starts over (it outlives an epoch, so the count bounds it)
+SPREAD_SEL_MEMO_SIZE = 4096
 
 
 def _canon_preferred_node_affinity(pod: Pod) -> Tuple:
@@ -92,6 +96,18 @@ def _has_preferred_pod_affinity(pod: Pod) -> bool:
          aff.pod_anti_affinity.preferred_during_scheduling_ignored_during_execution)))
 
 
+def _zone_of(ni: NodeInfo) -> str:
+    return ni.node.metadata.labels.get(wellknown.LABEL_ZONE, "")
+
+
+def _node_flags(ni: NodeInfo) -> Tuple[bool, bool, bool]:
+    """What a priority needs some node to have before it can tell nodes
+    apart: a PreferNoSchedule taint, the prefer-avoid annotation, images."""
+    return (any(t.effect == "PreferNoSchedule" for t in ni.taints),
+            prios.PREFER_AVOID_PODS_ANNOTATION in ni.node.metadata.annotations,
+            bool(ni.image_sizes))
+
+
 class ScoreCompiler:
     """Builds the static [P, N] score matrix for a batch."""
 
@@ -110,11 +126,22 @@ class ScoreCompiler:
         self.weights = dict(weights if weights is not None
                             else prios.DEFAULT_PRIORITY_WEIGHTS)
         self.hard_pod_affinity_weight = hard_pod_affinity_weight
+        #: the mirror epoch the zone ids and the three flags are true for
         self._epoch = -1
-        self._vec_cache: Dict[Tuple, np.ndarray] = {}
+        self._vec_cache = NodeVectorCache(mirror, np.float32, "scores")
         self._zone_ids: Optional[np.ndarray] = None
+        self._n_zones = 1
+        #: per row, the zone label its zone id was numbered from (None:
+        #: the row held no node), and whether it holds each property of
+        #: _node_flags; _flag_counts is the rows that do, so a flag is
+        #: "count > 0" without a walk
+        self._row_zone: List[Optional[str]] = []
+        self._row_flags = np.zeros((3, 0), bool)
+        self._flag_counts = np.zeros((3,), np.int64)
         self._any_prefer_taints = False
         self._any_avoid_annotations = False
+        self._any_images = False
+        self._spread_sel_memo: Dict[Tuple, bool] = {}
         self._cluster_has_affinity_pods = False
         #: bumped by invalidate_spread_selectors (Service/RC/RS/SS
         #: events): part of the spread chain signature, so a selector
@@ -134,49 +161,67 @@ class ScoreCompiler:
     # ------------------------------------------------------- cached vectors
 
     def _refresh_epoch(self) -> None:
-        if self._epoch == self.mirror.epoch:
+        """Bring the zone ids and the three "some node has ..." flags to
+        the mirror's epoch by the rows written since: a row that kept its
+        zone label leaves the numbering alone and moves the flags' counts
+        by its before and after. A row whose label differs from the one
+        recorded for it (a new node's always does), a removed row, a
+        resize, or REBUILD_SHARE of the rows rescans everything, so zone
+        ids and _n_zones are always what a full scan numbers."""
+        m = self.mirror
+        if self._epoch == m.epoch:
             return
-        self._epoch = self.mirror.epoch
-        self._vec_cache.clear()
-        self._spread_sel_memo: Dict[Tuple, bool] = {}
-        cap = self.mirror.t.capacity
+        rows = m.rows_since(self._epoch) if self._epoch >= 0 \
+            and len(self._row_zone) == m.t.capacity else None
+        if rows is not None:
+            rows = rows.tolist()
+            for row in rows:
+                ni = m.infos[row]
+                if ni is None or ni.node is None \
+                        or _zone_of(ni) != self._row_zone[row]:
+                    rows = None
+                    break
+        if rows is None:
+            self._rescan()
+        elif rows:
+            before = self._row_flags[:, rows].sum(axis=1)
+            for row in rows:
+                self._row_flags[:, row] = _node_flags(m.infos[row])
+            self._flag_counts += self._row_flags[:, rows].sum(axis=1) - before
+            m.vector_rows_recomputed.inc(len(rows))
+        self._epoch = m.epoch
+        self._any_prefer_taints, self._any_avoid_annotations, \
+            self._any_images = (bool(c) for c in self._flag_counts > 0)
+
+    def _rescan(self) -> None:
+        """The full walk: zones numbered in row order of first sight."""
+        m = self.mirror
+        cap = m.t.capacity
         zone_ids = np.zeros((cap,), np.int32)
         zones: Dict[str, int] = {"": 0}
-        any_taints = False
-        any_avoid = False
-        any_images = False
-        for row, ni in enumerate(self.mirror.infos):
+        row_zone: List[Optional[str]] = [None] * cap
+        flags = np.zeros((3, cap), bool)
+        for row, ni in enumerate(m.infos):
             if ni is None or ni.node is None:
                 continue
-            z = ni.node.metadata.labels.get(wellknown.LABEL_ZONE, "")
+            z = row_zone[row] = _zone_of(ni)
             zid = zones.get(z)
             if zid is None:
                 zid = len(zones)
                 zones[z] = zid
             zone_ids[row] = zid
-            if any(t.effect == "PreferNoSchedule" for t in ni.taints):
-                any_taints = True
-            if prios.PREFER_AVOID_PODS_ANNOTATION in ni.node.metadata.annotations:
-                any_avoid = True
-            if ni.image_sizes:
-                any_images = True
+            flags[:, row] = _node_flags(ni)
         self._zone_ids = zone_ids
         self._n_zones = len(zones)
-        self._any_prefer_taints = any_taints
-        self._any_avoid_annotations = any_avoid
-        self._any_images = any_images
+        self._row_zone = row_zone
+        self._row_flags = flags
+        self._flag_counts = flags.sum(axis=1)
+        self._spread_sel_memo = {}
+        m.vector_rebuilds.inc(cache="zones")
+        m.vector_rows_recomputed.inc(m.n_rows)
 
     def _vec(self, key: Tuple, fn) -> np.ndarray:
-        hit = self._vec_cache.get(key)
-        if hit is not None:
-            return hit
-        cap = self.mirror.t.capacity
-        vec = np.zeros((cap,), np.float32)
-        for row, ni in enumerate(self.mirror.infos):
-            if ni is not None and ni.node is not None:
-                vec[row] = fn(ni)
-        self._vec_cache[key] = vec
-        return vec
+        return self._vec_cache.vector(key, fn)
 
     def _node_affinity_raw(self, pod: Pod, meta: prios.PriorityMetadata
                            ) -> Optional[np.ndarray]:
@@ -217,9 +262,12 @@ class ScoreCompiler:
         if not meta.pod_selectors:
             return None
         # selectors derive from the pod's owning service/controller; key by
-        # namespace + its labels (pods of one controller share both)
+        # namespace + its labels (pods of one controller share both), and
+        # by the generation of the selector sources: the vector outlives
+        # an epoch, and a Service event changes what it counts
         key = ("spread", pod.metadata.namespace,
-               tuple(sorted(pod.metadata.labels.items())))
+               tuple(sorted(pod.metadata.labels.items())),
+               self.spread_sel_gen)
         return self._vec(key, lambda ni: prios.selector_spread_map(pod, meta, ni))
 
     # ------------------------------------------------------------- compile
@@ -272,10 +320,11 @@ class ScoreCompiler:
     def invalidate_spread_selectors(self) -> None:
         """Drop the per-template spread-selector memo. The scheduler shell
         calls this on Service/RC/RS/StatefulSet informer events (the same
-        events that move parked pods back to active): mirror.epoch only
-        moves on node changes, so without this a Service created mid-run
-        on a node-quiet cluster would leave its templates memoized as
-        selector-less and silently skip spread scoring."""
+        events that move parked pods back to active): the memo reads the
+        listers, not the nodes, so without this a Service created mid-run
+        would leave its templates memoized as selector-less and silently
+        skip spread scoring. The generation is part of the key of every
+        cached spread-count vector, so those start over as well."""
         self._spread_sel_memo = {}
         self.spread_sel_gen += 1
 
@@ -284,17 +333,17 @@ class ScoreCompiler:
         selector matches the pod; without one, the whole (ns, labels)
         score-key component — and its per-template fits_row +
         PriorityMetadata work — is dead weight. Memoized per template,
-        invalidated by node epoch AND selector-source events
-        (invalidate_spread_selectors), so a selector-less 16k-pod burst
-        skips static scoring entirely."""
-        memo = getattr(self, "_spread_sel_memo", None)
-        if memo is None:
-            memo = self._spread_sel_memo = {}
+        invalidated by selector-source events
+        (invalidate_spread_selectors) and by a full rescan of the nodes,
+        so a selector-less 16k-pod burst skips static scoring entirely."""
+        memo = self._spread_sel_memo
         key = (pod.metadata.namespace,
                tuple(sorted(pod.metadata.labels.items())))
         hit = memo.get(key)
         if hit is None:
             hit = bool(self.listers.selectors_for_pod(pod))
+            if len(memo) >= SPREAD_SEL_MEMO_SIZE:
+                memo.clear()
             memo[key] = hit
         return hit
 

@@ -16,7 +16,10 @@ interface calls of findNodesThatFit/PrioritizeNodes
   2. TermCompiler — label selectors, taints/tolerations, host ports and
      hostname constraints compiled into cached per-node boolean vectors.
      String matching never reaches the device: every unique term is evaluated
-     once per node-epoch against the snapshot (pods in one Deployment share
+     once per node and then again only for the rows the mirror has written
+     since (NodeVectorCache: the mirror stamps each row with the epoch of
+     its last write, so a cycle that follows a bind re-evaluates the rows
+     that took a pod, not the cluster; pods in one Deployment share
      selectors, so the cache hit rate is ~1).
 
   3. PodBatchTensors — the pod-axis arrays for one scheduling batch:
@@ -34,7 +37,8 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from collections import OrderedDict
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -53,6 +57,18 @@ N_FIXED_COLS = 3
 
 CFG_KEYS = ("alloc", "max_pods", "node_ok", "mem_pressure", "valid")
 USAGE_KEYS = ("used", "nonzero_used", "pod_count")
+
+#: share of the live rows written since a cached node vector was last true
+#: at or above which the vector is rebuilt by the full walk instead of
+#: patched row by row (TensorMirror.rows_since). A patched row and a walked
+#: row cost the same call of the same per-node function; past half the
+#: cluster the walk's plain enumerate is no dearer than the patch's row
+#: list, and it is what a resize or a start-up takes anyway
+REBUILD_SHARE = 0.5
+#: cached node vectors a NodeVectorCache keeps; the least recently used
+#: goes first. Entries outlive an epoch, so the count is what bounds them
+#: (a [capacity] f32 vector of a 50,000-node cluster is 256 KiB)
+NODE_VECTOR_CACHE_SIZE = 128
 
 
 def _bucket(n: int, minimum: int = 128) -> int:
@@ -160,8 +176,17 @@ class TensorMirror:
         self._free: List[int] = list(range(self.t.capacity))
         # row-aligned NodeInfo refs for term compilation / host fallbacks
         self.infos: List[Optional[NodeInfo]] = [None] * self.t.capacity
-        #: bumped on any node change; TermCompiler cache epoch
+        #: bumped by every non-empty apply(): "some node changed". The
+        #: chain signatures, the nominated-reservation key and the
+        #: extender encode read exactly that
         self.epoch = 0
+        #: per row, the epoch of its last _write_row / _remove_row: "rows
+        #: written since epoch e" is one compare (rows_since), which is
+        #: how a cached node vector catches up by row
+        self.row_epoch = np.zeros((self.t.capacity,), np.int64)
+        #: rows apply_chained wrote: stamped by the next apply(), when
+        #: the epoch they were held back from moves
+        self._chained_rows: set = set()
         self._dirty_rows: set = set()
         self._device_cfg: Optional[dict] = None
         self._device_usage: Optional[dict] = None
@@ -177,6 +202,13 @@ class TensorMirror:
         #: SchedulerMetrics' scheduler_host_to_device_transfers_total
         #: here; a bare mirror counts on a counter of its own
         self.transfers = Counter("scheduler_host_to_device_transfers_total")
+        #: rows of cached node vectors (TermCompiler's, ScoreCompiler's,
+        #: its zone ids) recomputed, by patch or by full walk, and the
+        #: full walks by cache ("terms" | "scores" | "zones"); installed
+        #: by the shell like `transfers`
+        self.vector_rows_recomputed = Counter(
+            "scheduler_node_vector_rows_recomputed_total")
+        self.vector_rebuilds = Counter("scheduler_node_vector_rebuilds_total")
 
     def _capacity_for(self, need: int, minimum: int = 128) -> int:
         """Row capacity for `need` nodes: the power-of-two bucket, padded
@@ -196,30 +228,47 @@ class TensorMirror:
         if not dirty_names:
             return
         self.epoch += 1
+        if self._chained_rows:
+            self.row_epoch[list(self._chained_rows)] = self.epoch
+            self._chained_rows.clear()
         need = len(snapshot.node_infos)
         if need > self.t.capacity:
             self._grow(self._capacity_for(need))
-        for name in dirty_names:
-            ni = snapshot.node_infos.get(name)
-            if ni is None or ni.node is None:
-                self._remove_row(name)
-            else:
-                self._write_row(name, ni)
+        self._write_rows(snapshot, dirty_names)
 
     def apply_chained(self, snapshot: Snapshot, dirty_names: Sequence[str]) -> None:
         """Host-row updates whose device effect already rides in a chained
         usage handle (the dirt is the pipelined drain's own assumes of
         residual-free pods: usage columns only — no label/taint/port/cfg
-        changes, so the term-cache epoch survives). Rows stay queued in
-        _dirty_rows: the next non-chained device_cfg_usage scatter rewrites
-        them with identical host-truth values (idempotent) or corrects any
-        foreign mutation that slipped past the chain_seq guard."""
+        changes, so the epoch survives and cached node vectors do not
+        see these rows until the next apply() moves it and stamps them).
+        Rows stay queued in _dirty_rows: the next non-chained
+        device_cfg_usage scatter rewrites them with identical host-truth
+        values (idempotent) or corrects any foreign mutation that slipped
+        past the chain_seq guard."""
+        self._chained_rows.update(self._write_rows(snapshot, dirty_names))
+
+    def _write_rows(self, snapshot: Snapshot,
+                    dirty_names: Sequence[str]) -> List[int]:
+        rows = []
         for name in dirty_names:
             ni = snapshot.node_infos.get(name)
             if ni is None or ni.node is None:
-                self._remove_row(name)
+                row = self._remove_row(name)
+                if row is not None:
+                    rows.append(row)
             else:
-                self._write_row(name, ni)
+                rows.append(self._write_row(name, ni))
+        return rows
+
+    def rows_since(self, epoch: int) -> Optional[np.ndarray]:
+        """The rows written or removed since `epoch`, ascending — or None
+        when they are REBUILD_SHARE of the live rows or more, and the
+        caller walks every row instead (after a resize every row is)."""
+        rows = np.flatnonzero(self.row_epoch > epoch)
+        if len(rows) >= REBUILD_SHARE * max(1, self.n_rows):
+            return None
+        return rows
 
     def device_ready(self) -> bool:
         """False after a capacity/column resize or invalidate_usage dropped
@@ -247,6 +296,8 @@ class TensorMirror:
         self.t = t
         self._free.extend(range(n, new_capacity))
         self.infos.extend([None] * (new_capacity - n))
+        # every cached node vector has the old length: all rows are new
+        self.row_epoch = np.full((new_capacity,), self.epoch, np.int64)
         self._device_cfg = None  # shapes changed; full re-upload
         self._device_usage = None
         self._dirty_rows.clear()
@@ -267,7 +318,7 @@ class TensorMirror:
             self._device_usage = None
             self._dirty_rows.clear()
 
-    def _write_row(self, name: str, ni: NodeInfo) -> None:
+    def _write_row(self, name: str, ni: NodeInfo) -> int:
         row = self.row_of.get(name)
         if row is None:
             row = self._free.pop()
@@ -308,12 +359,14 @@ class TensorMirror:
         t.mem_pressure[row] = ni.memory_pressure
         t.valid[row] = True
         self.infos[row] = ni
+        self.row_epoch[row] = self.epoch
         self._dirty_rows.add(row)
+        return row
 
-    def _remove_row(self, name: str) -> None:
+    def _remove_row(self, name: str) -> Optional[int]:
         row = self.row_of.pop(name, None)
         if row is None:
-            return
+            return None
         del self.name_of[row]
         self.infos[row] = None
         t = self.t
@@ -326,7 +379,9 @@ class TensorMirror:
         t.node_ok[row] = False
         t.mem_pressure[row] = False
         self._free.append(row)
+        self.row_epoch[row] = self.epoch
         self._dirty_rows.add(row)
+        return row
 
     # ------------------------------------------------------------- device
 
@@ -472,31 +527,94 @@ def precompute_pod_features(pod: Pod) -> Tuple:
     return sig
 
 
+class _NodeVector:
+    """One entry of a NodeVectorCache: the vector, the mirror epoch it is
+    true for, and the per-node function that builds a row of it."""
+
+    __slots__ = ("vec", "epoch", "fn")
+
+    def __init__(self):
+        self.vec: Optional[np.ndarray] = None
+        self.epoch = -1
+        self.fn: Optional[Callable] = None
+
+
+class NodeVectorCache:
+    """key -> the [capacity] vector of fn(NodeInfo) over the mirror's rows
+    (0 / False where a row holds no node), kept true by row: an entry
+    remembers the epoch it is true for and the per-node function that
+    built it, and on use at a later epoch recomputes the rows the mirror
+    has stamped since (TensorMirror.rows_since) and nothing else; at or
+    past REBUILD_SHARE of the live rows, or after a resize, it takes the
+    full walk: the same function over the same rows in the same order.
+
+    PRECONDITION: fn reads its own NodeInfo alone (taints, the node's
+    labels / fields / annotations, used_ports, image_sizes, the node's
+    pods) and nothing of another node: whatever reduces over nodes
+    happens later, on the finished vector (ScoreCompiler._compute_row).
+    Every write such an fn can see goes through _write_row / _remove_row
+    of the row it sits in, so an unstamped row answers as it did. What
+    apply_chained writes is seen when the next apply() stamps it, which
+    is when the epoch it was held back from moves.
+
+    Entries outlive an epoch, so the cache is bounded by count, least
+    recently used first; an evicted key is rebuilt on its next use."""
+
+    def __init__(self, mirror: TensorMirror, dtype, cache: str):
+        self.mirror = mirror
+        self.dtype = dtype
+        #: the `cache` label of scheduler_node_vector_rebuilds_total
+        self.cache = cache
+        self._entries: "OrderedDict[Tuple, _NodeVector]" = OrderedDict()
+
+    def clear(self) -> None:
+        self._entries.clear()
+
+    def vector(self, key: Tuple, fn: Callable[[NodeInfo], object]
+               ) -> np.ndarray:
+        m = self.mirror
+        entry = self._entries.get(key)
+        if entry is None:
+            if len(self._entries) >= NODE_VECTOR_CACHE_SIZE:
+                self._entries.popitem(last=False)
+            entry = self._entries[key] = _NodeVector()
+        else:
+            self._entries.move_to_end(key)
+        entry.fn = fn
+        vec = entry.vec
+        sized = vec is not None and len(vec) == m.t.capacity
+        if sized and entry.epoch == m.epoch:
+            return vec
+        rows = m.rows_since(entry.epoch) if sized else None
+        infos = m.infos
+        if rows is None:
+            vec = entry.vec = np.zeros((m.t.capacity,), self.dtype)
+            for row, ni in enumerate(infos):
+                if ni is not None and ni.node is not None:
+                    vec[row] = fn(ni)
+            m.vector_rebuilds.inc(cache=self.cache)
+            m.vector_rows_recomputed.inc(m.n_rows)
+        else:
+            for row in rows.tolist():
+                ni = infos[row]
+                vec[row] = fn(ni) \
+                    if ni is not None and ni.node is not None else 0
+            m.vector_rows_recomputed.inc(len(rows))
+        entry.epoch = m.epoch
+        return vec
+
+
 class TermCompiler:
     """Compiles pod-side constraint terms into cached [capacity] bool vectors
-    over the mirror's rows. Cache entries are invalidated by mirror epoch."""
+    over the mirror's rows. A cached vector catches up with the mirror by
+    the rows written since it was last true (NodeVectorCache)."""
 
     def __init__(self, mirror: TensorMirror):
         self.mirror = mirror
-        self._cache: Dict[Tuple, np.ndarray] = {}
-        self._cache_epoch = -1
+        self._cache = NodeVectorCache(mirror, bool, "terms")
 
     def _vector(self, key: Tuple, fn) -> np.ndarray:
-        # entries from an older mirror epoch are all stale at once: clear
-        # wholesale so the cache stays bounded by live terms per epoch
-        if self._cache_epoch != self.mirror.epoch:
-            self._cache.clear()
-            self._cache_epoch = self.mirror.epoch
-        hit = self._cache.get(key)
-        if hit is not None:
-            return hit
-        cap = self.mirror.t.capacity
-        vec = np.zeros((cap,), bool)
-        for row, ni in enumerate(self.mirror.infos):
-            if ni is not None and ni.node is not None:
-                vec[row] = fn(ni)
-        self._cache[key] = vec
-        return vec
+        return self._cache.vector(key, fn)
 
     def tolerations_vector(self, pod: Pod) -> np.ndarray:
         """PodToleratesNodeTaints as a node vector."""

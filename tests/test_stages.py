@@ -493,7 +493,9 @@ NEW_RATIOS = [
     "sched_affinity_scores_ms_per_pod", "sched_templates_per_cycle",
     "sched_inscan_fallback_share",
     # PR 32: what a launch costs in host-to-device transfers
-    "sched_h2d_transfers_per_cycle"]
+    "sched_h2d_transfers_per_cycle",
+    # PR 34: rows of cached node vectors a cycle recomputes
+    "sched_node_rows_recomputed_per_cycle"]
 NEW_READERS = ["idle_waiting_for_pods_share", "idle_waiting_for_hub_share",
                "idle_unattributed_share"]
 
