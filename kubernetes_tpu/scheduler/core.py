@@ -1679,7 +1679,10 @@ class BatchScheduler:
             batch = PodBatchTensors(pods, self.mirror, self.terms,
                                     extra_mask=extra_mask,
                                     extra_group=extra_group,
-                                    seq_base=self._seq_base)
+                                    seq_base=self._seq_base,
+                                    stage=self._stage)
+            if self.sched_metrics is not None:
+                self.sched_metrics.static_mask_rows.inc(batch.n_unique_masks)
             self._seq_base += len(pods)
             w = self.scorer.weights
             batch.resource_weights[0] = w.get("LeastRequestedPriority", 1)
@@ -1753,6 +1756,8 @@ class BatchScheduler:
                 # soft credits, and nominated reservations ride the carry /
                 # phantom overlay, so EVERY non-gang batch takes the fast path
                 batch.enable_class_scan()
+                if self.sched_metrics is not None:
+                    self.sched_metrics.scan_classes.inc(batch.n_classes)
             if chaining:
                 node_cfg, usage = self.mirror.device_cfg(), chain.new_usage
                 self.chained_launches += 1

@@ -36,7 +36,13 @@ STAGE_LEAVES = ("pop_wait", "refresh", "tensorize", "dispatch",
 #: affinity_scores in dispatch (scorer.static_scores while the cluster
 #: holds inter-pod score carriers). A batch that needs none of them
 #: enters none, so a cluster without such pods reads 0 in all three.
-STAGE_PARTS = ("topology_apply", "affinity_masks", "affinity_scores")
+#: static_masks, also in tensorize, is entered by every batch, once:
+#: PodBatchTensors' term vectors of the batch's distinct constraint keys
+#: (tolerations, node selector and required node affinity, host ports,
+#: hostname; cached vectors catching up by row) and their stack into
+#: unique_masks.
+STAGE_PARTS = ("topology_apply", "affinity_masks", "affinity_scores",
+               "static_masks")
 #: every reason of scheduler_topo_inscan_fallbacks_total
 INSCAN_FALLBACK_REASONS = ("term_cap", "kmax", "soft_terms", "soft_kmax",
                            "soft_gang", "aff_growth")
@@ -157,6 +163,22 @@ class SchedulerMetrics:
             "Distinct (anti-)affinity terms read by the batches' template "
             "mask rows, summed over batches")
         self.constraint_terms.declare()
+        # what a batch's static-mask and class machinery was sized by:
+        # the rows of PodBatchTensors.unique_masks (one per distinct
+        # constraint-term set of the batch; a deployment of a hundred
+        # node selectors has a hundred) and the classes of the class
+        # scan (distinct (template, score row) pairs, before bucketing;
+        # 0 for a batch that builds no class tables)
+        self.static_mask_rows = r.counter(
+            "scheduler_static_mask_rows_total",
+            "Rows of the batches' static feasibility masks (distinct "
+            "constraint-term sets), summed over batches")
+        self.static_mask_rows.declare()
+        self.scan_classes = r.counter(
+            "scheduler_scan_classes_total",
+            "Classes (distinct template and score-row pairs) of the "
+            "batches' class scans, summed over batches")
+        self.scan_classes.declare()
         # which way a batch's affinity rows were computed: host numpy or
         # the device matmuls of kernels/affinity.py (the score rows have
         # the host route alone)

@@ -38,6 +38,7 @@ from __future__ import annotations
 import math
 import threading
 from collections import OrderedDict
+from contextlib import nullcontext
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -558,7 +559,13 @@ class NodeVectorCache:
     is when the epoch it was held back from moves.
 
     Entries outlive an epoch, so the cache is bounded by count, least
-    recently used first; an evicted key is rebuilt on its next use."""
+    recently used first; an evicted key is rebuilt on its next use. A
+    deployment with a hundred node selectors keeps a hundred `sel` keys
+    alive here at once: each is used by every batch, so each recomputes
+    every row stamped since the last batch (a bind stamps its row
+    although it changes no label: rows x keys calls of fn a cycle), and
+    past NODE_VECTOR_CACHE_SIZE keys in one queue every use is an
+    eviction and a full walk."""
 
     def __init__(self, mirror: TensorMirror, dtype, cache: str):
         self.mirror = mirror
@@ -664,8 +671,13 @@ class PodBatchTensors:
 
     The static feasibility mask is deduplicated: `unique_masks [U, N]` holds
     one row per distinct constraint-term set, `mask_idx [P]` points each pod
-    at its row. Pods from one controller share every term, so U stays O(few)
-    while P is thousands — the device upload shrinks accordingly. Static
+    at its row. Pods from one controller share every term, so U is the
+    number of distinct term sets in the queue, not of pods: 1 where every
+    pod is alike, and as many as a deployment has node selectors where
+    workloads are confined to node pools (120 zone pairs in one pop:
+    U = 120 in a bucket of 128, a [128, N] bool table built and
+    uploaded every batch, 1 MiB at 8,192 rows, and as many classes in
+    the class scan). The building is timed as stage `static_masks`. Static
     priority scores use the same scheme (`unique_scores [S, N]`, `score_idx
     [P]`, filled by core.BatchScheduler from ScoreCompiler output; default is
     a single all-zeros row meaning "only on-device resource priorities").
@@ -674,7 +686,11 @@ class PodBatchTensors:
     def __init__(self, pods: List[Pod], mirror: TensorMirror,
                  terms: TermCompiler, extra_mask: Optional[np.ndarray] = None,
                  min_bucket: int = 8, seq_base: int = 0,
-                 extra_group: Optional[np.ndarray] = None):
+                 extra_group: Optional[np.ndarray] = None,
+                 stage: Optional[Callable] = None):
+        """`stage(name, **attrs)` is the caller's stage timer
+        (core.BatchScheduler._stage): the term vectors and their stack
+        are timed as `static_masks`, once a batch."""
         self.pods = pods
         P = _bucket(len(pods), min_bucket)
         vocab = mirror.vocab
@@ -719,7 +735,9 @@ class PodBatchTensors:
         # tolerations, and constraint terms; dedupe the per-pod numeric work
         # by template signature and fill rows with one gather per array.
         uniq: Dict[Tuple, int] = {}
-        rows: List[np.ndarray] = []
+        #: the first pod of each distinct constraint key, and its index
+        #: where the key carries an extra row (-1: none)
+        firsts: List[Tuple[Pod, int]] = []
         tmpl: Dict[Tuple, int] = {}
         tmpl_req: List[np.ndarray] = []
         tmpl_nz: List[Tuple[float, float]] = []
@@ -762,19 +780,8 @@ class PodBatchTensors:
                 blocked = blocked_sig
                 u = uniq.get(ckey)
                 if u is None:
-                    mask = terms.tolerations_vector(pod) & \
-                        terms.node_selector_vector(pod)
-                    pv = terms.host_ports_vector(pod)
-                    if pv is not None:
-                        mask = mask & pv
-                    hv = terms.hostname_vector(pod)
-                    if hv is not None:
-                        mask = mask & hv
-                    if has_extra:
-                        mask = mask & extra_mask[i]
-                    u = len(rows)
-                    uniq[ckey] = u
-                    rows.append(mask)
+                    u = uniq[ckey] = len(firsts)
+                    firsts.append((pod, i if has_extra else -1))
                 t_i = len(tmpl_req)
                 tmpl[tkey] = t_i
                 tmpl_req.append(req_row)
@@ -801,11 +808,26 @@ class PodBatchTensors:
         self._tmpl_blocked = tmpl_blocked
         self._tmpl_mask = tmpl_mask
         self._class_tables: Optional[Dict[str, np.ndarray]] = None
-        U = _bucket(len(rows), minimum=1)
+        U = _bucket(len(firsts), minimum=1)
         self.unique_masks = np.zeros((U, N), bool)
-        if rows:
-            self.unique_masks[:len(rows)] = np.stack(rows)
-        self.n_unique_masks = len(rows)
+        with stage("static_masks", rows=len(firsts)) if stage is not None \
+                else nullcontext():
+            for u, (pod, i) in enumerate(firsts):
+                mask = terms.tolerations_vector(pod) & \
+                    terms.node_selector_vector(pod)
+                pv = terms.host_ports_vector(pod)
+                if pv is not None:
+                    mask = mask & pv
+                hv = terms.hostname_vector(pod)
+                if hv is not None:
+                    mask = mask & hv
+                if i >= 0:
+                    mask = mask & extra_mask[i]
+                self.unique_masks[u] = mask
+        self.n_unique_masks = len(firsts)
+        #: distinct (template, score row) pairs of the class scan, before
+        #: bucketing (enable_class_scan); 0 while no class table is built
+        self.n_classes = 0
         # score dedupe table; default single zero row (resource-only scoring)
         self.score_idx = np.zeros((P,), np.int32)
         self.unique_scores = np.zeros((1, N), np.float32)
@@ -947,6 +969,7 @@ class PodBatchTensors:
         pair = self.tmpl_idx.astype(np.int64) * S \
             + self.score_idx.astype(np.int64)
         uniq, class_idx = np.unique(pair, return_inverse=True)
+        self.n_classes = len(uniq)
         C = _bucket(len(uniq), minimum=1)
         t_of = (uniq // S).astype(np.int64)
         s_of = (uniq % S).astype(np.int64)

@@ -224,9 +224,10 @@ def random_cluster(seed):
     return nodes, pods, colours
 
 
-def run_program(nodes, pods, batches):
+def run_program(nodes, pods, batches, prepare=None):
     """The pods through Scheduler.schedule_pending over the in-process
-    client, `batches` pods a cycle; what compare() is handed."""
+    client, `batches` pods a cycle; what compare() is handed.
+    `prepare(sched)` sees the scheduler before its first cycle."""
     from kubernetes_tpu.api import serde
     from kubernetes_tpu.runtime import SCHEME
     from kubernetes_tpu.scheduler import Scheduler
@@ -235,6 +236,8 @@ def run_program(nodes, pods, batches):
     for n in nodes:
         client.nodes().create(SCHEME.decode_any(n))
     sched = Scheduler(client, batch_size=1024)
+    if prepare is not None:
+        prepare(sched)
     sched.informers.start()
     sched.informers.wait_for_cache_sync()
     try:
@@ -263,13 +266,16 @@ def parse_metrics(lines):
     return parse("\n".join(lines) if not isinstance(lines, str) else lines)
 
 
-def judged(nodes, pods, listed, scrape):
+def judged(nodes, pods, listed, scrape, reference=None):
+    """compare() over a run of run_program, under this configuration's
+    reference or the one given."""
     created = {m["metadata"]["name"]: i + 1 for i, m in enumerate(pods)}
     watch = {p["metadata"]["name"]: p["spec"].get("nodeName")
              for p in listed}
     said = {}
     compared = verdict.compare(
-        ref, nodes, pods, created, watch, [], listed, scrape, [0, 0], "",
+        reference or ref, nodes, pods, created, watch, [], listed, scrape,
+        [0, 0], "",
         say=lambda phase, **fields: said.update(fields))
     return compared, said
 

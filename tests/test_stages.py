@@ -495,7 +495,10 @@ NEW_RATIOS = [
     # PR 32: what a launch costs in host-to-device transfers
     "sched_h2d_transfers_per_cycle",
     # PR 34: rows of cached node vectors a cycle recomputes
-    "sched_node_rows_recomputed_per_cycle"]
+    "sched_node_rows_recomputed_per_cycle",
+    # PR 35: the static masks a batch builds and the classes it scans
+    "sched_static_masks_ms_per_pod", "sched_static_masks_per_cycle",
+    "sched_scan_classes_per_cycle"]
 NEW_READERS = ["idle_waiting_for_pods_share", "idle_waiting_for_hub_share",
                "idle_unattributed_share"]
 
