@@ -50,6 +50,8 @@ INSCAN_FALLBACK_REASONS = ("term_cap", "kmax", "soft_terms", "soft_kmax",
 
 #: every `cache` of scheduler_node_vector_rebuilds_total
 NODE_VECTOR_CACHES = ("terms", "scores", "zones")
+#: every `side` of scheduler_mirror_row_writes_total
+MIRROR_ROW_WRITE_SIDES = ("node", "usage")
 
 
 class SchedulerMetrics:
@@ -240,11 +242,13 @@ class SchedulerMetrics:
         # cached node vectors (tensorize.NodeVectorCache behind
         # TermCompiler and ScoreCompiler, and ScoreCompiler's zone ids)
         # catch up with the mirror by the rows written since they were
-        # last true; the shell installs both counters on the algorithm's
-        # mirror. Rows: every row of every such vector recomputed, by
-        # patch or by full walk (a bind-only cycle: rows that took a pod
-        # x vectors in use). Rebuilds: the full walks, by cache; none in
-        # a window without a node event
+        # last true, on the side they read; the shell installs both
+        # counters on the algorithm's mirror. Rows: every row of every
+        # such vector recomputed, by patch or by full walk (a bind-only
+        # cycle: 0 for the vectors that read the node alone, the rows
+        # that took a pod for a host-port or spread vector; a node event:
+        # its rows x the vectors in use). Rebuilds: the full walks, by
+        # cache; none in a window without a node event
         self.node_vector_rows_recomputed = r.counter(
             "scheduler_node_vector_rows_recomputed_total",
             "Rows of cached node vectors recomputed")
@@ -255,6 +259,16 @@ class SchedulerMetrics:
             "by cache")
         for cache in NODE_VECTOR_CACHES:
             self.node_vector_rebuilds.declare(cache=cache)
+        # what the mirror's row writes changed (TensorMirror._write_row /
+        # _remove_row, installed like the two above): "node" where the
+        # node side moved (a row taken or removed, a later set_node),
+        # "usage" where the pods and their requests alone did (a bind).
+        # The usage share is the share of writes no node-side vector pays
+        self.mirror_row_writes = r.counter(
+            "scheduler_mirror_row_writes_total",
+            "Rows written to the tensor mirror, by the side that changed")
+        for side in MIRROR_ROW_WRITE_SIDES:
+            self.mirror_row_writes.declare(side=side)
         # ---- sharded drain (mesh execution substrate) ----
         # batches routed through the shard_map kernel (per-shard
         # filter+score, cross-shard argmax) vs the GSPMD/single paths

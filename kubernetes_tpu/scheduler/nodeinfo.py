@@ -10,6 +10,7 @@ for extended resources — the reference's column schema.
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Set, Tuple
 
@@ -92,14 +93,24 @@ def pod_has_affinity_constraints(pod: Pod) -> bool:
     return a is not None and (a.pod_affinity is not None or a.pod_anti_affinity is not None)
 
 
+#: one count for every set_node of the process, never one per object: two
+#: NodeInfos hold the same node_generation only when one is the clone()
+#: of the other
+_NODE_GENERATION = itertools.count(1)
+
+
 class NodeInfo:
     """Dense per-node aggregate; `generation` is bumped on every mutation so
-    snapshots copy only changed nodes (ref: node_info.go:83-99)."""
+    snapshots copy only changed nodes (ref: node_info.go:83-99).
+    `node_generation` names the set_node that the node side (`node`,
+    `taints`, `image_sizes`, `allocatable`, the three pressures) comes
+    from: a pod event leaves it alone, which is how the tensor mirror
+    tells a bind from a relabel (TensorMirror._write_row)."""
 
     __slots__ = ("node", "pods", "pods_with_affinity", "requested",
                  "non_zero_requested", "allocatable", "used_ports",
                  "taints", "memory_pressure", "disk_pressure", "pid_pressure",
-                 "image_sizes", "generation")
+                 "image_sizes", "generation", "node_generation")
 
     def __init__(self, node: Optional[Node] = None):
         self.node: Optional[Node] = None
@@ -117,6 +128,7 @@ class NodeInfo:
         self.pid_pressure = False
         self.image_sizes: Dict[str, int] = {}
         self.generation = 0
+        self.node_generation = 0
         if node is not None:
             self.set_node(node)
 
@@ -126,6 +138,7 @@ class NodeInfo:
 
     def set_node(self, node: Node) -> None:
         self.node = node
+        self.node_generation = next(_NODE_GENERATION)
         self.allocatable = Resource.from_request_map(helpers.node_allocatable(node))
         self.taints = list(node.spec.taints)
         self.memory_pressure = _cond(node, "MemoryPressure")
@@ -181,6 +194,7 @@ class NodeInfo:
         c.pid_pressure = self.pid_pressure
         c.image_sizes = dict(self.image_sizes)
         c.generation = self.generation
+        c.node_generation = self.node_generation
         return c
 
 

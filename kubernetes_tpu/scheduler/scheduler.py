@@ -211,6 +211,8 @@ class Scheduler:
             self.metrics.node_vector_rows_recomputed
         self.algorithm.mirror.vector_rebuilds = \
             self.metrics.node_vector_rebuilds
+        #: scheduler_mirror_row_writes_total{side}, counted at the write
+        self.algorithm.mirror.row_writes = self.metrics.mirror_row_writes
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         #: the exception that ended the run loop (MAX_LOOP_ERROR_STREAK

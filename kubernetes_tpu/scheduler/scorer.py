@@ -126,7 +126,8 @@ class ScoreCompiler:
         self.weights = dict(weights if weights is not None
                             else prios.DEFAULT_PRIORITY_WEIGHTS)
         self.hard_pod_affinity_weight = hard_pod_affinity_weight
-        #: the mirror epoch the zone ids and the three flags are true for
+        #: the mirror epoch the zone ids and the three flags (node side,
+        #: all of them) are true for
         self._epoch = -1
         self._vec_cache = NodeVectorCache(mirror, np.float32, "scores")
         self._zone_ids: Optional[np.ndarray] = None
@@ -162,16 +163,17 @@ class ScoreCompiler:
 
     def _refresh_epoch(self) -> None:
         """Bring the zone ids and the three "some node has ..." flags to
-        the mirror's epoch by the rows written since: a row that kept its
-        zone label leaves the numbering alone and moves the flags' counts
-        by its before and after. A row whose label differs from the one
-        recorded for it (a new node's always does), a removed row, a
-        resize, or REBUILD_SHARE of the rows rescans everything, so zone
-        ids and _n_zones are always what a full scan numbers."""
+        the mirror's epoch by the rows whose node side was written since
+        (a bind writes none): a row that kept its zone label leaves the
+        numbering alone and moves the flags' counts by its before and
+        after. A row whose label differs from the one recorded for it (a
+        new node's always does), a removed row, a resize, or
+        REBUILD_SHARE of the rows rescans everything, so zone ids and
+        _n_zones are always what a full scan numbers."""
         m = self.mirror
-        if self._epoch == m.epoch:
+        if self._epoch >= m.stamp_epoch("node"):
             return
-        rows = m.rows_since(self._epoch) if self._epoch >= 0 \
+        rows = m.rows_since(self._epoch, "node") if self._epoch >= 0 \
             and len(self._row_zone) == m.t.capacity else None
         if rows is not None:
             rows = rows.tolist()
@@ -220,22 +222,24 @@ class ScoreCompiler:
         m.vector_rebuilds.inc(cache="zones")
         m.vector_rows_recomputed.inc(m.n_rows)
 
-    def _vec(self, key: Tuple, fn) -> np.ndarray:
-        return self._vec_cache.vector(key, fn)
+    def _vec(self, key: Tuple, fn, reads: str) -> np.ndarray:
+        return self._vec_cache.vector(key, fn, reads)
 
     def _node_affinity_raw(self, pod: Pod, meta: prios.PriorityMetadata
                            ) -> Optional[np.ndarray]:
         key = ("nodeaff", _canon_preferred_node_affinity(pod))
         if not key[1]:
             return None
-        return self._vec(key, lambda ni: prios.node_affinity_map(pod, meta, ni))
+        return self._vec(key, lambda ni: prios.node_affinity_map(pod, meta, ni),
+                         reads="node")
 
     def _taint_raw(self, pod: Pod, meta: prios.PriorityMetadata
                    ) -> Optional[np.ndarray]:
         if not self._any_prefer_taints:
             return None  # all counts 0 -> reversed reduce gives constant 10
         key = ("tainttol", _canon_tolerations(pod))
-        return self._vec(key, lambda ni: prios.taint_toleration_map(pod, meta, ni))
+        return self._vec(key, lambda ni: prios.taint_toleration_map(pod, meta, ni),
+                         reads="node")
 
     def _image_raw(self, pod: Pod, meta: prios.PriorityMetadata
                    ) -> Optional[np.ndarray]:
@@ -245,7 +249,8 @@ class ScoreCompiler:
         if not images:
             return None
         key = ("img", images)
-        return self._vec(key, lambda ni: prios.image_locality_map(pod, meta, ni))
+        return self._vec(key, lambda ni: prios.image_locality_map(pod, meta, ni),
+                         reads="node")
 
     def _avoid_raw(self, pod: Pod, meta: prios.PriorityMetadata
                    ) -> Optional[np.ndarray]:
@@ -255,7 +260,8 @@ class ScoreCompiler:
         if ref is None or ref.kind not in ("ReplicationController", "ReplicaSet"):
             return None
         key = ("avoid", ref.kind, ref.name)
-        return self._vec(key, lambda ni: prios.node_prefer_avoid_map(pod, meta, ni))
+        return self._vec(key, lambda ni: prios.node_prefer_avoid_map(pod, meta, ni),
+                         reads="node")
 
     def _spread_counts(self, pod: Pod, meta: prios.PriorityMetadata
                        ) -> Optional[np.ndarray]:
@@ -268,7 +274,8 @@ class ScoreCompiler:
         key = ("spread", pod.metadata.namespace,
                tuple(sorted(pod.metadata.labels.items())),
                self.spread_sel_gen)
-        return self._vec(key, lambda ni: prios.selector_spread_map(pod, meta, ni))
+        return self._vec(key, lambda ni: prios.selector_spread_map(pod, meta, ni),
+                         reads="pods")
 
     # ------------------------------------------------------------- compile
 

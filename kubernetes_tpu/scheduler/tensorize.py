@@ -16,11 +16,12 @@ interface calls of findNodesThatFit/PrioritizeNodes
   2. TermCompiler — label selectors, taints/tolerations, host ports and
      hostname constraints compiled into cached per-node boolean vectors.
      String matching never reaches the device: every unique term is evaluated
-     once per node and then again only for the rows the mirror has written
-     since (NodeVectorCache: the mirror stamps each row with the epoch of
-     its last write, so a cycle that follows a bind re-evaluates the rows
-     that took a pod, not the cluster; pods in one Deployment share
-     selectors, so the cache hit rate is ~1).
+     once per node and then again only for the rows whose inputs the
+     mirror has written since (NodeVectorCache: the mirror stamps each row
+     with the epoch of its last write and of its last node-side write, so
+     a cycle that follows a bind re-evaluates the rows that took a pod for
+     a host-port term and no row for a selector or a toleration; pods in
+     one Deployment share selectors, so the cache hit rate is ~1).
 
   3. PodBatchTensors — the pod-axis arrays for one scheduling batch:
      requests, non-zero requests, flags, and the DEDUPLICATED static
@@ -185,6 +186,18 @@ class TensorMirror:
         #: written since epoch e" is one compare (rows_since), which is
         #: how a cached node vector catches up by row
         self.row_epoch = np.zeros((self.t.capacity,), np.int64)
+        #: per row, the epoch at which its node side last changed: what
+        #: NodeInfo.set_node derives from the Node object (labels, fields,
+        #: annotations, taints, images, allocatable, the pressures). The
+        #: row was newly taken, removed or resized, or _write_row was
+        #: handed a NodeInfo from a later set_node than the row last saw
+        #: (_row_node_gen). A bind moves row_epoch and leaves this alone
+        self.row_node_epoch = np.zeros((self.t.capacity,), np.int64)
+        self._row_node_gen: List[int] = [0] * self.t.capacity
+        #: the last epoch at which any row_node_epoch moved: a vector
+        #: that reads the node side alone and is true for it is a hit
+        #: without a look at the rows
+        self.node_epoch = 0
         #: rows apply_chained wrote: stamped by the next apply(), when
         #: the epoch they were held back from moves
         self._chained_rows: set = set()
@@ -210,6 +223,11 @@ class TensorMirror:
         self.vector_rows_recomputed = Counter(
             "scheduler_node_vector_rows_recomputed_total")
         self.vector_rebuilds = Counter("scheduler_node_vector_rebuilds_total")
+        #: _write_row / _remove_row calls by what they changed: side="node"
+        #: (the node side moved: a row taken, removed, or a later
+        #: set_node) or "usage" (pods and their requests alone, a bind);
+        #: installed by the shell like `transfers`
+        self.row_writes = Counter("scheduler_mirror_row_writes_total")
 
     def _capacity_for(self, need: int, minimum: int = 128) -> int:
         """Row capacity for `need` nodes: the power-of-two bucket, padded
@@ -262,11 +280,20 @@ class TensorMirror:
                 rows.append(self._write_row(name, ni))
         return rows
 
-    def rows_since(self, epoch: int) -> Optional[np.ndarray]:
-        """The rows written or removed since `epoch`, ascending — or None
-        when they are REBUILD_SHARE of the live rows or more, and the
-        caller walks every row instead (after a resize every row is)."""
-        rows = np.flatnonzero(self.row_epoch > epoch)
+    def stamp_epoch(self, reads: str) -> int:
+        """The last epoch at which anything a reader of `reads` ("node":
+        the node side alone; "pods": the node's pods as well) can see was
+        written: a vector true for it or a later one has nothing to catch
+        up with."""
+        return self.node_epoch if reads == "node" else self.epoch
+
+    def rows_since(self, epoch: int, reads: str) -> Optional[np.ndarray]:
+        """The rows whose `reads` side ("node" | "pods", as stamp_epoch)
+        was written or removed since `epoch`, ascending — or None when
+        they are REBUILD_SHARE of the live rows or more, and the caller
+        walks every row instead (after a resize every row is)."""
+        stamps = self.row_node_epoch if reads == "node" else self.row_epoch
+        rows = np.flatnonzero(stamps > epoch)
         if len(rows) >= REBUILD_SHARE * max(1, self.n_rows):
             return None
         return rows
@@ -297,8 +324,11 @@ class TensorMirror:
         self.t = t
         self._free.extend(range(n, new_capacity))
         self.infos.extend([None] * (new_capacity - n))
+        self._row_node_gen.extend([0] * (new_capacity - n))
         # every cached node vector has the old length: all rows are new
         self.row_epoch = np.full((new_capacity,), self.epoch, np.int64)
+        self.row_node_epoch = self.row_epoch.copy()
+        self.node_epoch = self.epoch
         self._device_cfg = None  # shapes changed; full re-upload
         self._device_usage = None
         self._dirty_rows.clear()
@@ -321,7 +351,8 @@ class TensorMirror:
 
     def _write_row(self, name: str, ni: NodeInfo) -> int:
         row = self.row_of.get(name)
-        if row is None:
+        taken = row is None
+        if taken:
             row = self._free.pop()
             self.row_of[name] = row
             self.name_of[row] = name
@@ -361,8 +392,17 @@ class TensorMirror:
         t.valid[row] = True
         self.infos[row] = ni
         self.row_epoch[row] = self.epoch
+        if taken or self._row_node_gen[row] != ni.node_generation:
+            self._row_node_gen[row] = ni.node_generation
+            self._stamp_node_side(row)
+        else:
+            self.row_writes.inc(side="usage")
         self._dirty_rows.add(row)
         return row
+
+    def _stamp_node_side(self, row: int) -> None:
+        self.row_node_epoch[row] = self.node_epoch = self.epoch
+        self.row_writes.inc(side="node")
 
     def _remove_row(self, name: str) -> Optional[int]:
         row = self.row_of.pop(name, None)
@@ -381,6 +421,7 @@ class TensorMirror:
         t.mem_pressure[row] = False
         self._free.append(row)
         self.row_epoch[row] = self.epoch
+        self._stamp_node_side(row)
         self._dirty_rows.add(row)
         return row
 
@@ -530,42 +571,52 @@ def precompute_pod_features(pod: Pod) -> Tuple:
 
 class _NodeVector:
     """One entry of a NodeVectorCache: the vector, the mirror epoch it is
-    true for, and the per-node function that builds a row of it."""
+    true for, the per-node function that builds a row of it, and the side
+    of its NodeInfo that function reads."""
 
-    __slots__ = ("vec", "epoch", "fn")
+    __slots__ = ("vec", "epoch", "fn", "reads")
 
     def __init__(self):
         self.vec: Optional[np.ndarray] = None
         self.epoch = -1
         self.fn: Optional[Callable] = None
+        self.reads = "pods"
 
 
 class NodeVectorCache:
     """key -> the [capacity] vector of fn(NodeInfo) over the mirror's rows
     (0 / False where a row holds no node), kept true by row: an entry
     remembers the epoch it is true for and the per-node function that
-    built it, and on use at a later epoch recomputes the rows the mirror
-    has stamped since (TensorMirror.rows_since) and nothing else; at or
-    past REBUILD_SHARE of the live rows, or after a resize, it takes the
-    full walk: the same function over the same rows in the same order.
+    built it, and on use at a later epoch recomputes the rows whose
+    inputs the mirror has stamped since (TensorMirror.rows_since) and
+    nothing else; at or past REBUILD_SHARE of the live rows, or after a
+    resize, it takes the full walk: the same function over the same rows
+    in the same order.
 
-    PRECONDITION: fn reads its own NodeInfo alone (taints, the node's
-    labels / fields / annotations, used_ports, image_sizes, the node's
-    pods) and nothing of another node: whatever reduces over nodes
-    happens later, on the finished vector (ScoreCompiler._compute_row).
-    Every write such an fn can see goes through _write_row / _remove_row
-    of the row it sits in, so an unstamped row answers as it did. What
-    apply_chained writes is seen when the next apply() stamps it, which
-    is when the epoch it was held back from moves.
+    PRECONDITION, in two halves. (1) fn reads its own NodeInfo alone and
+    nothing of another node: whatever reduces over nodes happens later,
+    on the finished vector (ScoreCompiler._compute_row). (2) The caller
+    says which side of that NodeInfo fn reads, as a fact about fn:
+    `reads="node"` for what set_node derives from the Node object and
+    nothing else (`node` with its labels, fields and annotations,
+    `taints`, `image_sizes`, `allocatable`, the pressures), so such an fn
+    may read NOTHING a pod event changes; `reads="pods"` for an fn that
+    also reads what the node's pods bring (`pods`, `used_ports`,
+    `requested`). Every write an fn can see goes through _write_row /
+    _remove_row of the row it sits in, which stamp row_epoch always and
+    row_node_epoch when the node side moved, so a row unstamped on the
+    side fn reads answers as it did: a "node" vector skips the rows a
+    bind wrote, a "pods" vector recomputes them. What apply_chained
+    writes (usage only, by construction) is seen by a "pods" vector when
+    the next apply() stamps it, which is when the epoch it was held back
+    from moves, and never by a "node" vector.
 
     Entries outlive an epoch, so the cache is bounded by count, least
     recently used first; an evicted key is rebuilt on its next use. A
     deployment with a hundred node selectors keeps a hundred `sel` keys
-    alive here at once: each is used by every batch, so each recomputes
-    every row stamped since the last batch (a bind stamps its row
-    although it changes no label: rows x keys calls of fn a cycle), and
-    past NODE_VECTOR_CACHE_SIZE keys in one queue every use is an
-    eviction and a full walk."""
+    alive here at once: each is used by every batch and is a hit until a
+    node event, and past NODE_VECTOR_CACHE_SIZE keys in one queue every
+    use is an eviction and a full walk."""
 
     def __init__(self, mirror: TensorMirror, dtype, cache: str):
         self.mirror = mirror
@@ -577,8 +628,11 @@ class NodeVectorCache:
     def clear(self) -> None:
         self._entries.clear()
 
-    def vector(self, key: Tuple, fn: Callable[[NodeInfo], object]
-               ) -> np.ndarray:
+    def vector(self, key: Tuple, fn: Callable[[NodeInfo], object],
+               reads: Optional[str] = None) -> np.ndarray:
+        """`reads` is the side fn reads (PRECONDITION, 2). Left out, the
+        key keeps the side it was last given; one never given a side
+        follows every write, which is right for any fn."""
         m = self.mirror
         entry = self._entries.get(key)
         if entry is None:
@@ -588,11 +642,14 @@ class NodeVectorCache:
         else:
             self._entries.move_to_end(key)
         entry.fn = fn
+        if reads is not None:
+            entry.reads = reads
+        reads = entry.reads
         vec = entry.vec
         sized = vec is not None and len(vec) == m.t.capacity
-        if sized and entry.epoch == m.epoch:
+        if sized and entry.epoch >= m.stamp_epoch(reads):
             return vec
-        rows = m.rows_since(entry.epoch) if sized else None
+        rows = m.rows_since(entry.epoch, reads) if sized else None
         infos = m.infos
         if rows is None:
             vec = entry.vec = np.zeros((m.t.capacity,), self.dtype)
@@ -614,14 +671,15 @@ class NodeVectorCache:
 class TermCompiler:
     """Compiles pod-side constraint terms into cached [capacity] bool vectors
     over the mirror's rows. A cached vector catches up with the mirror by
-    the rows written since it was last true (NodeVectorCache)."""
+    the rows written, on the side it reads, since it was last true
+    (NodeVectorCache)."""
 
     def __init__(self, mirror: TensorMirror):
         self.mirror = mirror
         self._cache = NodeVectorCache(mirror, bool, "terms")
 
-    def _vector(self, key: Tuple, fn) -> np.ndarray:
-        return self._cache.vector(key, fn)
+    def _vector(self, key: Tuple, fn, reads: str) -> np.ndarray:
+        return self._cache.vector(key, fn, reads)
 
     def tolerations_vector(self, pod: Pod) -> np.ndarray:
         """PodToleratesNodeTaints as a node vector."""
@@ -629,13 +687,15 @@ class TermCompiler:
         return self._vector(
             ("tol", _canon_tolerations(pod)),
             lambda ni: helpers.tolerates_taints(
-                tols, ni.taints, effects=["NoSchedule", "NoExecute"]))
+                tols, ni.taints, effects=["NoSchedule", "NoExecute"]),
+            reads="node")
 
     def node_selector_vector(self, pod: Pod) -> np.ndarray:
         """PodMatchNodeSelector (nodeSelector + required node affinity)."""
         return self._vector(
             ("sel", _canon_node_selector(pod)),
-            lambda ni: helpers.pod_matches_node_selector_and_affinity(pod, ni.node))
+            lambda ni: helpers.pod_matches_node_selector_and_affinity(pod, ni.node),
+            reads="node")
 
     def host_ports_vector(self, pod: Pod) -> Optional[np.ndarray]:
         """True where the pod's host ports are free (PodFitsHostPorts).
@@ -651,7 +711,8 @@ class TermCompiler:
                             ip == uip or ip == "0.0.0.0" or uip == "0.0.0.0"):
                         return False
             return True
-        return self._vector(("ports", tuple(sorted(wanted))), free)
+        return self._vector(("ports", tuple(sorted(wanted))), free,
+                            reads="pods")
 
     def hostname_vector(self, pod: Pod) -> Optional[np.ndarray]:
         """PodFitsHost: spec.nodeName pins the pod to one row."""

@@ -1,16 +1,23 @@
-"""ISSUE 34: cached node vectors catch up with the mirror by row.
+"""ISSUE 34, ISSUE 36: cached node vectors catch up with the mirror by
+row, and by the side of the row they read.
 
-`TensorMirror` stamps each row with the epoch of its last write;
-`tensorize.NodeVectorCache` (behind `TermCompiler._vector` and
+`TensorMirror` stamps each row with the epoch of its last write and, apart,
+with the epoch at which its node side last changed (a bind moves the first
+alone); `tensorize.NodeVectorCache` (behind `TermCompiler._vector` and
 `ScoreCompiler._vec`) and `ScoreCompiler._refresh_epoch` recompute the rows
-stamped since a vector was last true and nothing else. These tests pin
+stamped, on the side they read, since a vector was last true and nothing
+else. These tests pin
 
   - equality: over seeded random sequences of cache events, after every
     `refresh` what the long-lived compilers hold equals what compilers
     built fresh over the same mirror compute by the full walk;
   - the bound: the cache evicts by count without changing an answer;
+  - the sides: a bind recomputes no row of a vector that reads the node
+    alone and its row of one that reads the pods; every node event (in
+    place or by a new object, a delete, a retaken row, a resize) reaches
+    the vectors that read it;
   - engagement: through `Scheduler.schedule_pending`, a cycle that follows
-    a bind walks no cluster, by the two counters on /metrics.
+    a bind recomputes no row at all, by the counters on /metrics.
 """
 
 import copy
@@ -39,6 +46,7 @@ ZONE = api.wellknown.LABEL_ZONE
 AVOID = prios.PREFER_AVOID_PODS_ANNOTATION
 ROWS = "scheduler_node_vector_rows_recomputed_total"
 REBUILDS = "scheduler_node_vector_rebuilds_total"
+WRITES = "scheduler_mirror_row_writes_total"
 
 
 def make_node(name, zone=None):
@@ -317,27 +325,29 @@ def test_cached_vectors_equal_a_fresh_build_after_every_refresh(seed, n_nodes):
 def test_a_patch_recomputes_the_stamped_rows_and_no_others():
     c = Cluster(7, 100)
     c.refresh()
-    pod = c.probes[0]
-    c.terms.tolerations_vector(pod)
+    pod, port_pod = c.probes[0], c.probes[6]
     rows, rebuilds = c.mirror.vector_rows_recomputed, c.mirror.vector_rebuilds
-    assert (rows.value(), rebuilds.value(cache="terms")) == (100, 1)
+
+    def use():
+        c.terms.tolerations_vector(pod)
+        after_tol = rows.value()
+        c.terms.host_ports_vector(port_pod)
+        return after_tol, rows.value(), rebuilds.value(cache="terms")
+    assert use() == (100, 200, 2)
     # same epoch: a hit
-    c.terms.tolerations_vector(pod)
-    assert (rows.value(), rebuilds.value(cache="terms")) == (100, 1)
+    assert use() == (200, 200, 2)
     for _ in range(3):
         c.add_pod()
     touched = {p.spec.node_name for p in c.pods.values()}
     c.refresh()
-    c.terms.tolerations_vector(pod)
-    assert rows.value() == 100 + len(touched)
-    assert rebuilds.value(cache="terms") == 1
-    # at REBUILD_SHARE of the live rows the full walk is taken
+    # a bind changes no taint: no row of `tol`, its rows of `ports`
+    assert use() == (200, 200 + len(touched), 2)
+    # at REBUILD_SHARE of the live rows the full walk is taken: every
+    # update_node passes set_node, also with the object it already holds
     for name in sorted(c.nodes)[:50]:
         c.cache.update_node(c.nodes[name], c.nodes[name])
     c.refresh()
-    c.terms.tolerations_vector(pod)
-    assert rows.value() == 200 + len(touched)
-    assert rebuilds.value(cache="terms") == 2
+    assert use() == (300 + len(touched), 400 + len(touched), 4)
     c.check()
 
 
@@ -345,13 +355,20 @@ def test_zone_ids_rescan_on_a_relabel_and_patch_on_a_bind():
     c = Cluster(11, 100)
     c.refresh()
     c.scorer._refresh_epoch()
-    rebuilds = c.mirror.vector_rebuilds
-    assert rebuilds.value(cache="zones") == 1
+    rows, rebuilds = c.mirror.vector_rows_recomputed, c.mirror.vector_rebuilds
+    assert (rows.value(), rebuilds.value(cache="zones")) == (100, 1)
+    # a bind leaves the zones and the flags alone: not one row
+    c.add_pod()
+    c.refresh()
+    c.scorer._refresh_epoch()
+    assert (rows.value(), rebuilds.value(cache="zones")) == (100, 1)
+    assert c.scorer._epoch < c.mirror.epoch
+    # a taint keeps the row's zone: its flags are patched, one row
     c.add_pod()
     c.taint()
     c.refresh()
     c.scorer._refresh_epoch()
-    assert rebuilds.value(cache="zones") == 1
+    assert (rows.value(), rebuilds.value(cache="zones")) == (101, 1)
     name = sorted(c.nodes)[0]
     new = copy.deepcopy(c.nodes[name])
     new.metadata.labels[ZONE] = "z-new"
@@ -436,6 +453,322 @@ def test_a_service_event_starts_the_spread_vectors_over():
     c.check()
 
 
+# -------------------------------------------------------------- sides
+
+
+def _sided(seed=17, n_nodes=100):
+    """A cluster in which every kind of vector is alive before the event
+    under test: n1 carries a PreferNoSchedule taint, an image and the
+    prefer-avoid annotation, so the three flags are up; n0 is plain."""
+    c = Cluster(seed, n_nodes)
+    other = c.nodes["n1"]
+    other.spec.taints = [api.Taint(key="other", value="x",
+                                   effect="PreferNoSchedule")]
+    other.status.images = [api.ContainerImage(
+        names=["img-a"], size_bytes=500 * 1024 * 1024)]
+    other.metadata.annotations[AVOID] = json.dumps(
+        {"preferAvoidPods": [{"podSignature": {"podController": {
+            "kind": "ReplicationController", "name": "rc-1"}}}]})
+    for label in (ZONE, "disk"):
+        c.nodes["n0"].metadata.labels.pop(label, None)
+    for name in ("n0", "n1"):
+        c.cache.update_node(c.nodes[name], c.nodes[name])
+    c.refresh()
+    c.ask()
+    return c
+
+
+def _probe(c, name):
+    return next(p for p in c.probes if p.metadata.name == name)
+
+
+def _meta(c, pod):
+    return prios.PriorityMetadata(pod, c.listers)
+
+
+def _relabel(node):
+    node.metadata.labels["disk"] = "ssd"
+    node.metadata.labels[ZONE] = "z1"
+
+
+def _taint(node):
+    node.spec.taints = [
+        api.Taint(key="dedicated", value="x", effect="NoSchedule"),
+        api.Taint(key="soft", value="x", effect="PreferNoSchedule")]
+
+
+def _images(node):
+    node.status.images = [api.ContainerImage(
+        names=["img-a"], size_bytes=900 * 1024 * 1024)]
+
+
+#: event -> (the change to n0's Node, what the vectors that read it
+#: answered for n0's row before, and after)
+NODE_EVENTS = {
+    "relabel": (_relabel, {"sel": False, "sel-zones": False, "nodeaff": 1},
+                {"sel": True, "sel-zones": True, "nodeaff": 4}),
+    "taint": (_taint, {"tol": True, "tol-tolerated": True, "tainttol": 0,
+                       "tainttol-tolerated": 0},
+              {"tol": False, "tol-tolerated": True, "tainttol": 1,
+               "tainttol-tolerated": 0}),
+    "images": (_images, {"img": 0}, {"img": 8}),
+}
+
+
+def _answers(c, row):
+    """n0's row of one vector of each node-side kind, by what NODE_EVENTS
+    calls it."""
+    plain, image = _probe(c, "plain"), _probe(c, "image-a")
+    tolerates, soft = _probe(c, "tolerates"), _probe(c, "tolerates-soft")
+    prefers = _probe(c, "prefers")
+    c.scorer._refresh_epoch()
+    vectors = {
+        "sel": c.terms.node_selector_vector(_probe(c, "selects")),
+        "sel-zones": c.terms.node_selector_vector(_probe(c, "in-zones")),
+        "tol": c.terms.tolerations_vector(plain),
+        "tol-tolerated": c.terms.tolerations_vector(tolerates),
+        "nodeaff": c.scorer._node_affinity_raw(prefers, _meta(c, prefers)),
+        "tainttol": c.scorer._taint_raw(plain, _meta(c, plain)),
+        "tainttol-tolerated": c.scorer._taint_raw(soft, _meta(c, soft)),
+        "img": c.scorer._image_raw(image, _meta(c, image)),
+    }
+    return {kind: vec[row].item() for kind, vec in vectors.items()}
+
+
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["in-place", "by-copy"])
+@pytest.mark.parametrize("event", sorted(NODE_EVENTS))
+def test_a_node_event_reaches_the_vectors_that_read_the_node(event, in_place):
+    """update_node(old, old) after an in-place change passes set_node like
+    a new object does, and set_node is what the mirror's node stamp
+    follows: the row is recomputed, once a vector, and no other row."""
+    c = _sided()
+    change, before, after = NODE_EVENTS[event]
+    row = c.mirror.row_of["n0"]
+    was = _answers(c, row)
+    assert {k: was[k] for k in before} == before
+    rows, rebuilds = c.mirror.vector_rows_recomputed, c.mirror.vector_rebuilds
+    held = len(c.terms._cache._entries) + len(c.scorer._vec_cache._entries)
+    old = c.nodes["n0"]
+    new = old if in_place else copy.deepcopy(old)
+    change(new)
+    c.nodes["n0"] = new
+    c.cache.update_node(old, new)
+    node_epoch = c.mirror.node_epoch
+    c.refresh()
+    assert c.mirror.node_epoch == c.mirror.epoch == node_epoch + 1
+    assert c.mirror.row_node_epoch[row] == c.mirror.epoch
+    rows0, rebuilds0 = rows.value(), sum(rebuilds.snapshot().values())
+    now = _answers(c, row)
+    assert {k: now[k] for k in after} == after
+    assert {k: v for k, v in now.items() if k not in after} == \
+        {k: v for k, v in was.items() if k not in after}
+    c.ask()
+    # one row of every vector held (both sides: a node event is a write)
+    # and of the flags; the relabel moves a zone, which rescans those
+    zones = 100 if event == "relabel" else 1
+    assert rows.value() - rows0 == held + zones
+    assert sum(rebuilds.snapshot().values()) - rebuilds0 == \
+        (event == "relabel")
+    c.check()
+
+
+def _bound(c, name, node_name):
+    """A pod on `node_name` that holds host port 8080 and that the web
+    Service selects."""
+    pod = make_pod(name, labels={"app": "web"}, host_port=8080,
+                   node_name=node_name)
+    c.cache.add_pod(pod)
+    return pod
+
+
+def _pods_side(c, kind):
+    if kind == "ports":
+        return c.terms.host_ports_vector(_probe(c, "port-8080"))
+    web = _probe(c, "web")
+    return c.scorer._spread_counts(web, _meta(c, web))
+
+
+@pytest.mark.parametrize("kind", ["ports", "spread"])
+def test_a_vector_that_reads_the_pods_follows_a_bind_and_a_delete(kind):
+    """Declared node-side, either would keep its answer through both."""
+    c = _sided()
+    row = c.mirror.row_of["n0"]
+    free = {"ports": True, "spread": 0}[kind]
+    taken = {"ports": False, "spread": 1}[kind]
+    assert _pods_side(c, kind)[row] == free
+    rows = c.mirror.vector_rows_recomputed
+    node_epoch = c.mirror.node_epoch
+    pod = _bound(c, "binds", "n0")
+    c.refresh()
+    rows0 = rows.value()
+    assert _pods_side(c, kind)[row] == taken
+    assert rows.value() - rows0 == 1
+    c.cache.remove_pod(pod)
+    c.refresh()
+    assert _pods_side(c, kind)[row] == free
+    assert rows.value() - rows0 == 2
+    # neither was a node event: the node-side vectors were hits throughout
+    assert c.mirror.node_epoch == node_epoch < c.mirror.epoch
+    c.ask()
+    assert rows.value() - rows0 == 2 + 2    # the other of the two kinds
+    c.check()
+
+
+def test_a_removed_rows_answer_is_0_and_the_node_that_retakes_it_is_computed():
+    c = _sided()
+    row = c.mirror.row_of["n2"]
+    selects, plain = _probe(c, "selects"), _probe(c, "plain")
+    c.nodes["n2"].metadata.labels.pop("disk", None)
+    c.nodes["n2"].spec.taints = [api.Taint(key="soft", value="x",
+                                           effect="PreferNoSchedule")]
+    c.cache.update_node(c.nodes["n2"], c.nodes["n2"])
+    _bound(c, "held", "n2")
+    c.refresh()
+
+    def read():
+        c.scorer._refresh_epoch()
+        return (bool(c.terms.tolerations_vector(plain)[row]),
+                bool(c.terms.node_selector_vector(selects)[row]),
+                bool(_pods_side(c, "ports")[row]),
+                int(_pods_side(c, "spread")[row]),
+                int(c.scorer._taint_raw(plain, _meta(c, plain))[row]),
+                int(c.scorer._zone_ids[row]))
+    assert read()[:5] == (True, False, False, 1, 1)
+    c.cache.remove_node(c.nodes.pop("n2"))   # ni.node = None, no set_node
+    c.refresh()
+    assert "n2" not in c.mirror.row_of and c.mirror.infos[row] is None
+    assert c.mirror.row_node_epoch[row] == c.mirror.epoch
+    assert read() == (False, False, False, 0, 0, 0)
+    # the freed row is the next one taken
+    node = make_node("retakes", "z2")
+    node.metadata.labels["disk"] = "ssd"
+    node.spec.taints = [api.Taint(key="other", value="x",
+                                  effect="PreferNoSchedule")]
+    c.nodes["retakes"] = node
+    c.cache.add_node(node)
+    rows = c.mirror.vector_rows_recomputed
+    c.refresh()
+    assert c.mirror.row_of["retakes"] == row
+    rows0 = rows.value()
+    assert read()[:5] == (True, True, True, 0, 1)
+    assert c.scorer._zone_ids[row] > 0
+    # one row of each of the five vectors, and a rescan of the zones
+    assert rows.value() - rows0 == 5 + len(c.nodes)
+    c.check()
+
+
+def test_a_resize_walks_every_vector_whatever_it_reads():
+    c = _sided(n_nodes=100)
+    capacity = c.mirror.t.capacity
+    rows, rebuilds = c.mirror.vector_rows_recomputed, c.mirror.vector_rebuilds
+    held = len(c.terms._cache._entries) + len(c.scorer._vec_cache._entries)
+    c.grow()
+    c.refresh()
+    assert c.mirror.t.capacity > capacity
+    assert (c.mirror.row_node_epoch == c.mirror.epoch).all()
+    assert (c.mirror.row_epoch == c.mirror.epoch).all()
+    assert c.mirror.node_epoch == c.mirror.epoch
+    rows0, rebuilds0 = rows.value(), sum(rebuilds.snapshot().values())
+    c.ask()
+    assert sum(rebuilds.snapshot().values()) - rebuilds0 == held + 1
+    assert rows.value() - rows0 == (held + 1) * len(c.nodes)
+    c.check()
+
+
+def test_chained_rows_reach_a_ports_vector_at_the_next_apply_and_no_sel():
+    """apply_chained's rows are usage-only by construction: the next
+    apply() stamps them on row_epoch alone."""
+    c = _sided()
+    row, other = c.mirror.row_of["n0"], c.mirror.row_of["n2"]
+    selects = _probe(c, "selects")
+    rows = c.mirror.vector_rows_recomputed
+    _bound(c, "chained", "n0")
+    epoch = c.mirror.epoch
+    c.mirror.apply_chained(c.snapshot, c.cache.update_snapshot(c.snapshot))
+    assert (c.mirror.epoch, c.mirror.node_epoch) == (epoch, epoch)
+    rows0 = rows.value()
+    assert _pods_side(c, "ports")[row]          # not seen yet: a hit
+    c.terms.node_selector_vector(selects)
+    assert rows.value() == rows0
+    _bound(c, "applied", "n2")
+    c.refresh()
+    assert (c.mirror.epoch, c.mirror.node_epoch) == (epoch + 1, epoch)
+    assert c.mirror.row_epoch[[row, other]].tolist() == [epoch + 1] * 2
+    assert (c.mirror.row_node_epoch[[row, other]] <= epoch).all()
+    ports = _pods_side(c, "ports")
+    assert not ports[row] and not ports[other]
+    assert rows.value() - rows0 == 2
+    c.terms.node_selector_vector(selects)
+    assert rows.value() - rows0 == 2
+    c.check()
+
+
+def test_the_mirror_counts_a_write_by_the_side_it_changed():
+    c = Cluster(19, 100)
+    writes = c.mirror.row_writes
+    sides = lambda: (writes.value(side="node"), writes.value(side="usage"))
+    assert sides() == (0, 0)
+    c.refresh()
+    assert sides() == (100, 0)                  # rows newly taken
+    for _ in range(5):
+        c.add_pod()
+    touched = len({p.spec.node_name for p in c.pods.values()})
+    c.refresh()
+    assert sides() == (100, touched)            # binds
+    c.remove_pod()
+    c.refresh()
+    assert sides() == (100, touched + 1)        # a delete
+    c.cache.update_node(c.nodes["n3"], c.nodes["n3"])   # set_node
+    c.add_pod()
+    c.refresh()
+    assert sides()[0] == 101 and sides()[1] in (touched + 1, touched + 2)
+    c.cache.remove_node(c.nodes.pop("n4"))      # _remove_row
+    c.pods = {k: p for k, p in c.pods.items() if p.spec.node_name != "n4"}
+    c.refresh()
+    assert sides()[0] == 102
+    # a clone carries its set_node's name, a second set_node gets a new one
+    ni = c.snapshot.node_infos["n3"]
+    assert ni.clone().node_generation == ni.node_generation > 0
+    gen = ni.node_generation
+    ni.set_node(ni.node)
+    assert ni.node_generation > gen
+
+
+def test_a_mask_row_is_the_and_of_its_pods_term_vectors():
+    """A row of unique_masks is what its pod's own term vectors (`tol`,
+    `sel`, `ports`, hostname) and the caller's extra row AND to, built
+    from the long-lived compiler after binds it did not have to see."""
+    from kubernetes_tpu.scheduler.tensorize import PodBatchTensors
+    c = _sided()
+    _bound(c, "holds-8080", "n3")
+    c.refresh()
+    pods = list(c.probes) + [make_pod("pinned", node_name="n5"),
+                             make_pod("pinned-selects", node_name="n0",
+                                      node_selector={"disk": "ssd"})]
+    rng = np.random.RandomState(5)
+    extra = rng.random_sample((len(pods), c.mirror.t.capacity)) > 0.3
+    extra[::2] = True                          # every other pod: no row
+    for extra_mask in (None, extra):
+        batch = PodBatchTensors(pods, c.mirror, c.terms,
+                                extra_mask=extra_mask)
+        fresh = TermCompiler(c.mirror)
+        assert batch.n_unique_masks >= 7
+        for i, pod in enumerate(pods):
+            want = fresh.tolerations_vector(pod) & \
+                fresh.node_selector_vector(pod)
+            for vec in (fresh.host_ports_vector(pod),
+                        fresh.hostname_vector(pod),
+                        None if extra_mask is None else extra_mask[i]):
+                if vec is not None:
+                    want = want & vec
+            assert np.array_equal(batch.unique_masks[batch.mask_idx[i]],
+                                  want), pod.metadata.name
+        assert not batch.unique_masks[batch.n_unique_masks:].any()
+    row = c.mirror.row_of["n3"]
+    assert not batch.unique_masks[batch.mask_idx[6], row]    # port-8080
+
+
 # --------------------------------------------------------- engagement
 
 
@@ -470,7 +803,10 @@ def test_the_series_are_declared_at_zero_on_a_fresh_registry():
     assert scrape[ROWS] == 0
     for cache in ("terms", "scores", "zones"):
         assert scrape[f'{REBUILDS}{{cache="{cache}"}}'] == 0
+    for side in ("node", "usage"):
+        assert scrape[f'{WRITES}{{side="{side}"}}'] == 0
     mirror = sched.algorithm.mirror
+    assert mirror.row_writes is sched.metrics.mirror_row_writes
     assert mirror.vector_rows_recomputed is \
         sched.metrics.node_vector_rows_recomputed
     assert mirror.vector_rebuilds is sched.metrics.node_vector_rebuilds
@@ -496,9 +832,13 @@ def test_a_cycle_after_a_bind_only_cycle_walks_no_cluster():
         dirtied = int((mirror.row_epoch == mirror.epoch).sum())
         assert 1 <= dirtied <= 20
         assert total() == rebuilds0
-        # tol, sel and the zone ids: vectors used + 1
-        assert 0 < rows.value() - rows0 <= dirtied * 3
-    # a zone relabel: one rescan of the zones, the term vectors patched
+        # tol, sel, the zone ids and the flags read the node alone, and
+        # a bind changes no node
+        assert rows.value() - rows0 == 0
+        writes = sched.metrics.mirror_row_writes
+        assert writes.value(side="node") == 200
+        assert writes.value(side="usage") >= dirtied
+    # a zone relabel: one rescan of the zones, one row a term vector
     rows0 = rows.value()
     new = copy.deepcopy(nodes["n7"])
     new.metadata.labels[ZONE] = "z9"
@@ -506,7 +846,8 @@ def test_a_cycle_after_a_bind_only_cycle_walks_no_cluster():
     _cycle(client, sched, [f"d{i}" for i in range(20)])
     assert (rebuilds.value(cache="terms"), rebuilds.value(cache="zones"),
             rebuilds.value(cache="scores")) == (2, 2, 0)
-    assert 200 < rows.value() - rows0 <= 200 + 2 * 21
+    assert rows.value() - rows0 == 200 + 2
+    assert sched.metrics.mirror_row_writes.value(side="node") == 201
     # and what the benchmark's metric divides: cycles
     scrape = parse_metrics(sched.metrics.registry.expose())
     assert scrape[ROWS] == rows.value()

@@ -400,6 +400,48 @@ def test_a_batch_of_240_pods_has_120_mask_rows_and_120_classes():
     assert said["pods_outside_their_zones"] == 0
 
 
+def test_bind_only_cycles_of_120_selectors_recompute_no_row():
+    """The cell's shape, served: 120 selectors alive in every pop, and
+    after the first cycle nothing but binds. The 121 cached term vectors
+    (and the zones and flags) read the node alone, so the three cycles
+    that follow binds recompute not one row (PR 36), while each still
+    stacks its 120 mask rows."""
+    config = dict(CONFIG, nodes=160)
+    nodes = cluster.make_nodes(config, 160, 4)
+    pods = cluster.PodStream(config, 4).take(960)
+    series = ("scheduler_node_vector_rows_recomputed_total",
+              "scheduler_static_mask_rows_total",
+              'scheduler_mirror_row_writes_total{side="node"}',
+              'scheduler_mirror_row_writes_total{side="usage"}')
+    cycles = []
+
+    def watch(sched):
+        cycle = sched.schedule_pending
+
+        def counted(*a, **kw):
+            results = cycle(*a, **kw)
+            scrape = parse_metrics(sched.metrics.registry.expose())
+            rebuilds = sum(sched.metrics.node_vector_rebuilds
+                           .snapshot().values())
+            cycles.append([scrape[name] for name in series] + [rebuilds])
+            return results
+        sched.schedule_pending = counted
+
+    listed, scrape = run_program(nodes, pods, [240] * 4, prepare=watch)
+    assert len(cycles) == 4
+    first = cycles[0]
+    # the first cycle walks: 120 `sel`, one `tol`, the zones, 160 rows each
+    assert first == [122 * 160, 120, 160, 0, 122]
+    for before, after in zip(cycles, cycles[1:]):
+        rows, masks, node_writes, usage_writes, rebuilds = \
+            (b - a for a, b in zip(before, after))
+        assert (rows, masks, node_writes, rebuilds) == (0, 120, 0, 0)
+        assert 1 <= usage_writes <= 160
+    compared, said = judged(nodes, pods, listed, scrape)
+    assert verdict.correct(compared), said
+    assert said["pods_outside_their_zones"] == 0
+
+
 # ------------------------------------ (e) the series and the data files
 
 NEW_METRICS = ("sched_static_masks_ms_per_pod",
