@@ -511,15 +511,18 @@ def kernel_parity(seed):
           f"score arithmetic differs from the reference on a 3900m/31Gi "
           f"node: LeastRequested {int((lr != lr_ref).sum())}, "
           f"BalancedAllocation {int((ba != ba_ref).sum())} of {len(RC)}")
-    # SelectorSpread's count inversion, for max counts whose reciprocal
+    # SelectorSpread's count inversion against upstream's float64
+    # int(10 * ((maxc - c) / maxc)), for max counts whose reciprocal
     # rounds down in f32 (25, 49, 110) as well as up (3)
+    tab = kb.spread_round_table(128)
     for maxc in (3, 25, 49, 110):
         cnt = np.arange(maxc + 1, dtype=np.float32)
         n = len(cnt)
         got = np.asarray(jax.jit(kb._spread_score)(
             cnt, np.ones(n, bool), np.zeros(n, np.int32),
-            np.zeros(1, np.float32), np.zeros((1, n), np.float32)))
-        check((got == [int(10 * (maxc - c) / maxc) for c in range(n)]).all(),
+            np.zeros(1, np.float32), np.zeros((1, n), np.float32), tab))
+        check((got == [int(10.0 * (float(maxc - c) / float(maxc)))
+                       for c in range(n)]).all(),
               f"spread scores differ from the reference at max count {maxc}")
     out["scores"] = (f"LeastRequested/BalancedAllocation over {len(RC)} "
                      f"request levels of a 3900m/31Gi node, spread "

@@ -44,6 +44,7 @@ from ..observability.tracer import NULL_TRACER
 from .cache import Cache, Snapshot
 from .nodeinfo import NodeInfo, pod_has_affinity_constraints
 from . import predicates as preds
+from . import priorities as prios
 from . import sharding as sharding_mod
 from .tensorize import PodBatchTensors, TensorMirror, TermCompiler
 from .topology import AffinityProfile, BatchOverlay, TopologyIndex
@@ -399,15 +400,19 @@ class BatchScheduler:
                 self.topology.has_score_carriers())
 
     def _topology_apply(self, dirty) -> None:
-        """TopologyIndex.apply under its own part of `refresh`. An index
+        """TopologyIndex.apply, and SelectorSpread's SpreadIndex.apply
+        beside it, under their own part of `refresh`. A topology index
         that no (anti-)affinity carrier or term has switched on yet only
         scans the dirty nodes for one; that, and the one pass over the
-        whole snapshot that switches it on, stay in `refresh`."""
-        if not self.topology.active:
+        whole snapshot that switches it on, stay in `refresh`. A spread
+        index that no spread group has switched on does nothing."""
+        spread = self.scorer.spread_index
+        if not (self.topology.active or spread.active):
             self.topology.apply(self.snapshot, dirty)
             return
         with self._stage("topology_apply", nodes=len(dirty)):
             self.topology.apply(self.snapshot, dirty)
+            spread.apply(self.snapshot, dirty)
 
     # ------------------------------------------------------- residual host path
 
@@ -817,11 +822,16 @@ class BatchScheduler:
         soft term union fits the in-scan credit tables
         (_assign_soft_terms), the kernel re-scores per pod itself and the
         whole batch launches at once; only an overflowing union still
-        schedules in SOFT_SCORE_CHUNK sub-batches. Spread beyond the
-        in-scan group cap chunks as before."""
+        schedules in SOFT_SCORE_CHUNK sub-batches. SelectorSpread never
+        chunks: every spread group of the batch rides the scan's carry
+        (_assign_spread_groups), and a pop that holds more than
+        SPREAD_GROUP_CAP of them is cut before the first pod of the group
+        past it (counted, reason spread_groups), which the next launch
+        then scores from the counts this one leaves."""
         chunk = self.SOFT_SCORE_CHUNK
-        if len(pods) <= chunk or chunk <= 0:
-            return len(pods)
+        cut = self._spread_group_cut(pods)
+        if cut <= chunk or chunk <= 0:
+            return cut
         if self.scorer.weights.get("InterPodAffinityPriority"):
             has_pref = any(
                 p.spec.affinity is not None and (
@@ -845,108 +855,183 @@ class BatchScheduler:
                         if any(pod_group_key(p) is not None for p in pods):
                             self._count_inscan_fallback("soft_gang")
                     return chunk
-        # spread carriers beyond the in-scan group cap would otherwise run
-        # the whole batch on frozen counts — chunk so they refresh
-        listers = self.scorer.listers
-        if listers is not None and \
-                self.scorer.weights.get("SelectorSpreadPriority"):
-            memo: Dict[Tuple, bool] = {}
-            n_groups = 0
-            for pod in pods:
-                key = (pod.metadata.namespace,
-                       tuple(sorted(pod.metadata.labels.items())))
-                v = memo.get(key)
-                if v is None:
-                    v = bool(listers.selectors_for_pod(pod))
-                    memo[key] = v
-                    if v:
-                        n_groups += 1
-                        if n_groups > self.SPREAD_GROUP_CAP:
-                            return chunk
-        return len(pods)
+        return cut
 
-    #: in-scan spread group cap per batch; overflow groups fall back to
-    #: the static (batch-start) spread row
-    SPREAD_GROUP_CAP = 7
+    #: spread groups one launch carries: the rows of the scan's [G, N]
+    #: count carry (G bucketed; 128 MiB of device memory at 8,192 node
+    #: rows). A pop holds about one group a Service it touches, some 110
+    #: in a rollout of thousands of Services, so this follows the queue
+    #: as TOPO_TERM_CAP does; a pop past it is cut there, never scored
+    #: from a frozen row (_spread_group_cut)
+    SPREAD_GROUP_CAP = 4096
+
+    def _spread_group_cut(self, pods: List[Pod]) -> int:
+        """The index of the first pod of spread group number
+        SPREAD_GROUP_CAP + 1, or len(pods): a launch carries the groups
+        before it exactly, and the pods from there on are the next
+        launch's. A pop of no more pods than the cap cannot hold more
+        groups, so the served path (pops of about a thousand) does not
+        look."""
+        listers = self.scorer.listers
+        if len(pods) <= self.SPREAD_GROUP_CAP or listers is None or \
+                not self.scorer.weights.get("SelectorSpreadPriority"):
+            return len(pods)
+        seen: set = set()
+        groups: set = set()
+        for i, pod in enumerate(pods):
+            key = (pod.metadata.namespace,
+                   tuple(sorted(pod.metadata.labels.items())))
+            if key in seen:
+                continue
+            seen.add(key)
+            sels = listers.selectors_for_pod(pod)
+            if sels:
+                gkey = self._spread_group_key(key, sels)
+                if gkey not in groups and \
+                        len(groups) == self.SPREAD_GROUP_CAP:
+                    self._count_inscan_fallback("spread_groups")
+                    return i
+                groups.add(gkey)
+        return len(pods)
 
     def _assign_spread_groups(self, pods: List[Pod],
                               batch: PodBatchTensors) -> Optional[Tuple]:
-        """Group pods by (namespace, labels) whose selectors make them
-        spread carriers; install per-group base counts + zone ids so the
-        kernel scores SelectorSpread from RUNNING counts (the serial
+        """Group the pods that a Service or controller selects by
+        (namespace, the set of selectors that match them): pods of one
+        group read one count (the pods that match every selector of the
+        set, selector_spreading.go countMatchingPods), whatever else
+        their labels say. Install per-group base counts + zone ids so
+        the kernel scores SelectorSpread from RUNNING counts (the serial
         semantics — selector_spreading.go:277 re-counts per pod).
 
-        Returns the batch's spread chain SIGNATURE (ordered group
-        template keys + everything the carried [G, N] counts' meaning
-        depends on), or None when no spread tables ride. Two batches
-        with equal signatures name group g identically, so a chained
-        launch may seed its count carry from the predecessor's finals."""
+        Every group of the batch gets a slot: one algorithm for 1 group
+        and for 200. A group's base counts are read off
+        scorer.SpreadIndex, which follows the binds (O(the group's bound
+        pods); no walk over the nodes once the index is on), and cross to
+        the device as (group, row, count) triplets. Past SPREAD_GROUP_CAP
+        the caller has cut the pop (_spread_group_cut); a caller that did
+        not leaves the groups past it to the batch-start row of
+        scorer.static_scores, counted as spread_groups.
+
+        Returns the batch's spread chain SIGNATURE (ordered group keys +
+        everything the carried [G, N] counts' meaning depends on), or
+        None when no spread tables ride. Two batches with equal
+        signatures name group g identically, so a chained launch may
+        seed its count carry from the predecessor's finals."""
         listers = self.scorer.listers
         weight = self.scorer.weights.get("SelectorSpreadPriority", 0)
         if listers is None or not weight:
             return None
-        from . import priorities as prios
-        self.scorer._refresh_epoch()
-        base_rows: List[np.ndarray] = []
-        group_sel: List[Tuple[str, list]] = []   # (namespace, selectors)
-        group_keys: List[Tuple] = []             # (ns, labels) per group
-        memo: Dict[Tuple, Optional[int]] = {}
-        for i, pod in enumerate(pods):
+        with self._stage("spread_groups", pods=len(pods)):
+            return self._spread_groups(pods, batch, listers, float(weight))
+
+    @staticmethod
+    def _spread_group_key(labels_key: Tuple, sels: list) -> Tuple:
+        """(namespace, the selectors' keys, sorted). A selector that does
+        not name itself (a lister of the caller's own) leaves the label
+        set to name the group."""
+        keys = [getattr(sel, "key", None) for sel in sels]
+        if any(k is None for k in keys):
+            return (labels_key[0], (("labels", labels_key[1]),))
+        return (labels_key[0], tuple(sorted(set(keys))))
+
+    def _spread_groups(self, pods: List[Pod], batch: PodBatchTensors,
+                       listers, weight: float) -> Optional[Tuple]:
+        #: (ns, labels) -> its group's key, or None where nothing selects it
+        group_of: Dict[Tuple, Optional[Tuple]] = {}
+        #: group key -> its selectors
+        sel_of: Dict[Tuple, list] = {}
+        keys: List[Optional[Tuple]] = []
+        overflow = False
+        for pod in pods:
             key = (pod.metadata.namespace,
                    tuple(sorted(pod.metadata.labels.items())))
-            g = memo.get(key, -2)
-            if g == -2:
-                g = None
-                meta = prios.PriorityMetadata(pod, listers)
-                if meta.pod_selectors and \
-                        len(base_rows) < self.SPREAD_GROUP_CAP:
-                    counts = self.scorer._spread_counts(pod, meta)
-                    if counts is not None:
-                        g = len(base_rows)
-                        base_rows.append(np.asarray(counts, np.float32))
-                        group_sel.append((pod.metadata.namespace,
-                                          meta.pod_selectors))
-                        group_keys.append(key)
-                memo[key] = g
-            if g is not None:
-                batch.spread_gidx[i] = g
-        if not base_rows:
-            return None
+            if key not in group_of:
+                sels = listers.selectors_for_pod(pod)
+                gkey = self._spread_group_key(key, sels) if sels else None
+                if gkey is not None and gkey not in sel_of:
+                    if len(sel_of) >= self.SPREAD_GROUP_CAP:
+                        gkey, overflow = None, True
+                    else:
+                        sel_of[gkey] = sels
+                group_of[key] = gkey
+            keys.append(key)
+        if overflow:
+            self._count_inscan_fallback("spread_groups")
         # canonical group order: slot g is sorted-template-key order, not
         # first-pod order — batches popping the same templates in a
         # rotated pod order land on the SAME signature, so the chained
-        # count carry stays consumable. Pure renumbering: every per-group
-        # structure below permutes consistently, decisions are invariant
-        order = sorted(range(len(base_rows)), key=lambda g: group_keys[g])
-        remap = {old: new for new, old in enumerate(order)}
-        base_rows = [base_rows[g] for g in order]
-        group_sel = [group_sel[g] for g in order]
-        group_keys = [group_keys[g] for g in order]
+        # count carry stays consumable. Pure renumbering: decisions are
+        # invariant
+        group_keys = sorted(sel_of)
+        if not group_keys:
+            return None
+        slot = {k: g for g, k in enumerate(group_keys)}
+        scorer = self.scorer
+        scorer._refresh_epoch()
+        index = scorer.spread_index
+        if not index.active:
+            index.activate(self.snapshot)
+        row_of = self.mirror.row_of
+        nz: List[Tuple[int, int, int]] = []
+        largest = 0
+        for g, key in enumerate(group_keys):
+            total = 0
+            for node, c in index.counts(key[0], sel_of[key]).items():
+                row = row_of.get(node)
+                if row is not None:
+                    nz.append((g, row, c))
+                    total += c
+            largest = max(largest, total)
+        # cross-group match lists: a winner must bump every group whose
+        # selectors match its labels, not only its own. A group is filed
+        # under an item its selectors require, so a label set meets its
+        # candidates by its own items
+        by_item: Dict[Tuple, List[int]] = {}
+        apart: List[int] = []
+        for g, key in enumerate(group_keys):
+            item = prios.required_item(sel_of[key])
+            if item is None:
+                apart.append(g)
+            else:
+                by_item.setdefault((key[0], item), []).append(g)
+        matched_of: Dict[Tuple, Tuple[int, ...]] = {}
+        matched: List[Tuple[int, ...]] = []
         gidx = batch.spread_gidx
-        for i in range(len(pods)):
-            if gidx[i] >= 0:
-                gidx[i] = remap[int(gidx[i])]
-        # cross-group match matrix: a winner must bump every group whose
-        # selectors match its labels, not only its own (ns, labels) group
-        G = len(base_rows)
-        match = np.zeros((len(pods), G), np.float32)
-        mmemo: Dict[Tuple, np.ndarray] = {}
         for i, pod in enumerate(pods):
-            key = (pod.metadata.namespace,
-                   tuple(sorted(pod.metadata.labels.items())))
-            row = mmemo.get(key)
-            if row is None:
-                row = np.zeros((G,), np.float32)
-                for g, (ns, sels) in enumerate(group_sel):
-                    if ns == pod.metadata.namespace and \
-                            all(sel(pod.metadata.labels) for sel in sels):
-                        row[g] = 1.0
-                mmemo[key] = row
-            match[i] = row
-        batch.set_spread(np.stack(base_rows), self.scorer._zone_ids,
-                         self.scorer._n_zones, float(weight), match=match)
-        return (tuple(group_keys), self.scorer._n_zones, float(weight),
-                self.mirror.epoch, self.scorer.spread_sel_gen,
+            key = keys[i]
+            m = matched_of.get(key)
+            if m is None:
+                lbls = pod.metadata.labels
+                cands = apart + [g for item in key[1]
+                                 for g in by_item.get((key[0], item), ())]
+                m = matched_of[key] = tuple(sorted(
+                    g for g in set(cands)
+                    if group_keys[g][0] == key[0]
+                    and all(sel(lbls) for sel in sel_of[group_keys[g]])))
+            matched.append(m)
+            if group_of[key] is not None:
+                gidx[i] = slot[group_of[key]]
+        table = scorer.spread_round_table()
+        # _spread_exact's int32 holds 6 * maxN * maxZ: a node's count is
+        # under the table's side, a zone's under the group's bound pods
+        # plus this batch
+        if 6 * (table.shape[0] - 1) * (largest + len(pods)) >= 2 ** 31:
+            self._count_inscan_fallback("spread_range")
+        elif not overflow:
+            self._end_inscan_streak("spread_groups", "spread_range")
+        batch.set_spread(len(group_keys),
+                         np.asarray(nz, np.int32).reshape(-1, 3).T,
+                         matched, scorer.zone_ids_device(), scorer._n_zones,
+                         weight, table)
+        if self.sched_metrics is not None:
+            self.sched_metrics.spread_groups.inc(len(group_keys))
+            walked = index.rows_walked
+            if walked:
+                index.rows_walked = 0
+                self.sched_metrics.spread_rows_walked.inc(walked)
+        return (tuple(group_keys), scorer._n_zones, weight,
+                self.mirror.epoch, scorer.spread_sel_gen,
                 self.mirror.t.capacity)
 
     #: in-scan topology term cap per batch; bigger batches fall back to
@@ -957,8 +1042,9 @@ class BatchScheduler:
 
     def _count_inscan_fallback(self, reason: str) -> None:
         """No silent caps: every in-scan fallback (kmax/term-cap overflow,
-        soft term-union overflow) is counted by reason and logged once per
-        streak."""
+        soft term-union overflow, a pop cut at the spread group cap or a
+        spread group past what the score's int32 holds) is counted by
+        reason and logged once per streak."""
         if self.sched_metrics is not None:
             self.sched_metrics.topo_inscan_fallbacks.inc(reason=reason)
         self._batch_fell_back = True
@@ -1831,7 +1917,7 @@ class BatchScheduler:
                 chain.soft_sig != soft_sig or "soft_cnt" not in nu):
             return False
         if spread_sig is not None:
-            batch.spread_base = chain.batch.spread_base
+            batch.spread_nz = chain.batch.spread_nz
             batch.spread_zone = chain.batch.spread_zone
             batch.spread_zinit = chain.batch.spread_zinit
         if soft_sig is not None:

@@ -52,7 +52,7 @@ intent (:286-296); parity fixtures compare score classes, not tie order.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import Tuple
 
 import jax
@@ -180,7 +180,8 @@ def _pod_score(node_cfg: dict, nz_used, pod: dict,
 ZONE_WEIGHTING = 2.0 / 3.0
 
 _BATCH_INVARIANT = ("unique_masks", "unique_scores", "resource_weights",
-                    "spread_base", "spread_zone", "spread_zinit",
+                    "spread_slots", "spread_nz", "spread_tab",
+                    "spread_zone", "spread_zinit",
                     "spread_weight", "anti_dom", "anti_cnt0",
                     "class_req", "class_nz", "class_blocked",
                     "class_mask_idx", "class_score_idx",
@@ -206,15 +207,108 @@ def _zone_sums(zoh: jnp.ndarray, cf: jnp.ndarray) -> jnp.ndarray:
     return jnp.matmul(zoh, cf, precision=lax.Precision.HIGHEST)
 
 
+#: bit of a spread_round_table entry that says the unblended score
+#: 10 * (a / maxN) lands under its whole number in float64
+_PLAIN_BIT = 11
+
+
+@lru_cache(maxsize=4)
+def spread_round_table(m: int):
+    """[m + 1, m + 1] int32, the host-made float64 part of _spread_exact.
+
+    Upstream computes SelectorSpread's reduce in float64
+    (selector_spreading.go CalculateSpreadPriorityReduce):
+
+        fScore    = 10 * (float64(maxN - n) / float64(maxN))
+        zoneScore = 10 * (float64(maxZ - z) / float64(maxZ))
+        int(fScore * (1 - 2.0/3.0) + 2.0/3.0 * zoneScore)
+
+    The chip has no float64. The exact rational value, floored in
+    integers, is upstream's answer wherever that value is no whole
+    number (the nearest whole number is 1 / (3 maxN maxZ) away, float64
+    errs by 1e-14); where it IS a whole number k, float64 lands on k or a
+    hair under it, and int() then gives k or k - 1. Which, is a function
+    of the two quotients alone, because IEEE division rounds a quotient
+    by its value and not by how it is written: p = (maxN - n) / maxN and
+    q = (maxZ - z) / maxZ, and given p and k, q = (3k - 10p) / 20 is
+    determined. So entry [maxN, a] (a = maxN - n) holds one bit for each
+    k in 0..10: float64 lands under k. Bit _PLAIN_BIT says the same of
+    the unblended int(10 * (a / maxN)) that a cluster without zone labels
+    gets. m is the largest count a node can hold (its pod limit)."""
+    import numpy as np
+    d = np.arange(m + 1, dtype=np.int64)[:, None]
+    a = np.arange(m + 1, dtype=np.int64)[None, :]
+    live = (d > 0) & (a <= d)
+    w2 = 2.0 / 3.0
+    w1 = 1.0 - w2
+    with np.errstate(divide="ignore", invalid="ignore"):
+        f = 10.0 * (a.astype(np.float64) / d.astype(np.float64))
+        bits = np.zeros((m + 1, m + 1), np.int64)
+        for k in range(11):
+            qn = 3 * k * d - 10 * a
+            qd = 20 * d
+            zone = 10.0 * (qn.astype(np.float64) / qd.astype(np.float64))
+            under = live & (qn >= 0) & (qn <= qd) & (f * w1 + w2 * zone < k)
+            bits |= under.astype(np.int64) << k
+        whole = live & ((10 * a) % np.maximum(d, 1) == 0)
+        plain = whole & (f < (10 * a) // np.maximum(d, 1))
+        bits |= plain.astype(np.int64) << _PLAIN_BIT
+    return bits.astype(np.int32)
+
+
+def _count_ge(x: jnp.ndarray, step: jnp.ndarray, upto: int) -> jnp.ndarray:
+    """floor(x / step) for 0 <= x <= upto * step, in int32 compares: the
+    count of k in 1..upto with x >= k * step (no divide: _floor_tenths)."""
+    return sum((x >= k * step).astype(jnp.int32) for k in range(1, upto + 1))
+
+
+def _spread_exact(n, max_n, z, max_z, zoned, have_zones, tab):
+    """Upstream's int() of its float64 blend (spread_round_table has the
+    expression) from integers: n [N] the group's count on the node, max_n
+    the largest over the fitting nodes, z [N] the count of the node's
+    zone, max_z the largest zone's, zoned [N] the node has a zone label,
+    have_zones some fitting node has. All counts are integer-valued;
+    max_n == 0 scores every node 10 (p = 1) and a zone without a label or
+    max_z == 0 keeps the zone score 10 (q = 1), as upstream initialises
+    them. The value is 10p/3 + 20q/3: its floor and whether it is whole
+    come from int32 compares (quotient and remainder of each term, then
+    the carry of the two remainders); a whole value takes the table's
+    bit. Exact while 6 * max_n * max_z < 2**31 (core counts a batch
+    that could pass it)."""
+    i32 = jnp.int32
+    m = tab.shape[0] - 1
+    mn = max_n.astype(i32)
+    d_n = jnp.maximum(mn, 1)
+    a = jnp.clip(jnp.where(mn > 0, mn - n.astype(i32), 1), 0, d_n)
+    mz = max_z.astype(i32)
+    d_z = jnp.maximum(mz, 1)
+    b = jnp.clip(jnp.where(zoned & (mz > 0), mz - z.astype(i32), d_z),
+                 0, d_z)
+    ten_a = 10 * a
+    q_a = _count_ge(ten_a, 3 * d_n, 3)
+    r_a = ten_a - 3 * d_n * q_a
+    q_b = _count_ge(20 * b, 3 * d_z, 6)
+    r_b = 20 * b - 3 * d_z * q_b
+    t = r_a * d_z + r_b * d_n
+    t3 = 3 * d_n * d_z
+    e = q_a + q_b + (t >= t3).astype(i32)
+    bits = tab[jnp.minimum(d_n, m), jnp.minimum(a, m)]
+    blended = e - jnp.where((t == 0) | (t == t3), (bits >> e) & 1, 0)
+    e0 = _count_ge(ten_a, d_n, 10)
+    plain = e0 - jnp.where(ten_a == e0 * d_n, (bits >> _PLAIN_BIT) & 1, 0)
+    return jnp.where(have_zones, blended, plain).astype(jnp.float32)
+
+
 def _spread_score(cnt_g: jnp.ndarray, fits: jnp.ndarray,
                   zone_of: jnp.ndarray, zinit: jnp.ndarray,
-                  zoh: jnp.ndarray) -> jnp.ndarray:
+                  zoh: jnp.ndarray, tab: jnp.ndarray) -> jnp.ndarray:
     """One pod's [N] SelectorSpread score from running group counts —
     the serial reduce (priorities.selector_spread_reduce /
     selector_spreading.go): invert node counts to 0-10 normalized over the
     FEASIBLE set, blend zone-level counts at weight 2/3; zone id 0 means
     'no zone label' (keeps the MaxPriority zone default, excluded from the
-    zone max). int() truncation == floor for these non-negatives."""
+    zone max). The arithmetic is upstream's float64 int(), exactly
+    (_spread_exact)."""
     cf = jnp.where(fits, cnt_g, 0.0)
     maxc = jnp.max(cf)
     zs = zinit + _zone_sums(zoh, cf)
@@ -225,19 +319,8 @@ def _spread_score(cnt_g: jnp.ndarray, fits: jnp.ndarray,
     # rejects (the pre-PR test_multichip XLA failures); the f32 form is
     # semantically identical and reduces everywhere
     have_zones = jnp.max(jnp.where(fits & (zone_of > 0), 1.0, 0.0)) > 0
-    node_s = jnp.where(maxc > 0,
-                       _div_exact(MAX_PRIORITY * (maxc - cnt_g),
-                                  jnp.maximum(maxc, 1.0)),
-                       MAX_PRIORITY)
-    zone_s = jnp.where((zone_of > 0) & (maxz > 0),
-                       _div_exact(MAX_PRIORITY * (maxz - zs[zone_of]),
-                                  jnp.maximum(maxz, 1.0)),
-                       MAX_PRIORITY)
-    blended = jnp.where(have_zones,
-                        node_s * (1.0 - ZONE_WEIGHTING)
-                        + ZONE_WEIGHTING * zone_s,
-                        node_s)
-    return jnp.floor(blended)
+    return _spread_exact(cnt_g, maxc, zs[zone_of], maxz, zone_of > 0,
+                         have_zones, tab)
 
 
 def _split_batch(pod_batch: dict):
@@ -250,17 +333,38 @@ def _split_batch(pod_batch: dict):
     return per_pod, pod_batch["unique_masks"], pod_batch["unique_scores"], rw
 
 
-def _spread_tables(pod_batch: dict, N: int):
-    """(base [G,N], zone_of [N], zinit [Z], weight scalar) with inert
-    defaults for batches without spread groups."""
-    base = pod_batch.get("spread_base")
-    if base is None:
+def _spread_tables(pod_batch: dict, N: int, offset=0):
+    """(base [G,N], zone_of [N], zinit [Z], weight scalar, round table)
+    with inert defaults for batches without spread groups. The host ships
+    what is non-zero of the base counts, spread_nz [3, S] int32 (group,
+    node row, count; padding names group G and is dropped), and the
+    device scatters it into zeros: spread_slots [G] is there for its
+    shape. `offset` is the first row of this shard's node slice."""
+    slots = pod_batch.get("spread_slots")
+    if slots is None:
         return (jnp.zeros((1, N), jnp.float32),
                 jnp.zeros((N,), jnp.int32),
                 jnp.zeros((1,), jnp.float32),
-                jnp.float32(0.0))
+                jnp.float32(0.0),
+                jnp.zeros((1, 1), jnp.int32))
+    g, row, cnt = pod_batch["spread_nz"]
+    row = row - offset
+    row = jnp.where((row >= 0) & (row < N), row, N)
+    base = jnp.zeros((slots.shape[0], N), jnp.float32).at[g, row].add(
+        cnt.astype(jnp.float32), mode="drop")
     return (base, pod_batch["spread_zone"], pod_batch["spread_zinit"],
-            pod_batch["spread_weight"])
+            pod_batch["spread_weight"], pod_batch["spread_tab"])
+
+
+def _spread_bump(spread, pod, col, ok_f, **scatter):
+    """The winner's count: +1 at its node for EVERY group whose selectors
+    match it (spread_mg [K], -1 padded: its own group and those that
+    overlap it), as the serial re-count would see it."""
+    mg = pod.get("spread_mg")
+    if mg is None:
+        return spread
+    return spread.at[jnp.maximum(mg, 0), col].add(
+        jnp.where(mg >= 0, ok_f, 0.0), **scatter)
 
 
 @jax.jit
@@ -271,7 +375,7 @@ def filter_score(node_cfg: dict, usage: dict, pod_batch: dict
     pod_batch = unpack_inputs(pod_batch)
     per_pod, unique_masks, unique_scores, rw = _split_batch(pod_batch)
     N = node_cfg["alloc"].shape[0]
-    spread_base, zone_of, zinit, spread_w = _spread_tables(pod_batch, N)
+    spread_base, zone_of, zinit, spread_w, tab = _spread_tables(pod_batch, N)
     zoh = _zone_onehot(zone_of, zinit)
 
     def one(pod):
@@ -283,7 +387,7 @@ def filter_score(node_cfg: dict, usage: dict, pod_batch: dict
         g = pod.get("spread_gidx", jnp.int32(-1))
         use_spread = jnp.where(g >= 0, 1.0, 0.0)
         score = score + spread_w * use_spread * _spread_score(
-            spread_base[jnp.maximum(g, 0)], fits, zone_of, zinit, zoh)
+            spread_base[jnp.maximum(g, 0)], fits, zone_of, zinit, zoh, tab)
         return fits, jnp.where(fits, score, NEG)
     return jax.vmap(one)(per_pod)
 
@@ -505,8 +609,8 @@ def _class_ctx(node_cfg: dict, usage: dict, pod_batch: dict, nom: dict):
     anti_dom = pod_batch.get("anti_dom")
     has_topo = anti_dom is not None
     has_dir2 = has_topo and "cmatch_tids" in pod_batch
-    has_spread = pod_batch.get("spread_base") is not None
-    spread_base, zone_of, zinit, spread_w = _spread_tables(pod_batch, N)
+    has_spread = pod_batch.get("spread_slots") is not None
+    spread_base, zone_of, zinit, spread_w, tab = _spread_tables(pod_batch, N)
     zoh = _zone_onehot(zone_of, zinit)
     soft = _soft_tables(pod_batch)
     has_soft = soft is not None
@@ -520,7 +624,7 @@ def _class_ctx(node_cfg: dict, usage: dict, pod_batch: dict, nom: dict):
            "anti_dom": anti_dom, "has_topo": has_topo,
            "has_dir2": has_dir2, "has_spread": has_spread,
            "spread_w": spread_w, "zone_of": zone_of, "zinit": zinit,
-           "zoh": zoh, "soft": soft, "has_soft": has_soft,
+           "zoh": zoh, "spread_tab": tab, "soft": soft, "has_soft": has_soft,
            "has_nom": has_nom, "nom": nom}
     carry0 = {"used": usage["used"], "nz_used": usage["nonzero_used"],
               "pod_count": usage["pod_count"], "ms": ms0}
@@ -581,7 +685,7 @@ def _class_pod_step(ctx, carry, pod):
         use_spread = jnp.where(g >= 0, 1.0, 0.0)
         score = score + ctx["spread_w"] * use_spread * _spread_score(
             carry["spread"][jnp.maximum(g, 0)], fits, ctx["zone_of"],
-            ctx["zinit"], ctx["zoh"])
+            ctx["zinit"], ctx["zoh"], ctx["spread_tab"])
     masked = jnp.where(fits, score, NEG)
     best = jnp.argmax(_tie_penalized(masked, rows, pod["seq"])) \
         .astype(jnp.int32)
@@ -603,10 +707,7 @@ def _class_pod_step(ctx, carry, pod):
     out = {"used": used, "nz_used": nz_used, "pod_count": pod_count,
            "ms": carry["ms"].at[:, best].set(col)}
     if ctx["has_spread"]:
-        sm = pod.get("spread_match")
-        if sm is None:
-            sm = jnp.zeros((carry["spread"].shape[0],), jnp.float32)
-        out["spread"] = carry["spread"].at[:, best].add(sm * ok_f)
+        out["spread"] = _spread_bump(carry["spread"], pod, best, ok_f)
     if ctx["has_topo"]:
         out.update(_topo_scatter(ctx["anti_dom"], carry, pod, best, ok,
                                  ctx["has_dir2"]))
@@ -646,7 +747,7 @@ def _schedule_batch_classes(node_cfg: dict, usage: dict, pod_batch: dict,
     decisions). Every non-gang batch shape rides here now:
 
       - spread groups: per-group running counts in the carry, the
-        winner's spread_match row bumping every matching group —
+        winner bumping every matching group (_spread_bump) —
         identical to the classic kernel's in-scan spread.
       - soft inter-pod credits: the per-(term, domain) channel
         accumulators in the carry, read/written per pod.
@@ -718,7 +819,7 @@ def schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
         return _schedule_batch_classes(node_cfg, usage, pod_batch, nom)
     per_pod, unique_masks, unique_scores, rw = _split_batch(pod_batch)
     N = node_cfg["alloc"].shape[0]
-    spread_base, zone_of, zinit, spread_w = _spread_tables(pod_batch, N)
+    spread_base, zone_of, zinit, spread_w, tab = _spread_tables(pod_batch, N)
     zoh = _zone_onehot(zone_of, zinit)
     soft = _soft_tables(pod_batch)
     has_soft = soft is not None
@@ -773,26 +874,22 @@ def schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
         gi = jnp.maximum(g, 0)
         use_spread = jnp.where(g >= 0, 1.0, 0.0)
         score = score + spread_w * use_spread * _spread_score(
-            carry["spread"][gi], fits, zone_of, zinit, zoh)
+            carry["spread"][gi], fits, zone_of, zinit, zoh, tab)
         masked = jnp.where(fits, score, NEG)
         best = jnp.argmax(_tie_penalized(masked, rows, pod["seq"])) \
             .astype(jnp.int32)
         ok = fits[best] & pod["active"]
         onehot = (rows == best) & ok
         oh_f = onehot.astype(jnp.float32)
-        # a winner bumps EVERY spread group whose selectors match it (its
-        # spread_match row), not only its own — overlapping groups see
-        # each other's in-batch placements like the serial re-count does
-        sm = pod.get("spread_match")
-        if sm is None:
-            sm = jnp.zeros((carry["spread"].shape[0],), jnp.float32)
         ok_f = jnp.where(ok, 1.0, 0.0)
         out = {
             "used": carry["used"] + oh_f[:, None] * pod["req"][None, :],
             "nz_used": carry["nz_used"]
             + oh_f[:, None] * pod["nonzero_req"][None, :],
             "pod_count": carry["pod_count"] + oh_f,
-            "spread": carry["spread"].at[:, best].add(sm * ok_f),
+            # a winner bumps EVERY spread group whose selectors match
+            # it, not only its own (_spread_bump)
+            "spread": _spread_bump(carry["spread"], pod, best, ok_f),
         }
         if has_topo:
             out.update(_topo_scatter(anti_dom, carry, pod, best, ok,
@@ -843,7 +940,7 @@ def schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
     new_usage = {"used": final["used"],
                  "nonzero_used": final["nz_used"],
                  "pod_count": final["pod_count"]}
-    if pod_batch.get("spread_base") is not None:
+    if pod_batch.get("spread_slots") is not None:
         new_usage["spread"] = final["spread"]
     if has_soft:
         new_usage["soft_cnt"] = final["soft_cnt"]
@@ -879,7 +976,7 @@ def schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
 # the shard_map kernel as carried/overlaid state:
 #
 #   spread — group counts replicate? No: the [G, N] count rows shard on
-#     the node axis like spread_base; the per-step normalization needs
+#     the node axis (the scattered base counts); the per-step normalization needs
 #     the GLOBAL max count and zone sums, which are one pmax + one psum
 #     of integer-valued f32 (exact in any order, so bit-identical).
 #   soft — the [Ts, Ds] channel accumulators replicate; the winner's
@@ -893,7 +990,7 @@ def schedule_batch(node_cfg: dict, usage: dict, pod_batch: dict,
 _INT32_MAX = jnp.int32(2147483647)
 
 
-def _spread_score_sharded(cnt_g, fits, zone_of, zinit, zoh):
+def _spread_score_sharded(cnt_g, fits, zone_of, zinit, zoh, tab):
     """_spread_score under shard_map: cnt_g/fits/zone_of/zoh are the
     LOCAL node slice; the max count, zone sums, and zone presence reduce
     across shards. All reduced values are integer-valued f32 (counts),
@@ -907,19 +1004,8 @@ def _spread_score_sharded(cnt_g, fits, zone_of, zinit, zoh):
     maxz = jnp.max(jnp.where(z_idx > 0, zs, 0.0))
     have_zones = lax.pmax(
         jnp.max(jnp.where(fits & (zone_of > 0), 1.0, 0.0)), NODE_AXIS) > 0
-    node_s = jnp.where(maxc > 0,
-                       _div_exact(MAX_PRIORITY * (maxc - cnt_g),
-                                  jnp.maximum(maxc, 1.0)),
-                       MAX_PRIORITY)
-    zone_s = jnp.where((zone_of > 0) & (maxz > 0),
-                       _div_exact(MAX_PRIORITY * (maxz - zs[zone_of]),
-                                  jnp.maximum(maxz, 1.0)),
-                       MAX_PRIORITY)
-    blended = jnp.where(have_zones,
-                        node_s * (1.0 - ZONE_WEIGHTING)
-                        + ZONE_WEIGHTING * zone_s,
-                        node_s)
-    return jnp.floor(blended)
+    return _spread_exact(cnt_g, maxc, zs[zone_of], maxz, zone_of > 0,
+                         have_zones, tab)
 
 
 def _soft_score_sharded(raw, fits, weight):
@@ -948,8 +1034,9 @@ def _sharded_class_scan(node_cfg: dict, usage: dict, pod_batch: dict,
     anti_dom = pod_batch.get("anti_dom")
     has_topo = anti_dom is not None
     has_dir2 = has_topo and "cmatch_tids" in pod_batch
-    has_spread = pod_batch.get("spread_base") is not None
-    spread_base, zone_of, zinit, spread_w = _spread_tables(pod_batch, Nl)
+    has_spread = pod_batch.get("spread_slots") is not None
+    spread_base, zone_of, zinit, spread_w, tab = _spread_tables(
+        pod_batch, Nl, offset)
     zoh = _zone_onehot(zone_of, zinit)
     soft = _soft_tables(pod_batch)
     has_soft = soft is not None
@@ -992,7 +1079,7 @@ def _sharded_class_scan(node_cfg: dict, usage: dict, pod_batch: dict,
             use_spread = jnp.where(g >= 0, 1.0, 0.0)
             score = score + spread_w * use_spread * _spread_score_sharded(
                 carry["spread"][jnp.maximum(g, 0)], fits, zone_of, zinit,
-                zoh)
+                zoh, tab)
         masked = jnp.where(fits, score, NEG)
         # tie-break hash on the GLOBAL row id — identical inputs to the
         # single-device kernel's (row, seq) penalty
@@ -1028,11 +1115,8 @@ def _sharded_class_scan(node_cfg: dict, usage: dict, pod_batch: dict,
         out = {"used": used, "nz_used": nz_used, "pod_count": pod_count,
                "ms": carry["ms"].at[:, lb_w].set(col, mode="drop")}
         if has_spread:
-            sm = pod.get("spread_match")
-            if sm is None:
-                sm = jnp.zeros((carry["spread"].shape[0],), jnp.float32)
-            out["spread"] = carry["spread"].at[:, lb_w].add(sm * ok_f,
-                                                            mode="drop")
+            out["spread"] = _spread_bump(carry["spread"], pod, lb_w, ok_f,
+                                         mode="drop")
         if has_topo:
             out.update(_topo_scatter_sharded(anti_dom, carry, pod, lbc,
                                              owner, ok, has_dir2))
@@ -1134,7 +1218,7 @@ def schedule_batch_sharded(mesh, node_cfg: dict, usage: dict,
     usage_out = {"used": P(NODE_AXIS, None),
                  "nonzero_used": P(NODE_AXIS, None),
                  "pod_count": P(NODE_AXIS)}
-    if "spread_base" in pod_batch:
+    if "spread_slots" in pod_batch:
         usage_out["spread"] = P(None, NODE_AXIS)
     if "soft_dom" in pod_batch:
         usage_out["soft_cnt"] = P()   # replicated accumulators
@@ -1237,7 +1321,7 @@ def pack_inputs(put, arrays: dict) -> PackedInputs:
       - already on the device (a jax.Array: the epoch-cached anti_dom
         table, chained state): passed through;
       - placed on the node axis by a rule of sharding.spec_for
-        (unique_masks, unique_scores, spread_base, spread_zone, anti_dom,
+        (unique_masks, unique_scores, spread_zone, anti_dom,
         soft_dom, soft_base, dom_tab): its own transfer, as before — it
         is large ([U, N]) and sharded under a mesh;
       - replicated, float32 / int32 / bool and at most PACK_MAX_BYTES:

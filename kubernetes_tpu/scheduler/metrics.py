@@ -30,7 +30,8 @@ STAGE_LEAVES = ("pop_wait", "refresh", "tensorize", "dispatch",
                 "scan_wait", "repair", "assume", "bind_backlog")
 #: what the inter-pod (anti-)affinity machinery takes of a leaf, each
 #: nested in the leaf named: topology_apply in refresh (TopologyIndex.
-#: apply, the (term, domain) counts kept as binds land), affinity_masks
+#: apply, the (term, domain) counts kept as binds land, and beside it
+#: scorer.SpreadIndex.apply, SelectorSpread's counts), affinity_masks
 #: in tensorize (the second pass of core._residual_mask: template
 #: profiles, TopologyIndex.required_masks, the rows laid on the pods),
 #: affinity_scores in dispatch (scorer.static_scores while the cluster
@@ -40,12 +41,18 @@ STAGE_LEAVES = ("pop_wait", "refresh", "tensorize", "dispatch",
 #: PodBatchTensors' term vectors of the batch's distinct constraint keys
 #: (tolerations, node selector and required node affinity, host ports,
 #: hostname; cached vectors catching up by row) and their stack into
-#: unique_masks.
+#: unique_masks. spread_groups, in tensorize too, is
+#: core._assign_spread_groups: entered by every singleton batch while
+#: SelectorSpreadPriority is weighted and the scheduler has listers
+#: (the served scheduler always), it finds each pod's selectors, gives
+#: every selected label set of the batch a slot and reads its base
+#: counts off the index.
 STAGE_PARTS = ("topology_apply", "affinity_masks", "affinity_scores",
-               "static_masks")
+               "static_masks", "spread_groups")
 #: every reason of scheduler_topo_inscan_fallbacks_total
 INSCAN_FALLBACK_REASONS = ("term_cap", "kmax", "soft_terms", "soft_kmax",
-                           "soft_gang", "aff_growth")
+                           "soft_gang", "aff_growth", "spread_groups",
+                           "spread_range")
 
 
 #: every `cache` of scheduler_node_vector_rebuilds_total
@@ -134,11 +141,16 @@ class SchedulerMetrics:
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048,
                      4096))
         # in-scan (anti-)affinity fallbacks, by reason {term_cap, kmax,
-        # soft_terms, soft_kmax, soft_gang, aff_growth}: batches the
-        # kernel tables could not cover take the repair-overlay /
-        # sub-chunked path instead (aff_growth: cut short before a pod
-        # whose required affinity an earlier pod of the batch can widen)
-        # — a capped code path must be visible, never silent
+        # soft_terms, soft_kmax, soft_gang, aff_growth, spread_groups,
+        # spread_range}: batches the kernel tables could not cover take
+        # the repair-overlay / sub-chunked path instead (aff_growth: cut
+        # short before a pod whose required affinity an earlier pod of
+        # the batch can widen; spread_groups: cut short before the
+        # spread group past core.SPREAD_GROUP_CAP, or that group scored
+        # from a batch-start row where the caller did not cut;
+        # spread_range: a spread group whose counts could pass what the
+        # kernel's int32 score holds exactly) — a capped code path must
+        # be visible, never silent
         self.topo_inscan_fallbacks = r.counter(
             "scheduler_topo_inscan_fallbacks_total",
             "Batches that fell back from the in-scan topology/soft-credit "
@@ -181,6 +193,21 @@ class SchedulerMetrics:
             "Classes (distinct template and score-row pairs) of the "
             "batches' class scans, summed over batches")
         self.scan_classes.declare()
+        # SelectorSpread in the scan: the (namespace, label set) groups
+        # given a slot of the carried [G, N] counts, before bucketing,
+        # summed over batches (a pop of a rollout holds about one a
+        # Service it touches); and the node rows visited to make base
+        # counts: the pass that switches scorer.SpreadIndex on, and 0
+        # from then on, because the index follows the binds
+        self.spread_groups = r.counter(
+            "scheduler_spread_groups_total",
+            "Spread groups given a slot of the scan's carried counts, "
+            "summed over batches")
+        self.spread_groups.declare()
+        self.spread_rows_walked = r.counter(
+            "scheduler_spread_rows_walked_total",
+            "Node rows visited to make spread groups' base counts")
+        self.spread_rows_walked.declare()
         # which way a batch's affinity rows were computed: host numpy or
         # the device matmuls of kernels/affinity.py (the score rows have
         # the host route alone)

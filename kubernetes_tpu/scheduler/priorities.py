@@ -50,35 +50,113 @@ class PriorityMetadata:
             if aff and aff.node_affinity else [])
 
 
+class _MapSelector:
+    """A Service's or ReplicationController's `spec.selector`: every item
+    equal. `items` is what the label set has to hold, sorted: what
+    SpreadListers and scorer.SpreadIndex look a selector up by. `key`
+    names the selector: two sources with one selector have one key."""
+    __slots__ = ("items", "key")
+
+    def __init__(self, selector: Dict[str, str]):
+        self.items = tuple(sorted(selector.items()))
+        self.key = ("map", self.items)
+
+    def __call__(self, lbls: Dict[str, str]) -> bool:
+        return all(lbls.get(k) == v for k, v in self.items)
+
+
+class _LabelSelector:
+    """A ReplicaSet's or StatefulSet's LabelSelector; `items` are its
+    matchLabels (necessary, not sufficient: the expressions are tested by
+    the call)."""
+    __slots__ = ("selector", "items", "key")
+
+    def __init__(self, selector):
+        self.selector = selector
+        self.items = tuple(sorted((selector.match_labels or {}).items()))
+        self.key = ("selector", labelsmod.canonical_selector(selector))
+
+    def __call__(self, lbls: Dict[str, str]) -> bool:
+        return labelsmod.matches(self.selector, lbls)
+
+
+def required_item(selectors) -> Optional[Tuple[str, str]]:
+    """A label item that some selector of the set requires (the first of
+    its sorted items), or None where none requires any: what an index by
+    item files a selector set under, and finds its candidates by."""
+    return next((sel.items[0] for sel in selectors
+                 if getattr(sel, "items", ())), None)
+
+
+#: label sets whose selectors SpreadListers remembers; past it the memo
+#: starts over (a miss is a lookup by item, not a walk)
+SELECTOR_MEMO_SIZE = 1 << 16
+
+
 class SpreadListers:
     """Selector sources for SelectorSpread: services, RCs, RSs, StatefulSets
-    (ref: selector_spreading.go getSelectors)."""
+    (ref: selector_spreading.go getSelectors).
+
+    A pod's selectors are found by LOOKUP: the sources of a namespace are
+    listed once and each selector filed under one item it requires (the
+    first of its sorted items; a selector that requires none, a
+    LabelSelector of expressions alone, is kept apart and tested for
+    every pod), so a pod tests the selectors filed under its own label
+    items and not every Service of the namespace. The answer a label set
+    got is remembered. Both last until `invalidate()`, which the
+    scheduler's Service / RC / RS / StatefulSet handlers call through
+    ScoreCompiler.invalidate_spread_selectors; a lister over a plain list
+    that the caller changes in place has to call it too."""
 
     def __init__(self, services=None, rcs=None, rss=None, statefulsets=None):
         self.services = services or (lambda ns: [])
         self.rcs = rcs or (lambda ns: [])
         self.rss = rss or (lambda ns: [])
         self.statefulsets = statefulsets or (lambda ns: [])
+        #: namespace -> ({item: [selector]}, [selectors without an item])
+        self._index: Dict[str, Tuple[Dict[Tuple[str, str], list], list]] = {}
+        #: (namespace, sorted label items) -> its selectors
+        self._memo: Dict[Tuple, list] = {}
+
+    def invalidate(self) -> None:
+        """A selector source changed: list and file them again on the
+        next lookup."""
+        self._index = {}
+        self._memo = {}
+
+    def _namespace(self, ns: str):
+        hit = self._index.get(ns)
+        if hit is None:
+            by_item: Dict[Tuple[str, str], list] = {}
+            apart: list = []
+            sels = [_MapSelector(o.spec.selector)
+                    for lister in (self.services, self.rcs)
+                    for o in lister(ns) if o.spec.selector]
+            sels += [_LabelSelector(o.spec.selector)
+                     for lister in (self.rss, self.statefulsets)
+                     for o in lister(ns) if o.spec.selector]
+            for sel in sels:
+                item = required_item([sel])
+                if item is not None:
+                    by_item.setdefault(item, []).append(sel)
+                else:
+                    apart.append(sel)
+            hit = self._index[ns] = (by_item, apart)
+        return hit
 
     def selectors_for_pod(self, pod: Pod) -> List[Callable[[Dict[str, str]], bool]]:
         ns = pod.metadata.namespace
-        out = []
-        for svc in self.services(ns):
-            sel = svc.spec.selector
-            if sel and all(pod.metadata.labels.get(k) == v for k, v in sel.items()):
-                out.append(lambda lbls, s=dict(sel): all(
-                    lbls.get(k) == v for k, v in s.items()))
-        for rc in self.rcs(ns):
-            sel = rc.spec.selector
-            if sel and all(pod.metadata.labels.get(k) == v for k, v in sel.items()):
-                out.append(lambda lbls, s=dict(sel): all(
-                    lbls.get(k) == v for k, v in s.items()))
-        for rs in self.rss(ns):
-            if rs.spec.selector and labelsmod.matches(rs.spec.selector, pod.metadata.labels):
-                out.append(lambda lbls, s=rs.spec.selector: labelsmod.matches(s, lbls))
-        for ss in self.statefulsets(ns):
-            if ss.spec.selector and labelsmod.matches(ss.spec.selector, pod.metadata.labels):
-                out.append(lambda lbls, s=ss.spec.selector: labelsmod.matches(s, lbls))
+        lbls = pod.metadata.labels
+        key = (ns, tuple(sorted(lbls.items())))
+        out = self._memo.get(key)
+        if out is None:
+            by_item, apart = self._namespace(ns)
+            out = [sel for item in key[1] for sel in by_item.get(item, ())
+                   if sel(lbls)]
+            out += [sel for sel in apart if sel(lbls)]
+            if len(self._memo) >= SELECTOR_MEMO_SIZE:
+                self._memo.clear()
+            self._memo[key] = out
         return out
 
 
@@ -218,17 +296,24 @@ def selector_spread_reduce(pod: Pod, meta: PriorityMetadata,
     max_zone = max(zone_counts.values()) if zone_counts else 0
     out: Dict[str, int] = {}
     for name, ni in node_infos.items():
+        # upstream's float64, operand order included: the quotient first,
+        # then times MaxPriority (10 * x / y rounds elsewhere and int()
+        # then lands a whole point off on some counts)
         score = float(MAX_PRIORITY)
         if max_count > 0:
-            score = MAX_PRIORITY * (max_count - counts.get(name, 0)) / max_count
+            score = float(MAX_PRIORITY) * (
+                float(max_count - counts.get(name, 0)) / float(max_count))
         if have_zones and ni.node is not None:
             zone = ni.node.metadata.labels.get(wellknown.LABEL_ZONE, "")
             # zone-less nodes keep the default MaxPriority zone score
             # (selector_spreading.go: zoneScore only recomputed with a zone id)
             zone_score = float(MAX_PRIORITY)
             if zone and max_zone > 0:
-                zone_score = MAX_PRIORITY * (max_zone - zone_counts.get(zone, 0)) / max_zone
-            score = score * (1 - ZONE_WEIGHTING) + ZONE_WEIGHTING * zone_score
+                zone_score = float(MAX_PRIORITY) * (
+                    float(max_zone - zone_counts.get(zone, 0))
+                    / float(max_zone))
+            score = (score * (1.0 - ZONE_WEIGHTING)) \
+                + (ZONE_WEIGHTING * zone_score)
         out[name] = int(score)
     return out
 

@@ -52,6 +52,10 @@ EXPRESS_EWMA_DECAY = 0.8
 #: ride out a transient fault, a third identical failure is a broken
 #: program
 MAX_LOOP_ERROR_STREAK = 3
+#: rounds of informers.wait_for_cache_sync (10 s each) that start() waits
+#: for the caches before it starts the loop regardless (a hub that never
+#: answers must not hang a caller of start() for good)
+SYNC_WAIT_ROUNDS = 12
 #: EWMA of the express share of queue depth above which bulk caps take
 #: an extra shrink unit — express bands have been queueing recently,
 #: so the next arrival should not wait out a mega-batch commit
@@ -1732,7 +1736,14 @@ class Scheduler:
     def start(self) -> None:
         """Start informers and the scheduling loop (ref: Scheduler.Run)."""
         self.informers.start()
-        self.informers.wait_for_cache_sync()
+        # ref: WaitForCacheSync(stopCh) blocks until every cache has
+        # synced; a loop that starts on the first timeout would decide
+        # pods against Services or nodes it has not listed yet (an
+        # informer that was stopped answers False at once: go on)
+        for _ in range(SYNC_WAIT_ROUNDS):
+            if self.informers.wait_for_cache_sync() or \
+                    self._stop.is_set() or self.informers.stopped():
+                break
         self._thread = threading.Thread(target=self._run_loop, daemon=True)
         self._thread.start()
 

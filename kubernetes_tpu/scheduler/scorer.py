@@ -21,10 +21,18 @@ TaintToleration when no node has PreferNoSchedule taints: all 10) are
 selection-invariant and dropped — ScheduleResult.score is therefore the
 selection score, not the reference's absolute weighted sum.
 
-In-batch drift: SelectorSpread counts and InterPodAffinity terms are frozen
-at batch start (the reference re-runs them after every one-pod bind). Hard
-(anti-)affinity stays exact via core._repair_batch; soft scores may lag by
-one batch — the documented batching tradeoff.
+In-batch drift: none for SelectorSpread in a singleton batch. Every
+(namespace, label set) of a batch that a Service or controller selects
+rides the scan as a spread group (core._assign_spread_groups): its base
+counts come from SpreadIndex below, kept as binds and deletes land, its
+running counts live in the scan's carry, and its score is upstream's
+float64 int() exactly (kernels/batch.py _spread_exact), so no row of this
+file carries it. A gang batch has no spread carry: there the row computed
+here from batch-start counts stands (_spread_counts, _spread_reduce).
+Preferred inter-pod affinity rides the scan's credit tables while its
+term union fits them and is frozen at batch start past that (counted:
+core._count_inscan_fallback). Hard (anti-)affinity stays exact via the
+in-scan tables and core._repair_batch.
 """
 
 from __future__ import annotations
@@ -42,9 +50,6 @@ from .tensorize import (NodeVectorCache, TensorMirror, TermCompiler,
                         _canon_tolerations)
 
 MAXP = float(prios.MAX_PRIORITY)
-#: templates ScoreCompiler._pod_has_spread_selectors remembers; past it
-#: the memo starts over (it outlives an epoch, so the count bounds it)
-SPREAD_SEL_MEMO_SIZE = 4096
 
 
 def _canon_preferred_node_affinity(pod: Pod) -> Tuple:
@@ -108,6 +113,114 @@ def _node_flags(ni: NodeInfo) -> Tuple[bool, bool, bool]:
             bool(ni.image_sizes))
 
 
+class SpreadIndex:
+    """SelectorSpread's counts, kept as binds and deletes land:
+    (namespace, label set) -> node -> pods of that label set on the node
+    that are not terminating. A spread group's base row is the sum over
+    the label sets its selectors match: O(its bound pods), where
+    prios.selector_spread_map walks every pod of every node.
+
+    Switched on by the first batch that carries a spread group
+    (`activate`: one pass over the snapshot, counted as rows walked);
+    from then on `apply` follows the cache's dirty list beside
+    TopologyIndex.apply, diffing a dirty node's pods by (key,
+    resourceVersion) as that index does. A cluster no Service selects a
+    pod of never pays for it."""
+
+    def __init__(self):
+        self.active = False
+        #: node -> {pod key: (resourceVersion, label-set key or None)}
+        self._nodes: Dict[str, Dict[str, Tuple]] = {}
+        #: (namespace, sorted label items) -> {node: count}
+        self._counts: Dict[Tuple, Dict[str, int]] = {}
+        #: (namespace, label item) -> the label-set keys that hold it
+        self._by_item: Dict[Tuple, set] = {}
+        #: namespace -> every label-set key in it
+        self._by_ns: Dict[str, set] = {}
+        #: node rows visited to (re)build counts: the walk this index
+        #: replaces, so 0 after the pass that switches it on
+        self.rows_walked = 0
+
+    def activate(self, snapshot) -> None:
+        if self.active:
+            return
+        self.active = True
+        names = list(snapshot.node_infos)
+        self.rows_walked += len(names)
+        self.apply(snapshot, names)
+
+    def apply(self, snapshot, dirty_names) -> None:
+        if not self.active:
+            return
+        for name in dirty_names:
+            ni = snapshot.node_infos.get(name)
+            have = self._nodes.get(name)
+            if ni is None or ni.node is None:
+                if have is not None:
+                    for key in list(have):
+                        self._sub(name, have, key)
+                    del self._nodes[name]
+                continue
+            if have is None:
+                have = self._nodes[name] = {}
+            fresh = {p.metadata.key(): p for p in ni.pods}
+            for key in list(have):
+                p = fresh.get(key)
+                if p is None or p.metadata.resource_version != have[key][0]:
+                    self._sub(name, have, key)
+            for key, p in fresh.items():
+                if key not in have:
+                    self._add(name, have, key, p)
+
+    def _add(self, node: str, have: dict, key: str, pod: Pod) -> None:
+        lkey = None
+        if pod.metadata.deletion_timestamp is None:
+            ns = pod.metadata.namespace
+            lkey = (ns, tuple(sorted(pod.metadata.labels.items())))
+            per_node = self._counts.get(lkey)
+            if per_node is None:
+                per_node = self._counts[lkey] = {}
+                self._by_ns.setdefault(ns, set()).add(lkey)
+                for item in lkey[1]:
+                    self._by_item.setdefault((ns, item), set()).add(lkey)
+            per_node[node] = per_node.get(node, 0) + 1
+        have[key] = (pod.metadata.resource_version, lkey)
+
+    def _sub(self, node: str, have: dict, key: str) -> None:
+        _, lkey = have.pop(key)
+        if lkey is None:
+            return
+        per_node = self._counts[lkey]
+        left = per_node[node] - 1
+        if left:
+            per_node[node] = left
+            return
+        del per_node[node]
+        if not per_node:
+            ns = lkey[0]
+            del self._counts[lkey]
+            self._by_ns[ns].discard(lkey)
+            for item in lkey[1]:
+                self._by_item[(ns, item)].discard(lkey)
+
+    def counts(self, namespace: str, selectors) -> Dict[str, int]:
+        """node -> pods on it, in `namespace` and not terminating, whose
+        labels satisfy EVERY one of `selectors` (selector_spreading.go
+        countMatchingPods). The label sets to test are those filed under
+        an item some selector requires; selectors that require none test
+        every label set of the namespace."""
+        item = prios.required_item(selectors)
+        cands = self._by_item.get((namespace, item), ()) \
+            if item is not None else self._by_ns.get(namespace, ())
+        total: Dict[str, int] = {}
+        for lkey in cands:
+            lbls = dict(lkey[1])
+            if all(sel(lbls) for sel in selectors):
+                for node, c in self._counts[lkey].items():
+                    total[node] = total.get(node, 0) + c
+        return total
+
+
 class ScoreCompiler:
     """Builds the static [P, N] score matrix for a batch."""
 
@@ -142,7 +255,13 @@ class ScoreCompiler:
         self._any_prefer_taints = False
         self._any_avoid_annotations = False
         self._any_images = False
-        self._spread_sel_memo: Dict[Tuple, bool] = {}
+        #: the in-scan spread groups' base counts (core keeps it applied)
+        self.spread_index = SpreadIndex()
+        #: (m, mesh) -> kernels.batch.spread_round_table(m) on the device
+        self._round_tables: Dict[Tuple, object] = {}
+        #: the zone ids on the device, and the (rescan, mesh) they are of
+        self._zone_gen = 0
+        self._zone_dev: Tuple[Optional[Tuple], object] = (None, None)
         self._cluster_has_affinity_pods = False
         #: bumped by invalidate_spread_selectors (Service/RC/RS/SS
         #: events): part of the spread chain signature, so a selector
@@ -218,7 +337,7 @@ class ScoreCompiler:
         self._row_zone = row_zone
         self._row_flags = flags
         self._flag_counts = flags.sum(axis=1)
-        self._spread_sel_memo = {}
+        self._zone_gen += 1
         m.vector_rebuilds.inc(cache="zones")
         m.vector_rows_recomputed.inc(m.n_rows)
 
@@ -279,11 +398,14 @@ class ScoreCompiler:
 
     # ------------------------------------------------------------- compile
 
-    def _pod_score_key(self, pod: Pod) -> Optional[Tuple]:
+    def _pod_score_key(self, pod: Pod,
+                       kernel_spread: bool = False) -> Optional[Tuple]:
         """Canonical key of everything that can make this pod's static score
         row differ from another pod's — None when no priority can contribute
         (the common resource-only case). Pods from one controller share the
-        key, so rows are computed once per controller, not once per pod."""
+        key, so rows are computed once per controller, not once per pod.
+        `kernel_spread`: the pod rides an in-scan spread group, so
+        SelectorSpread asks no row of this file for it."""
         w = self.weights
         parts = []
         contributes = False
@@ -309,6 +431,7 @@ class ScoreCompiler:
                 parts.append(None)
         spread_or_interpod = False
         if w.get("SelectorSpreadPriority") and self.listers is not None \
+                and not kernel_spread \
                 and self._pod_has_spread_selectors(pod):
             spread_or_interpod = True
         if w.get("InterPodAffinityPriority") and (
@@ -325,34 +448,53 @@ class ScoreCompiler:
         return tuple(parts)
 
     def invalidate_spread_selectors(self) -> None:
-        """Drop the per-template spread-selector memo. The scheduler shell
-        calls this on Service/RC/RS/StatefulSet informer events (the same
-        events that move parked pods back to active): the memo reads the
-        listers, not the nodes, so without this a Service created mid-run
-        would leave its templates memoized as selector-less and silently
-        skip spread scoring. The generation is part of the key of every
-        cached spread-count vector, so those start over as well."""
-        self._spread_sel_memo = {}
+        """A selector source changed. The scheduler shell calls this on
+        Service/RC/RS/StatefulSet informer events (the same events that
+        move parked pods back to active): the listers file the selectors
+        by item and remember each label set's answer, which reads the
+        sources and not the nodes, so without this a Service created
+        mid-run would leave its label sets remembered as selector-less
+        and silently skip spread scoring. The generation is part of the
+        key of every cached spread-count vector and of the spread chain
+        signature, so those start over as well."""
+        if self.listers is not None:
+            self.listers.invalidate()
         self.spread_sel_gen += 1
 
     def _pod_has_spread_selectors(self, pod: Pod) -> bool:
         """SelectorSpread contributes only when some service/controller
         selector matches the pod; without one, the whole (ns, labels)
         score-key component — and its per-template fits_row +
-        PriorityMetadata work — is dead weight. Memoized per template,
-        invalidated by selector-source events
-        (invalidate_spread_selectors) and by a full rescan of the nodes,
-        so a selector-less 16k-pod burst skips static scoring entirely."""
-        memo = self._spread_sel_memo
-        key = (pod.metadata.namespace,
-               tuple(sorted(pod.metadata.labels.items())))
-        hit = memo.get(key)
-        if hit is None:
-            hit = bool(self.listers.selectors_for_pod(pod))
-            if len(memo) >= SPREAD_SEL_MEMO_SIZE:
-                memo.clear()
-            memo[key] = hit
-        return hit
+        PriorityMetadata work — is dead weight. A lookup by label item
+        that the listers remember (prios.SpreadListers), so a
+        selector-less 16k-pod burst skips static scoring entirely."""
+        return bool(self.listers.selectors_for_pod(pod))
+
+    def zone_ids_device(self):
+        """The zone-id vector on the device (node-axis data: it shards
+        with the mirror rows), shipped once a rescan of the zones and not
+        once a launch: a bind renumbers no zone."""
+        key = (self._zone_gen, id(self.mirror.mesh))
+        if self._zone_dev[0] != key:
+            self._zone_dev = (key, self.mirror.put_named(
+                "spread_zone", self._zone_ids.astype(np.int32)))
+        return self._zone_dev[1]
+
+    def spread_round_table(self):
+        """kernels.batch.spread_round_table for this cluster's largest pod
+        limit (bucketed, at least 128), on the device: made and shipped
+        once a size and mesh, handed to every batch that carries spread
+        groups."""
+        from .kernels.batch import spread_round_table
+        from .tensorize import _bucket
+        m = self.mirror
+        limit = int(m.t.max_pods.max()) if m.t.max_pods.size else 0
+        key = (_bucket(limit, minimum=128), id(m.mesh))
+        tab = self._round_tables.get(key)
+        if tab is None:
+            tab = self._round_tables[key] = m.put_named(
+                "spread_tab", spread_round_table(key[0]))
+        return tab
 
     def static_scores(self, pods: List[Pod], batch
                       ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
@@ -373,14 +515,14 @@ class ScoreCompiler:
         row_of: Dict[Tuple, int] = {}
         any_contrib = False
         for i, pod in enumerate(pods):
-            skey = self._pod_score_key(pod)
-            if skey is None:
-                continue
             # pods in an in-scan spread group get their spread component
             # from the kernel's running counts — the static row must not
             # double-count it; same for inter-pod affinity when the batch
             # carries in-scan soft credit tables (core._assign_soft_terms)
             kernel_spread = bool(batch.spread_gidx[i] >= 0)
+            skey = self._pod_score_key(pod, kernel_spread)
+            if skey is None:
+                continue
             kernel_interpod = getattr(batch, "soft_dom", None) is not None
             # the feasible set (normalization domain) depends on the mask
             # row, the request columns, and the pressure flag
@@ -462,16 +604,19 @@ class ScoreCompiler:
     def _spread_reduce(self, counts: np.ndarray, feas: np.ndarray
                        ) -> np.ndarray:
         """CalculateSpreadPriorityReduce with zone blending
-        (selector_spreading.go zoneWeighting=2/3)."""
+        (selector_spreading.go zoneWeighting=2/3), in upstream's float64
+        and operand order (the quotient, then times MaxPriority), int()
+        last: prios.selector_spread_reduce over the node axis."""
+        counts = counts.astype(np.float64)
         max_count = float(counts[feas].max()) if feas.any() else 0.0
         if max_count > 0:
-            node_score = MAXP * (max_count - counts) / max_count
+            node_score = MAXP * ((max_count - counts) / max_count)
         else:
             node_score = np.full_like(counts, MAXP)
         zid = self._zone_ids
         have_zones = (zid[feas] > 0).any() if feas.any() else False
         if not have_zones:
-            return np.floor(node_score)
+            return np.floor(node_score).astype(np.float32)
         zcounts = np.bincount(zid, weights=counts * feas,
                               minlength=self._n_zones)
         max_zone = float(zcounts[1:].max()) if self._n_zones > 1 else 0.0
@@ -480,12 +625,12 @@ class ScoreCompiler:
         # (selector_spreading.go: zoneScore initialized to MaxPriority and
         # only recomputed for nodes with a zone id)
         zone_score = np.where((zid > 0) & (max_zone > 0),
-                              MAXP * (max_zone - zone_of_node) /
-                              max(max_zone, 1.0),
+                              MAXP * ((max_zone - zone_of_node) /
+                                      max(max_zone, 1.0)),
                               MAXP)
-        blended = node_score * (1 - prios.ZONE_WEIGHTING) + \
-            prios.ZONE_WEIGHTING * zone_score
-        return np.floor(blended)
+        blended = (node_score * (1.0 - prios.ZONE_WEIGHTING)) + \
+            (prios.ZONE_WEIGHTING * zone_score)
+        return np.floor(blended).astype(np.float32)
 
     def _interpod_raw(self, pod: Pod) -> Optional[np.ndarray]:
         """Preferred inter-pod (anti-)affinity + symmetric hard credit.

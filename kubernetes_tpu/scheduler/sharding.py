@@ -41,10 +41,10 @@ _NODE_LEADING = re.compile(
     r"|mem_pressure|valid|count|spread_zone)$")
 
 #: tensors whose TRAILING axis is the node axis: the deduplicated
-#: mask/score tables, spread/soft base rows, the chained spread-count
-#: carry, and the topology/gang [T, N] node->domain tables
+#: mask/score tables, soft base rows, the spread-count carry (chained or
+#: not), and the topology/gang [T, N] node->domain tables
 _NODE_TRAILING = re.compile(
-    r"^(unique_masks|unique_scores|spread_base|spread|soft_base|anti_dom"
+    r"^(unique_masks|unique_scores|spread|soft_base|anti_dom"
     r"|soft_dom|dom_tab)$")
 
 #: tensors carried per TENANT, not per node: the DRF usage carry

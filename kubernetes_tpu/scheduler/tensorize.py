@@ -73,6 +73,16 @@ REBUILD_SHARE = 0.5
 NODE_VECTOR_CACHE_SIZE = 128
 
 
+#: least rows of the scan's carried spread counts [G, N] and least
+#: (group, row, count) triplets of its base a launch ships: a pop of a
+#: rollout holds 5-200 groups and as many triplets as those groups have
+#: bound pods, and every power of two in between would be a program of
+#: its own (5-10 s of compiler a piece on the chip). 16 MiB of device
+#: memory at 8,192 node rows; 48 KiB of the packed buffer
+SPREAD_MIN_GROUPS = 512
+SPREAD_MIN_ENTRIES = 4096
+
+
 def _bucket(n: int, minimum: int = 128) -> int:
     """Next power-of-two capacity >= n (static shapes for XLA)."""
     return max(minimum, 1 << max(0, math.ceil(math.log2(max(1, n)))))
@@ -899,10 +909,12 @@ class PodBatchTensors:
         # sharing (namespace, selector set) share a group whose per-node
         # match counts update inside the kernel scan
         self.spread_gidx = np.full((P,), -1, np.int32)
-        self.spread_base: Optional[np.ndarray] = None   # [G, N] f32
-        self.spread_zone: Optional[np.ndarray] = None   # [N] int32 (0=no zone)
+        self.spread_slots: Optional[np.ndarray] = None  # [G] f32 zeros
+        self.spread_nz: Optional[np.ndarray] = None     # [3, S] int32
+        self.spread_mg: Optional[np.ndarray] = None     # [P, K] int32
+        self.spread_zone = None         # [N] int32 (0=no zone), on the device
         self.spread_zinit: Optional[np.ndarray] = None  # [Z] f32 zeros
-        self.spread_match: Optional[np.ndarray] = None  # [P, G] f32
+        self.spread_tab = None          # [M+1, M+1] int32, on the device
         self.spread_weight = 0.0
 
         # in-scan required (anti-)affinity term tables
@@ -1049,29 +1061,50 @@ class PodBatchTensors:
             "class_mask_idx": mask_idx, "class_score_idx": score_idx,
             "class_idx": class_idx.astype(np.int32)[:P]}
 
-    def set_spread(self, base: np.ndarray, zone_of: np.ndarray,
-                   n_zones: int, weight: float,
-                   match: Optional[np.ndarray] = None) -> None:
-        """Install spread group tables (G and Z bucketed to bound XLA
-        recompiles across batches). `match` [P, G0] marks which groups'
-        selectors match each pod — a winner bumps EVERY matching group's
-        running count (overlapping selector groups see each other's
-        in-batch placements, like the serial re-count would)."""
-        G = _bucket(base.shape[0], minimum=1)
+    def set_spread(self, n_groups: int, nz: np.ndarray,
+                   matched: List[Tuple[int, ...]], zone_of: np.ndarray,
+                   n_zones: int, weight: float, round_table) -> None:
+        """Install the spread group tables. What crosses to the device is
+        what is non-zero, and the kernel scatters it into zeros
+        (kernels/batch.py _spread_tables): a group's base row holds at
+        most as many entries as the group has pods, so G rows of N f32
+        are nearly all zeros (4-8 MB a launch at G = 128-256, N = 8,192,
+        on a transfer of their own; the triplets of a pop's groups are
+        some KB inside the packed buffer).
+
+          nz [3, S0] int32      (group, node row, count) of every non-zero
+                                base count; padded with group G, which
+                                the scatter drops
+          matched [P0] tuples   the groups whose selectors match each pod
+                                (its own and those that overlap it): a
+                                winner bumps EVERY one of them, as the
+                                serial re-count would; [P, K] int32, -1
+                                padded
+          zone_of, round_table  the zone ids [N] int32 and
+                                kernels.batch.spread_round_table, both on
+                                the device already (ScoreCompiler ships
+                                them once a rescan / a size)
+
+        G, S, K and Z are bucketed to bound XLA recompiles across
+        batches, G and S from SPREAD_MIN_GROUPS and SPREAD_MIN_ENTRIES
+        up, so that the pops of one deployment share one program a pod
+        bucket however many groups each holds; spread_slots [G] is
+        shipped for its shape alone."""
+        G = _bucket(n_groups, minimum=SPREAD_MIN_GROUPS)
         P = self.req.shape[0]
-        padded = np.zeros((G, base.shape[1]), np.float32)
-        padded[:base.shape[0]] = base
-        self.spread_base = padded
-        self.spread_zone = zone_of.astype(np.int32)
+        self.spread_slots = np.zeros((G,), np.float32)
+        S = _bucket(nz.shape[1], minimum=SPREAD_MIN_ENTRIES)
+        self.spread_nz = np.zeros((3, S), np.int32)
+        self.spread_nz[0] = G
+        self.spread_nz[:, :nz.shape[1]] = nz
+        K = _bucket(max((len(m) for m in matched), default=1), minimum=1)
+        self.spread_mg = np.full((P, K), -1, np.int32)
+        for i, m in enumerate(matched):
+            self.spread_mg[i, :len(m)] = m
+        self.spread_zone = zone_of
         self.spread_zinit = np.zeros((_bucket(n_zones, minimum=8),),
                                      np.float32)
-        self.spread_match = np.zeros((P, G), np.float32)
-        if match is not None:
-            self.spread_match[:match.shape[0], :match.shape[1]] = match
-        else:
-            for i, g in enumerate(self.spread_gidx):
-                if g >= 0:
-                    self.spread_match[i, g] = 1.0
+        self.spread_tab = round_table
         self.spread_weight = float(weight)
 
     def set_static_scores(self, score_idx: np.ndarray,
@@ -1105,7 +1138,7 @@ class PodBatchTensors:
         host->device transfer for them, cut back into the same names
         inside the jitted kernel (unpack_inputs). What a partition rule
         places on the node axis (unique_masks, unique_scores,
-        spread_base, spread_zone, anti_dom, soft_dom, soft_base) still
+        spread_zone, anti_dom, soft_dom, soft_base) still
         crosses on its own: it is large and shards under a mesh; the
         epoch-cached anti_dom_dev is on the device already.
         pack_inputs reads which is which off each array."""
@@ -1121,12 +1154,15 @@ class PodBatchTensors:
                "unique_masks": self.unique_masks,
                "unique_scores": self.unique_scores,
                "resource_weights": self.resource_weights}
-        if self.spread_base is not None:
+        if self.spread_slots is not None:
             out["spread_gidx"] = self.spread_gidx
-            out["spread_match"] = self.spread_match
-            out["spread_base"] = self.spread_base
+            out["spread_mg"] = self.spread_mg
+            out["spread_slots"] = self.spread_slots
+            out["spread_nz"] = self.spread_nz
+            out["spread_tab"] = self.spread_tab
             # the zone-id vector is node-axis data: it shards with the
             # mirror rows so the shard_map kernel's local slice aligns
+            # (on the device already, like the round table)
             out["spread_zone"] = self.spread_zone
             out["spread_zinit"] = self.spread_zinit
             out["spread_weight"] = np.float32(self.spread_weight)

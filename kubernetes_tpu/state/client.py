@@ -49,7 +49,9 @@ class ResourceClient:
             self._resolve_cluster_ip_collision(obj)
         if self._validate:
             validate_obj(obj)
-        return self._store.create(self._resource, obj)
+        stored = self._store.create(self._resource, obj)
+        self._note_services_created([stored])
+        return stored
 
     def create_bulk(self, objs) -> list:
         """N creates, one store transaction (defaulting/validation still
@@ -73,18 +75,56 @@ class ResourceClient:
             slots.append(len(prepared))
             prepared.append(obj)
         stored = self._store.create_bulk(self._resource, prepared)
+        self._note_services_created(stored)
         return [s if isinstance(s, Exception) else stored[s] for s in slots]
+
+    def _service_ips(self) -> dict:
+        """cluster IP -> the keys of the Services that hold it, as of the
+        store's resourceVersion: `Store.service_ips` keeps the last
+        answer beside the version it is true for, so a run of Service
+        creates that nothing else interrupts (thousands of them in a
+        rollout's set-up) lists the Services once and not once a create.
+        Any other write moves the version on and the next use lists
+        again."""
+        store = self._store
+        cached = store.service_ips
+        if cached is not None and cached[0] == store.resource_version:
+            return cached[1]
+        items, rv = store.list("services")
+        ips: dict = {}
+        for s in items:
+            if s.spec.cluster_ip:
+                ips.setdefault(s.spec.cluster_ip, set()).add(
+                    s.metadata.key())
+        store.service_ips = (rv, ips)
+        return ips
+
+    def _note_services_created(self, stored) -> None:
+        """Carry `Store.service_ips` over our own creates: each is the
+        one write between the version the answer was true for and the
+        next; anything else in between drops it."""
+        store = self._store
+        for obj in stored:
+            cached = store.service_ips
+            if cached is None or not isinstance(obj, corev1.Service):
+                return
+            rv = int(obj.metadata.resource_version)
+            if cached[0] != rv - 1:
+                store.service_ips = None
+                return
+            if obj.spec.cluster_ip:
+                cached[1].setdefault(obj.spec.cluster_ip, set()).add(
+                    obj.metadata.key())
+            store.service_ips = (rv, cached[1])
 
     def _resolve_cluster_ip_collision(self, svc) -> None:
         """The ipallocator's uniqueness guarantee: the hash-derived default
         is salted until it collides with no existing service."""
         from ..api.defaults import service_cluster_ip
-        taken = {s.spec.cluster_ip
-                 for s, _ in ((o, None) for o in
-                              self._store.list("services")[0])
-                 if s.metadata.key() != svc.metadata.key()}
+        ips = self._service_ips()
+        own = {svc.metadata.key()}
         salt = 0
-        while svc.spec.cluster_ip in taken and salt < 64:
+        while ips.get(svc.spec.cluster_ip, own) - own and salt < 64:
             salt += 1
             svc.spec.cluster_ip = service_cluster_ip(
                 svc.metadata.namespace, svc.metadata.name, salt)

@@ -279,7 +279,14 @@ class SharedInformer:
         self.indexer.replace(items)
         with self._lock:
             handlers = list(self._handlers)
-        for obj in items:
+        # in resourceVersion order, as a watch from the start would have
+        # delivered them: a LIST comes sorted by name (pod-100 before
+        # pod-2), and a handler that queues what it is told (the
+        # scheduler's) would otherwise serve pods that were waiting
+        # before it listed in name order, not in the order of their
+        # creation
+        for obj in sorted(items, key=lambda o: int(
+                o.metadata.resource_version or 0)):
             key = Indexer.key_of(obj)
             prev = old.pop(key, None)
             for h in handlers:
@@ -573,6 +580,13 @@ class SharedInformerFactory:
         with self._lock:
             informers = list(self._informers.values())
         return all(inf.wait_for_sync(timeout) for inf in informers)
+
+    def stopped(self) -> bool:
+        """Some informer of the factory was stopped: wait_for_cache_sync
+        answers False at once and will never answer True."""
+        with self._lock:
+            informers = list(self._informers.values())
+        return any(inf._stop.is_set() for inf in informers)
 
     def stop(self) -> None:
         with self._lock:

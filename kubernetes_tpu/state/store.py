@@ -140,6 +140,12 @@ class Store:
         #: the last replay's accounting (state/wal.WalRecovery), None
         #: until a WAL-backed store has replayed at least once
         self.wal_recovery = None
+        #: (resourceVersion, {cluster IP: keys of the Services holding
+        #: it}) as state/client.py last computed it, or None: true only
+        #: while resource_version still reads that version, and dropped
+        #: wherever the version can move backwards (restart, a replica's
+        #: wholesale replace)
+        self.service_ips = None
         if wal_path is not None:
             self._replay_wal(wal_path)
             from .wal import WalWriter
@@ -373,6 +379,7 @@ class Store:
             self._history.clear()
             self._rv = 0
             self._uid_counter = 0
+            self.service_ips = None
             self._replay_wal(path)
             from .wal import WalWriter
             self._wal = WalWriter(path, sync=sync, deferred=not sync,
@@ -616,6 +623,7 @@ class Store:
         so a promote continues the same CAS timeline; local watches fire
         so read clients of the replica see live events."""
         with self._lock:
+            self.service_ips = None
             bucket = self._data.setdefault(resource, {})
             key = (obj.metadata.namespace, obj.metadata.name)
             self._follow_clock_locked(rv)
@@ -653,6 +661,7 @@ class Store:
         keeps the high-water mark), so a later promote still mints rvs
         above anything EITHER timeline handed out."""
         with self._lock:
+            self.service_ips = None
             bucket = self._data.setdefault(resource, {})
             listed = set()
             for obj in objs:

@@ -101,7 +101,7 @@ def _scheduler(class_scan=True):
 
 def _mixed_pods(n):
     """Half pod-anti-affinity, half plain, all selected by the spread
-    Service: the batch carries topology terms AND spread groups."""
+    Service: the batch carries topology terms AND a spread group."""
     return [fakecluster.make_pod(
         i, "pod-anti-affinity" if i % 2 else "uniform") for i in range(n)]
 
@@ -147,7 +147,7 @@ def class_batch():
                        "schedule_batch")
     batch = kb.unpack_inputs(args[2])
     assert batch["class_req"].ndim == 2 and "anti_dom" in batch \
-        and "spread_base" in batch
+        and "spread_slots" in batch
     assert batch["req"].shape[0] == 16384
     assert batch["unique_masks"].shape[1] == CAPACITY
     # what a rule places on the node axis crosses on its own, and so do
@@ -155,7 +155,7 @@ def class_batch():
     # PACK_MAX_BYTES); the pod-axis vectors and the class tables ride
     # the one buffer
     assert set(args[2].rest) == {"unique_masks", "unique_scores",
-                                 "anti_dom", "spread_base", "spread_zone",
+                                 "anti_dom", "spread_zone", "spread_tab",
                                  "anti_cnt0"}
     return args
 
@@ -338,10 +338,17 @@ def test_sharded_class_scan(class_batch, mesh4):
     # spread reduce and the topology dom broadcast, per pod
     assert text.count("all-reduce") >= 5
     import jax
-    whole = sum(int(np.prod(s.shape)) * s.dtype.itemsize
-                for s in jax.tree_util.tree_leaves(shapes))
+    size = lambda s: int(np.prod(s.shape)) * s.dtype.itemsize
+    leaves = jax.tree_util.tree_leaves(shapes)
+    whole = sum(size(s) for s in leaves)
+    split = sum(size(s) for s in leaves
+                if not s.sharding.is_fully_replicated)
     per_device = compiled.memory_analysis().argument_size_in_bytes
-    # node-axis tables dominate the arguments: a quarter each, plus the
-    # replicated per-pod arrays
-    assert per_device < 0.4 * whole, (per_device, whole)
+    # every node-axis table is an argument a quarter a device; what is
+    # replicated (the packed per-pod arrays, anti_cnt0's [T, hostnames])
+    # is there whole. Since every spread group rides the scan no static
+    # score row is left to dominate the arguments (256 rows before PR 37)
+    assert split > 0.4 * whole
+    assert per_device <= 1.02 * (whole - split + split / 4), \
+        (per_device, whole, split)
 

@@ -135,7 +135,7 @@ class TestClassScanRouting:
         pending = sched.schedule_launch(pods)
         # ACCEPTANCE: the spread batch was NOT demoted to the classic path
         assert pending.batch._class_tables is not None
-        assert pending.batch.spread_base is not None
+        assert pending.batch.spread_slots is not None
         assert pending.spread_sig is not None
         results = sched.schedule_finish(pending)
         row_of = dict(sched.mirror.row_of)

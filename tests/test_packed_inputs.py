@@ -39,10 +39,11 @@ def _device_as_before(b, mesh):
         "req", "nonzero_req", "mem_pressure_blocked", "active", "seq",
         "mask_idx", "score_idx", "nom_row", "unique_masks", "unique_scores",
         "resource_weights")}
-    if b.spread_base is not None:
-        for k in ("spread_gidx", "spread_match", "spread_base",
+    if b.spread_slots is not None:
+        for k in ("spread_gidx", "spread_mg", "spread_slots", "spread_nz",
                   "spread_zone", "spread_zinit"):
             out[k] = put(k)
+        out["spread_tab"] = b.spread_tab
         out["spread_weight"] = jnp.float32(b.spread_weight)
     if b.anti_dom is not None:
         out["anti_dom"] = b.anti_dom_dev if b.anti_dom_dev is not None \
@@ -107,8 +108,9 @@ FLAVOURS = {
                        "match_tids"}),
     "spread": (dict(variant="uniform", spread=True), "uniform", "batch",
                "schedule_batch",
-               {"spread_gidx", "spread_match", "spread_base", "spread_zone",
-                "spread_zinit", "spread_weight"}),
+               {"spread_gidx", "spread_mg", "spread_slots", "spread_nz",
+                "spread_tab", "spread_zone", "spread_zinit",
+                "spread_weight"}),
     "soft_terms": (dict(variant="preferred-affinity"), "preferred-affinity",
                    "batch", "schedule_batch",
                    {"soft_dom", "soft_cnt0", "soft_base", "soft_base_idx",
@@ -124,9 +126,13 @@ PLAIN_NAMES = {"req", "nonzero_req", "mem_pressure_blocked", "active", "seq",
                "mask_idx", "score_idx", "nom_row", "unique_masks",
                "unique_scores", "resource_weights"}
 #: what a rule of sharding.py places on the node axis: its own transfer
-NODE_AXIS_NAMES = {"unique_masks", "unique_scores", "spread_base",
+NODE_AXIS_NAMES = {"unique_masks", "unique_scores",
                    "spread_zone", "anti_dom", "soft_dom", "soft_base",
                    "dom_tab"}
+
+
+#: device arrays a batch is handed: passed through, no transfer a launch
+ON_DEVICE = {"spread_tab"}
 
 
 def _launch(flavour):
@@ -188,8 +194,10 @@ def test_kernel_sees_the_same_dict_and_decides_the_same(flavour):
     for name in sorted(seen):
         _same_bits(name, seen[name], before[name])
     # on their own: exactly what a rule places on the node axis, placed
-    # as before; everything else rode the one buffer
-    assert set(packed.rest) == set(seen) & NODE_AXIS_NAMES
+    # as before, and what was on the device already (the spread score's
+    # round table, shipped once a size); everything else rode the one
+    # buffer
+    assert set(packed.rest) == set(seen) & (NODE_AXIS_NAMES | ON_DEVICE)
     for name, a in packed.rest.items():
         assert a.sharding.is_equivalent_to(before[name].sharding, a.ndim), \
             name
