@@ -62,14 +62,6 @@ WIRE_DECODE_SECONDS = Histogram(
     "Payload decode latency, by encoding", WIRE_CODEC_BUCKETS)
 
 
-def reset_wire_metrics() -> None:
-    """Zero the client-side wire families (bench phase boundaries:
-    steady-state rates must not be skewed by warmup/setup traffic)."""
-    WIRE_BYTES_SENT.clear()
-    WIRE_BYTES_RECEIVED.clear()
-    WIRE_DECODE_SECONDS.clear()
-
-
 class WatchStaleError(ConnectionError):
     """A watch stream went silent past the heartbeat-staleness window and
     was killed by the consumer's watchdog (the server heartbeats every
@@ -167,7 +159,9 @@ class _HTTPWatch:
         self.last_rv: Optional[int] = None
         self.last_activity = time.monotonic()
         self.events: "Queue[Optional[WatchEvent]]" = Queue()
-        self._thread = threading.Thread(target=self._pump, daemon=True)
+        # the name is the role scheduler_thread_cpu_seconds sums it by
+        self._thread = threading.Thread(target=self._pump, daemon=True,
+                                        name="watch_pump")
         self._thread.start()
 
     def _pump(self) -> None:

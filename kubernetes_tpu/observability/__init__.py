@@ -10,10 +10,10 @@ MetricsRegistry the APIServer serves at GET /metrics, next to
 from .registry import MetricsRegistry, parse_exposition
 from .tracer import (DEFAULT_POD_SAMPLE, FlightRecorder, NULL_TRACER,
                      Span, SpanTracer, nearest_rank_percentile,
-                     stage_percentiles)
+                     thread_cpu_by_role)
 
 __all__ = [
     "DEFAULT_POD_SAMPLE", "FlightRecorder", "MetricsRegistry",
     "NULL_TRACER", "Span", "SpanTracer", "nearest_rank_percentile",
-    "parse_exposition", "stage_percentiles",
+    "parse_exposition", "thread_cpu_by_role",
 ]

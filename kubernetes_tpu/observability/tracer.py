@@ -16,13 +16,16 @@ the contract rests on the deterministic SET of spans, not on which
 informer thread's append won a race within a settle window.
 
 Cost model: a stage (SpanTracer.stage) is entered once per batch,
-request or transaction, never per pod. Its histogram is always on; its
-trace annotation costs the profiler's own check while no profiler
-session runs; its span reaches the flight recorder only where a harness
-attached an enabled tracer. Pod-lifecycle spans are sampled
-1-in-`pod_sample` by a crc32 of the trace_id (default 16,
-KTPU_TRACE_SAMPLE overrides; harnesses pass 1 to capture every pod) and
-cost nothing on a disabled tracer. The recorder is a per-component ring
+request or transaction, never per pod. Its histogram is always on; so is
+the read of the entering thread's CPU clock (time.thread_time() on entry
+and exit, two system calls) where the caller passes a `cpu` counter:
+the CPU seconds go into that counter and never into a span, whose
+timestamps stay on the injected clock. Its trace annotation costs the
+profiler's own check while no profiler session runs; its span reaches
+the flight recorder only where a harness attached an enabled tracer.
+Pod-lifecycle spans are sampled 1-in-`pod_sample` by a crc32 of the
+trace_id (default 16; harnesses pass 1 to capture every pod) and cost
+nothing on a disabled tracer. The recorder is a per-component ring
 — oldest spans evict, and the eviction count is itself visible
 (`dropped`).
 """
@@ -30,9 +33,9 @@ cost nothing on a disabled tracer. The recorder is a per-component ring
 from __future__ import annotations
 
 import json
-import os
 import sys
 import threading
+import time
 import zlib
 from collections import deque
 from typing import Dict, Iterable, List, Optional
@@ -40,7 +43,7 @@ from typing import Dict, Iterable, List, Optional
 from ..utils.clock import Clock, REAL_CLOCK
 
 #: 1-in-N pod-lifecycle sampling when the caller does not choose
-#: (KTPU_TRACE_SAMPLE overrides; 1 = trace every pod, 0 = disable)
+#: (1 = trace every pod, 0 = disable)
 DEFAULT_POD_SAMPLE = 16
 
 
@@ -151,14 +154,17 @@ class FlightRecorder:
 class _Stage:
     """One timed interval at a layer boundary (SpanTracer.stage)."""
 
-    __slots__ = ("_tracer", "_name", "_histogram", "_labels", "_component",
-                 "_ring", "_annotation", "start", "attrs", "seconds")
+    __slots__ = ("_tracer", "_name", "_histogram", "_cpu", "_labels",
+                 "_component", "_ring", "_annotation", "_cpu_start", "start",
+                 "attrs", "seconds")
 
-    def __init__(self, tracer, name, histogram, labels, component, trace,
-                 ring, attrs):
+    def __init__(self, tracer, name, histogram, cpu, labels, component,
+                 trace, ring, attrs):
         self._tracer = tracer
         self._name = name
         self._histogram = histogram
+        self._cpu = cpu
+        self._cpu_start = 0.0
         self._labels = labels
         self._component = component
         self._ring = ring
@@ -179,11 +185,18 @@ class _Stage:
         if self._annotation is not None:
             self._annotation.__enter__()
         self.start = self._tracer.clock.monotonic()
+        # the CPU interval inside the wall one: wall - CPU >= 0
+        if self._cpu is not None:
+            self._cpu_start = time.thread_time()
         return self
 
     def __exit__(self, *exc) -> None:
+        if self._cpu is not None:
+            cpu = time.thread_time() - self._cpu_start
         tracer = self._tracer
         end = tracer.clock.monotonic()
+        if self._cpu is not None:
+            self._cpu.inc(cpu, **self._labels)
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         self.seconds = end - self.start
@@ -208,8 +221,7 @@ class SpanTracer:
         self.clock = clock
         self.recorder = recorder if recorder is not None else FlightRecorder()
         if pod_sample is None:
-            pod_sample = int(os.environ.get("KTPU_TRACE_SAMPLE",
-                                            DEFAULT_POD_SAMPLE))
+            pod_sample = DEFAULT_POD_SAMPLE
         self.pod_sample = max(0, int(pod_sample))
         self.enabled = enabled and self.pod_sample != 0
 
@@ -227,7 +239,7 @@ class SpanTracer:
             return self.enabled
         return zlib.crc32(trace_id.encode()) % self.pod_sample == 0
 
-    def stage(self, name: str, histogram=None, *,
+    def stage(self, name: str, histogram=None, *, cpu=None,
               labels: Optional[dict] = None, trace: Optional[str] = None,
               component: str = "scheduler", ring: bool = True,
               **attrs) -> _Stage:
@@ -236,6 +248,12 @@ class SpanTracer:
 
         - `histogram.observe(seconds, **labels)`: always, the series an
           operator scrapes (None: a component without metrics);
+        - `cpu.inc(cpu_seconds, **labels)`: the entering thread's CPU
+          clock over the interval (time.thread_time(), read on entry and
+          exit), so that wall minus CPU is the stage's time off the core:
+          waits for the interpreter lock and calls that block. Real time
+          on any clock, so it goes into the counter alone, never into a
+          span (None: no read);
         - `trace`: the interval wrapped in a jax.profiler.TraceAnnotation
           of that name, so that while a profiler session runs the span
           lies on the /host:CPU plane on the device trace's own clock.
@@ -248,7 +266,7 @@ class SpanTracer:
         Works on a disabled tracer (NULL_TRACER for a component that has
         none): "disabled" switches the ring and the per-pod milestones,
         not the stages."""
-        return _Stage(self, name, histogram, labels or {}, component,
+        return _Stage(self, name, histogram, cpu, labels or {}, component,
                       trace, ring, attrs)
 
     def record(self, component: str, name: str, start: float,
@@ -303,27 +321,28 @@ def nearest_rank_percentile(sorted_vals: List[float], q: float) -> float:
     return sorted_vals[rank - 1]
 
 
-def stage_percentiles(recorder: FlightRecorder,
-                      component: Optional[str] = None,
-                      names: Optional[Iterable[str]] = None) -> dict:
-    """Per-stage duration percentiles from batch/stage spans (trace-less
-    spans with a real interval) — the bench's --trace report and the
-    cross-check against measure_device_profile's pipeline section."""
-    by_name: Dict[str, List[float]] = {}
-    for s in recorder.spans(component=component):
-        if s.trace_id:
-            continue  # pod milestones are instants, not stages
-        if names is not None and s.name not in names:
+def _thread_cpu_clock(native_id: int) -> int:
+    """The CPU clock of a thread of this process by its kernel id, as
+    glibc's pthread_getcpuclockid makes it (MAKE_THREAD_CPUCLOCK: the id
+    complemented, shifted by 3, ORed with CPUCLOCK_PERTHREAD_MASK 4 and
+    CPUCLOCK_SCHED 2). From the kernel id and not the pthread handle:
+    pthread_getcpuclockid reads through a handle that goes stale the
+    moment its thread exits, while a dead id makes clock_gettime raise."""
+    return (~native_id << 3) | 6
+
+
+def thread_cpu_by_role(roles: Iterable[str]) -> Dict[str, float]:
+    """CPU seconds of this process's live threads, summed by role: a
+    thread counts for the first role its name starts with, and for none
+    if it starts with none. Read at the call, nothing kept between calls:
+    a thread that has exited takes its CPU with it."""
+    out = dict.fromkeys(roles, 0.0)
+    for t in threading.enumerate():
+        role = next((r for r in out if t.name.startswith(r)), None)
+        if role is None or t.native_id is None:
             continue
-        by_name.setdefault(s.name, []).append(s.duration)
-    out = {}
-    for name, vals in sorted(by_name.items()):
-        vals.sort()
-        out[name] = {
-            "count": len(vals),
-            "p50_s": round(nearest_rank_percentile(vals, 0.50), 6),
-            "p95_s": round(nearest_rank_percentile(vals, 0.95), 6),
-            "p99_s": round(nearest_rank_percentile(vals, 0.99), 6),
-            "total_s": round(sum(vals), 6),
-        }
+        try:
+            out[role] += time.clock_gettime(_thread_cpu_clock(t.native_id))
+        except OSError:
+            continue    # it exited after enumerate(): its CPU is gone
     return out

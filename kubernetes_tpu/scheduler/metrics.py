@@ -8,6 +8,7 @@ reference's per-pod timers have no analog for.
 
 from __future__ import annotations
 
+from ..observability.tracer import thread_cpu_by_role
 from ..utils.metrics import Registry
 
 SCHEDULING_LATENCY_BUCKETS = (0.0005, 0.001, 0.002, 0.004, 0.008, 0.016,
@@ -55,6 +56,12 @@ INSCAN_FALLBACK_REASONS = ("term_cap", "kmax", "soft_terms", "soft_kmax",
                            "spread_range")
 
 
+#: every `role` of scheduler_thread_cpu_seconds: the prefix of the names
+#: of the threads that carry it (the loop thread, the two binders, the
+#: informers' delivery threads, the HTTP watch streams' readers)
+THREAD_ROLES = ("scheduling", "binder", "informer", "watch_pump")
+
+
 #: every `cache` of scheduler_node_vector_rebuilds_total
 NODE_VECTOR_CACHES = ("terms", "scores", "zones")
 #: every `side` of scheduler_mirror_row_writes_total
@@ -73,8 +80,27 @@ class SchedulerMetrics:
             "scheduler_scheduling_duration_seconds",
             "Scheduling stage latency per batch cycle, by operation",
             buckets=SCHEDULING_LATENCY_BUCKETS)
+        # the same stages on the CPU clock of the thread that entered
+        # them (SpanTracer.stage's cpu): a stage's duration minus its CPU
+        # is its time off the core, the waits for the interpreter lock
+        # and the calls that block (for pop_wait, scan_wait and
+        # bind_backlog, the wait they are)
+        self.scheduling_cpu = r.counter(
+            "scheduler_scheduling_cpu_seconds_total",
+            "CPU seconds of the entering thread inside each scheduling "
+            "stage, by operation")
         for op in STAGE_PARENTS + STAGE_LEAVES + STAGE_PARTS:
             self.scheduling_duration.declare(operation=op)
+            self.scheduling_cpu.declare(operation=op)
+        # CPU of the process's threads by role, read off each live
+        # thread's CPU clock at the scrape (nothing on the hot path); a
+        # thread that has exited, the collector's own and the /metrics
+        # server's are in none
+        self.thread_cpu = r.gauge(
+            "scheduler_thread_cpu_seconds",
+            "CPU seconds of the scheduler's live threads, by role",
+            fn=lambda: {(("role", role),): v for role, v in
+                        thread_cpu_by_role(THREAD_ROLES).items()})
         # ref: E2eSchedulingLatency — queue pop to bind committed
         self.e2e_scheduling_duration = r.histogram(
             "scheduler_e2e_scheduling_duration_seconds",
@@ -319,11 +345,16 @@ class SchedulerMetrics:
 
     def stage(self, tracer, name: str, ring: bool = True, **attrs):
         """observability.SpanTracer.stage for one `operation` of the
-        cycle: a leaf, and a part of one, also gets the trace annotation
-        sched.<name>, a parent none (it would cover the host time that
-        its leaves leave unexplained)."""
+        cycle: its wall into scheduler_scheduling_duration_seconds and
+        the entering thread's CPU clock into
+        scheduler_scheduling_cpu_seconds_total, both always (the CPU
+        into the counter alone, never into a span); a leaf, and a part
+        of one, also gets the trace annotation sched.<name>, a parent
+        none (it would cover the host time that its leaves leave
+        unexplained)."""
         return tracer.stage(
-            name, self.scheduling_duration, labels={"operation": name},
+            name, self.scheduling_duration, cpu=self.scheduling_cpu,
+            labels={"operation": name},
             trace="sched." + name
             if name in STAGE_LEAVES + STAGE_PARTS else None,
             ring=ring, **attrs)

@@ -1744,7 +1744,9 @@ class Scheduler:
             if self.informers.wait_for_cache_sync() or \
                     self._stop.is_set() or self.informers.stopped():
                 break
-        self._thread = threading.Thread(target=self._run_loop, daemon=True)
+        # the name is the role scheduler_thread_cpu_seconds sums it by
+        self._thread = threading.Thread(target=self._run_loop, daemon=True,
+                                        name="scheduling")
         self._thread.start()
 
     def _run_loop(self) -> None:

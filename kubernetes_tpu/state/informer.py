@@ -178,7 +178,10 @@ class SharedInformer:
             if self._started:
                 return
             self._started = True
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        # the name is the role scheduler_thread_cpu_seconds sums it by
+        self._thread = threading.Thread(
+            target=self._run, daemon=True,
+            name=f"informer-{self._resource}")
         self._thread.start()
 
     def stop(self) -> None:

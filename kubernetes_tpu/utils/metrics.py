@@ -122,7 +122,16 @@ class Gauge(_Metric):
     def __init__(self, name: str, help_text: str = "", fn=None):
         super().__init__(name, help_text)
         self._values: Dict[Tuple, float] = {}
-        self._fn = fn  # callback gauge: sampled at expose time
+        #: callback gauge, sampled at expose time: a number, or a dict of
+        #: label dict items (a snapshot() key, e.g. (("role", "binder"),))
+        #: -> number, one series a label set
+        self._fn = fn
+
+    def _sample(self) -> Dict[Tuple, float]:
+        v = self._fn()
+        if isinstance(v, dict):
+            return {key: float(x) for key, x in v.items()}
+        return {(): float(v)}
 
     def set(self, v: float, **labels) -> None:
         with self._lock:
@@ -138,7 +147,7 @@ class Gauge(_Metric):
 
     def value(self, **labels) -> float:
         if self._fn is not None:
-            return float(self._fn())
+            return self._sample().get(_label_key(labels), 0.0)
         with self._lock:
             return self._values.get(_label_key(labels), 0.0)
 
@@ -149,18 +158,13 @@ class Gauge(_Metric):
     def snapshot(self) -> Dict[Tuple, float]:
         """Label key -> value copy (callback gauges sample the fn)."""
         if self._fn is not None:
-            return {(): float(self._fn())}
+            return self._sample()
         with self._lock:
             return dict(self._values)
 
     def expose(self) -> List[str]:
         out = self._header()
-        if self._fn is not None:
-            out.append(f"{self.name} {float(self._fn())}")
-            return out
-        with self._lock:
-            items = sorted(self._values.items())
-        for key, v in items or [((), 0.0)]:
+        for key, v in sorted(self.snapshot().items()) or [((), 0.0)]:
             out.append(f"{self.name}{_fmt_labels(key)} {v}")
         return out
 

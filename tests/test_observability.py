@@ -27,8 +27,7 @@ import pytest
 from kubernetes_tpu import api
 from kubernetes_tpu.api.quantity import Quantity
 from kubernetes_tpu.observability import (FlightRecorder, MetricsRegistry,
-                                          SpanTracer, parse_exposition,
-                                          stage_percentiles)
+                                          SpanTracer, parse_exposition)
 from kubernetes_tpu.state.client import Client
 from kubernetes_tpu.state.store import Store
 from kubernetes_tpu.utils import healthz as healthz_mod
@@ -105,19 +104,6 @@ class TestSpanTracer:
         tr.event("c", "e")
         tr.record("c", "s", 0.0, 1.0)
         assert len(tr.recorder) == 0
-
-    def test_stage_percentiles(self):
-        clock = FakeClock()
-        tr = SpanTracer(clock=clock, pod_sample=1)
-        for d in (1.0, 2.0, 3.0, 4.0):
-            t0 = tr.now()
-            clock.step(d)
-            tr.record("sched", "launch", t0, tr.now())
-        out = stage_percentiles(tr.recorder, component="sched")
-        assert out["launch"]["count"] == 4
-        assert out["launch"]["p50_s"] == 2.0
-        assert out["launch"]["p99_s"] == 4.0
-        assert out["launch"]["total_s"] == 10.0
 
 
 class TestTraceInjectableClock:
