@@ -12,6 +12,7 @@ import sys
 import threading
 
 from ..apiserver.server import APIServer
+from ..utils import gcpolicy
 
 
 def main(argv=None) -> int:
@@ -100,6 +101,9 @@ def main(argv=None) -> int:
             authn = CertAuthenticator(fallback=authn)
         srv.authenticator = authn
         srv.authorizer = authz
+    # the collector stops re-walking the stored cluster: each full
+    # collection freezes what survived it (utils/gcpolicy.py)
+    srv.metrics.add_registry("gc", gcpolicy.install().registry)
     srv.start()
     compactor = None
     if store is not None:

@@ -73,56 +73,13 @@ class _Metric:
                 f"# TYPE {self.name} {self.kind}"]
 
 
-class Counter(_Metric):
-    kind = "counter"
-
-    def __init__(self, name: str, help_text: str = ""):
-        super().__init__(name, help_text)
-        self._values: Dict[Tuple, float] = {}
-        self._declared: set = set()
-
-    def declare(self, **labels) -> None:
-        """Render this labelled series at 0 from now on, clear()
-        included (Histogram.declare says why)."""
-        key = _label_key(labels)
-        with self._lock:
-            self._declared.add(key)
-            self._values.setdefault(key, 0.0)
-
-    def inc(self, n: float = 1.0, **labels) -> None:
-        key = _label_key(labels)
-        with self._lock:
-            self._values[key] = self._values.get(key, 0.0) + n
-
-    def value(self, **labels) -> float:
-        with self._lock:
-            return self._values.get(_label_key(labels), 0.0)
-
-    def clear(self) -> None:
-        with self._lock:
-            self._values = dict.fromkeys(self._declared, 0.0)
-
-    def snapshot(self) -> Dict[Tuple, float]:
-        """Label key -> value copy (the aggregator's merge input)."""
-        with self._lock:
-            return dict(self._values)
-
-    def expose(self) -> List[str]:
-        with self._lock:
-            items = sorted(self._values.items())
-        out = self._header()
-        for key, v in items or [((), 0.0)]:
-            out.append(f"{self.name}{_fmt_labels(key)} {v}")
-        return out
-
-
-class Gauge(_Metric):
-    kind = "gauge"
+class _Scalar(_Metric):
+    """One number a label set: what counters and gauges share."""
 
     def __init__(self, name: str, help_text: str = "", fn=None):
         super().__init__(name, help_text)
         self._values: Dict[Tuple, float] = {}
-        #: callback gauge, sampled at expose time: a number, or a dict of
+        #: callback series, sampled at expose time: a number, or a dict of
         #: label dict items (a snapshot() key, e.g. (("role", "binder"),))
         #: -> number, one series a label set
         self._fn = fn
@@ -133,17 +90,10 @@ class Gauge(_Metric):
             return {key: float(x) for key, x in v.items()}
         return {(): float(v)}
 
-    def set(self, v: float, **labels) -> None:
-        with self._lock:
-            self._values[_label_key(labels)] = float(v)
-
     def inc(self, n: float = 1.0, **labels) -> None:
         key = _label_key(labels)
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + n
-
-    def dec(self, n: float = 1.0, **labels) -> None:
-        self.inc(-n, **labels)
 
     def value(self, **labels) -> float:
         if self._fn is not None:
@@ -151,12 +101,9 @@ class Gauge(_Metric):
         with self._lock:
             return self._values.get(_label_key(labels), 0.0)
 
-    def clear(self) -> None:
-        with self._lock:
-            self._values.clear()
-
     def snapshot(self) -> Dict[Tuple, float]:
-        """Label key -> value copy (callback gauges sample the fn)."""
+        """Label key -> value copy (the aggregator's merge input;
+        callback series sample the fn)."""
         if self._fn is not None:
             return self._sample()
         with self._lock:
@@ -167,6 +114,41 @@ class Gauge(_Metric):
         for key, v in sorted(self.snapshot().items()) or [((), 0.0)]:
             out.append(f"{self.name}{_fmt_labels(key)} {v}")
         return out
+
+
+class Counter(_Scalar):
+    kind = "counter"
+
+    def __init__(self, name: str, help_text: str = "", fn=None):
+        super().__init__(name, help_text, fn)
+        self._declared: set = set()
+
+    def declare(self, **labels) -> None:
+        """Render this labelled series at 0 from now on, clear()
+        included (Histogram.declare says why)."""
+        key = _label_key(labels)
+        with self._lock:
+            self._declared.add(key)
+            self._values.setdefault(key, 0.0)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._values = dict.fromkeys(self._declared, 0.0)
+
+
+class Gauge(_Scalar):
+    kind = "gauge"
+
+    def set(self, v: float, **labels) -> None:
+        with self._lock:
+            self._values[_label_key(labels)] = float(v)
+
+    def dec(self, n: float = 1.0, **labels) -> None:
+        self.inc(-n, **labels)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._values.clear()
 
 
 class Histogram(_Metric):
@@ -648,8 +630,8 @@ class Registry:
             self._metrics[metric.name] = metric
         return metric
 
-    def counter(self, name: str, help_text: str = "") -> Counter:
-        return self.register(Counter(name, help_text))  # type: ignore
+    def counter(self, name: str, help_text: str = "", fn=None) -> Counter:
+        return self.register(Counter(name, help_text, fn=fn))  # type: ignore
 
     def gauge(self, name: str, help_text: str = "", fn=None) -> Gauge:
         return self.register(Gauge(name, help_text, fn=fn))  # type: ignore
