@@ -1749,6 +1749,9 @@ class BatchScheduler:
                 if chain is not None:
                     return None
         with self._stage("tensorize", pods=len(pods)):
+            # the cached node vectors keep every key this batch uses
+            self.terms.new_batch()
+            self.scorer.new_batch()
             extra_mask, profiles, extra_group = self._residual_mask(pods)
             residual_free = extra_mask is None and not any(
                 helpers.pod_host_ports(p) or _pod_has_conflict_volumes(p)
@@ -1811,7 +1814,12 @@ class BatchScheduler:
                     self.sched_metrics.affinity_evaluations.inc(
                         stage="scores", route="host")
             else:
-                static = self.scorer.static_scores(pods, batch)
+                with self._stage("static_scores", pods=len(pods)):
+                    static = self.scorer.static_scores(pods, batch)
+            if static is not None and self.sched_metrics is not None:
+                # the computed rows; the zero row every batch has is not one
+                self.sched_metrics.static_score_rows.inc(
+                    static[1].shape[0] - 1)
             has_prio_ext = any(e.config.prioritize_verb for e in self.extenders)
             # hysteresis: while host-computed static scores are in play, later
             # launches refuse the chain up front instead of discarding work.

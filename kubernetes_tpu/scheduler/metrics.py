@@ -47,9 +47,14 @@ STAGE_LEAVES = ("pop_wait", "refresh", "tensorize", "dispatch",
 #: SelectorSpreadPriority is weighted and the scheduler has listers
 #: (the served scheduler always), it finds each pod's selectors, gives
 #: every selected label set of the batch a slot and reads its base
-#: counts off the index.
+#: counts off the index. static_scores, in dispatch, is
+#: scorer.static_scores wherever affinity_scores is not (no inter-pod
+#: score carrier): entered by every batch, it computes a row only where a
+#: priority can tell the feasible nodes apart (TaintToleration once some
+#: node carries a PreferNoSchedule taint), so affinity_scores keeps its
+#: meaning and the two never time the same batch.
 STAGE_PARTS = ("topology_apply", "affinity_masks", "affinity_scores",
-               "static_masks", "spread_groups")
+               "static_masks", "spread_groups", "static_scores")
 #: every reason of scheduler_topo_inscan_fallbacks_total
 INSCAN_FALLBACK_REASONS = ("term_cap", "kmax", "soft_terms", "soft_kmax",
                            "soft_gang", "aff_growth", "spread_groups",
@@ -62,7 +67,8 @@ INSCAN_FALLBACK_REASONS = ("term_cap", "kmax", "soft_terms", "soft_kmax",
 THREAD_ROLES = ("scheduling", "binder", "informer", "watch_pump")
 
 
-#: every `cache` of scheduler_node_vector_rebuilds_total
+#: every `cache` of scheduler_node_vector_rebuilds_total and
+#: scheduler_node_vector_evictions_total
 NODE_VECTOR_CACHES = ("terms", "scores", "zones")
 #: every `side` of scheduler_mirror_row_writes_total
 MIRROR_ROW_WRITE_SIDES = ("node", "usage")
@@ -219,6 +225,16 @@ class SchedulerMetrics:
             "Classes (distinct template and score-row pairs) of the "
             "batches' class scans, summed over batches")
         self.scan_classes.declare()
+        # the weighted static score rows scorer.static_scores computed
+        # (one a distinct score key and mask row of the batch; the zero
+        # row every batch has is not counted), before bucketing: the S of
+        # unique_scores [S, N] less one, 0 where no priority but the two
+        # resource ones can tell a pod's feasible nodes apart
+        self.static_score_rows = r.counter(
+            "scheduler_static_score_rows_total",
+            "Static score rows computed (distinct score keys and mask "
+            "rows), summed over batches")
+        self.static_score_rows.declare()
         # SelectorSpread in the scan: the (namespace, label set) groups
         # given a slot of the carried [G, N] counts, before bucketing,
         # summed over batches (a pop of a rollout holds about one a
@@ -312,6 +328,16 @@ class SchedulerMetrics:
             "by cache")
         for cache in NODE_VECTOR_CACHES:
             self.node_vector_rebuilds.declare(cache=cache)
+        # cached node vectors dropped, by cache: a key unused for
+        # tensorize.NODE_VECTOR_IDLE_BATCHES batches, or the least
+        # recently used past NODE_VECTOR_CACHE_BYTES; never a key of the
+        # batch in hand. A dropped key is rebuilt by the full walk on its
+        # next use, so 0 while the queue's keys stay in use
+        self.node_vector_evictions = r.counter(
+            "scheduler_node_vector_evictions_total",
+            "Cached node vectors dropped by disuse or by bytes, by cache")
+        for cache in NODE_VECTOR_CACHES:
+            self.node_vector_evictions.declare(cache=cache)
         # what the mirror's row writes changed (TensorMirror._write_row /
         # _remove_row, installed like the two above): "node" where the
         # node side moved (a row taken or removed, a later set_node),

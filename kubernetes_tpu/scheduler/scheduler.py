@@ -210,11 +210,14 @@ class Scheduler:
         self.algorithm.mirror.transfers = \
             self.metrics.host_to_device_transfers
         #: scheduler_node_vector_{rows_recomputed,rebuilds}_total, counted
-        #: where a cached node vector catches up with the mirror
+        #: where a cached node vector catches up with the mirror, and
+        #: scheduler_node_vector_evictions_total where one is dropped
         self.algorithm.mirror.vector_rows_recomputed = \
             self.metrics.node_vector_rows_recomputed
         self.algorithm.mirror.vector_rebuilds = \
             self.metrics.node_vector_rebuilds
+        self.algorithm.mirror.vector_evictions = \
+            self.metrics.node_vector_evictions
         #: scheduler_mirror_row_writes_total{side}, counted at the write
         self.algorithm.mirror.row_writes = self.metrics.mirror_row_writes
         self._stop = threading.Event()

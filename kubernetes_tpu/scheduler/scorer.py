@@ -19,7 +19,14 @@ then Reduce per priority over the FILTERED node list. Here:
 Priorities whose contribution is CONSTANT over a pod's feasible nodes (e.g.
 TaintToleration when no node has PreferNoSchedule taints: all 10) are
 selection-invariant and dropped — ScheduleResult.score is therefore the
-selection score, not the reference's absolute weighted sum.
+selection score, not the reference's absolute weighted sum. Once some node
+carries a PreferNoSchedule taint (a cluster autoscaler's soft taint on its
+scale-down candidates), every pod has a score key and TaintToleration a
+row: one a (tolerations, mask row) of the batch, reverse-normalised over
+its batch-start feasible set where upstream takes the nodes filtered at
+each decision. With counts of 0 and 1 that is the same argmax: the
+maximum falls to 0 only once no tainted node the row penalises fits, and
+then the nodes that still fit score 10 either way.
 
 In-batch drift: none for SelectorSpread in a singleton batch. Every
 (namespace, label set) of a batch that a Service or controller selects
@@ -343,6 +350,10 @@ class ScoreCompiler:
 
     def _vec(self, key: Tuple, fn, reads: str) -> np.ndarray:
         return self._vec_cache.vector(key, fn, reads)
+
+    def new_batch(self) -> None:
+        """core.schedule_launch opens each batch (NodeVectorCache)."""
+        self._vec_cache.new_batch()
 
     def _node_affinity_raw(self, pod: Pod, meta: prios.PriorityMetadata
                            ) -> Optional[np.ndarray]:

@@ -67,10 +67,14 @@ USAGE_KEYS = ("used", "nonzero_used", "pod_count")
 #: cluster the walk's plain enumerate is no dearer than the patch's row
 #: list, and it is what a resize or a start-up takes anyway
 REBUILD_SHARE = 0.5
-#: cached node vectors a NodeVectorCache keeps; the least recently used
-#: goes first. Entries outlive an epoch, so the count is what bounds them
-#: (a [capacity] f32 vector of a 50,000-node cluster is 256 KiB)
-NODE_VECTOR_CACHE_SIZE = 128
+#: batches a cached node vector may go unused before NodeVectorCache drops
+#: it: a key that has left the queue frees its vector, one that comes back
+#: within this many batches (a short pop between full ones) is still true
+NODE_VECTOR_IDLE_BATCHES = 64
+#: bytes of cached node vectors a NodeVectorCache keeps past the vectors
+#: of the batch in hand, least recently used going first: 8,192 bool
+#: vectors or 2,048 f32 ones at 8,192 rows, 256 f32 ones at 65,536
+NODE_VECTOR_CACHE_BYTES = 64 << 20
 
 
 #: least rows of the scan's carried spread counts [G, N] and least
@@ -233,6 +237,10 @@ class TensorMirror:
         self.vector_rows_recomputed = Counter(
             "scheduler_node_vector_rows_recomputed_total")
         self.vector_rebuilds = Counter("scheduler_node_vector_rebuilds_total")
+        #: cached node vectors dropped, by disuse or by bytes, by cache
+        #: ("terms" | "scores"); installed by the shell like `transfers`
+        self.vector_evictions = Counter(
+            "scheduler_node_vector_evictions_total")
         #: _write_row / _remove_row calls by what they changed: side="node"
         #: (the node side moved: a row taken, removed, or a later
         #: set_node) or "usage" (pods and their requests alone, a bind);
@@ -581,16 +589,18 @@ def precompute_pod_features(pod: Pod) -> Tuple:
 
 class _NodeVector:
     """One entry of a NodeVectorCache: the vector, the mirror epoch it is
-    true for, the per-node function that builds a row of it, and the side
-    of its NodeInfo that function reads."""
+    true for, the per-node function that builds a row of it, the side
+    of its NodeInfo that function reads, and the batch that last used
+    it."""
 
-    __slots__ = ("vec", "epoch", "fn", "reads")
+    __slots__ = ("vec", "epoch", "fn", "reads", "batch")
 
     def __init__(self):
         self.vec: Optional[np.ndarray] = None
         self.epoch = -1
         self.fn: Optional[Callable] = None
         self.reads = "pods"
+        self.batch = 0
 
 
 class NodeVectorCache:
@@ -621,22 +631,54 @@ class NodeVectorCache:
     the next apply() stamps it, which is when the epoch it was held back
     from moves, and never by a "node" vector.
 
-    Entries outlive an epoch, so the cache is bounded by count, least
-    recently used first; an evicted key is rebuilt on its next use. A
-    deployment with a hundred node selectors keeps a hundred `sel` keys
-    alive here at once: each is used by every batch and is a hit until a
-    node event, and past NODE_VECTOR_CACHE_SIZE keys in one queue every
-    use is an eviction and a full walk."""
+    Entries outlive an epoch, so the bound follows the queue: the caller
+    opens each batch (`new_batch`), and a key the batch in hand has used
+    is never dropped, however many keys the batch holds. A key unused for
+    NODE_VECTOR_IDLE_BATCHES batches is dropped as the next batch opens,
+    and past NODE_VECTOR_CACHE_BYTES the least recently used of the keys
+    earlier batches used go; a dropped key is rebuilt on its next use.
+    A deployment with a hundred node pools keeps its hundreds of `tol`
+    and `sel` keys alive here at once: each is used by every batch and
+    is a hit until a node event. Dropping decides only when a vector is
+    rebuilt, never what it holds."""
 
     def __init__(self, mirror: TensorMirror, dtype, cache: str):
         self.mirror = mirror
         self.dtype = dtype
-        #: the `cache` label of scheduler_node_vector_rebuilds_total
+        #: the `cache` label of scheduler_node_vector_rebuilds_total and
+        #: scheduler_node_vector_evictions_total
         self.cache = cache
         self._entries: "OrderedDict[Tuple, _NodeVector]" = OrderedDict()
+        #: bytes of the vectors held
+        self.nbytes = 0
+        #: the batch in hand (new_batch)
+        self._batch = 0
 
     def clear(self) -> None:
         self._entries.clear()
+        self.nbytes = 0
+
+    def new_batch(self) -> None:
+        """A batch opens: the keys unused for NODE_VECTOR_IDLE_BATCHES
+        batches go (the least recently used are first in line)."""
+        self._batch += 1
+        idle = self._batch - NODE_VECTOR_IDLE_BATCHES
+        while self._entries and \
+                next(iter(self._entries.values())).batch < idle:
+            self._drop_oldest()
+
+    def _drop_oldest(self) -> None:
+        _, entry = self._entries.popitem(last=False)
+        if entry.vec is not None:
+            self.nbytes -= entry.vec.nbytes
+        self.mirror.vector_evictions.inc(cache=self.cache)
+
+    def _trim(self) -> None:
+        """Past NODE_VECTOR_CACHE_BYTES, the least recently used keys go,
+        down to the first that the batch in hand has used."""
+        while self.nbytes > NODE_VECTOR_CACHE_BYTES and \
+                next(iter(self._entries.values())).batch < self._batch:
+            self._drop_oldest()
 
     def vector(self, key: Tuple, fn: Callable[[NodeInfo], object],
                reads: Optional[str] = None) -> np.ndarray:
@@ -646,11 +688,10 @@ class NodeVectorCache:
         m = self.mirror
         entry = self._entries.get(key)
         if entry is None:
-            if len(self._entries) >= NODE_VECTOR_CACHE_SIZE:
-                self._entries.popitem(last=False)
             entry = self._entries[key] = _NodeVector()
         else:
             self._entries.move_to_end(key)
+        entry.batch = self._batch
         entry.fn = fn
         if reads is not None:
             entry.reads = reads
@@ -662,12 +703,16 @@ class NodeVectorCache:
         rows = m.rows_since(entry.epoch, reads) if sized else None
         infos = m.infos
         if rows is None:
+            if vec is not None:
+                self.nbytes -= vec.nbytes
             vec = entry.vec = np.zeros((m.t.capacity,), self.dtype)
+            self.nbytes += vec.nbytes
             for row, ni in enumerate(infos):
                 if ni is not None and ni.node is not None:
                     vec[row] = fn(ni)
             m.vector_rebuilds.inc(cache=self.cache)
             m.vector_rows_recomputed.inc(m.n_rows)
+            self._trim()
         else:
             for row in rows.tolist():
                 ni = infos[row]
@@ -690,6 +735,10 @@ class TermCompiler:
 
     def _vector(self, key: Tuple, fn, reads: str) -> np.ndarray:
         return self._cache.vector(key, fn, reads)
+
+    def new_batch(self) -> None:
+        """core.schedule_launch opens each batch (NodeVectorCache)."""
+        self._cache.new_batch()
 
     def tolerations_vector(self, pod: Pod) -> np.ndarray:
         """PodToleratesNodeTaints as a node vector."""

@@ -11,7 +11,8 @@ else. These tests pin
   - equality: over seeded random sequences of cache events, after every
     `refresh` what the long-lived compilers hold equals what compilers
     built fresh over the same mirror compute by the full walk;
-  - the bound: the cache evicts by count without changing an answer;
+  - the bound: the cache keeps every key of the batch in hand and drops
+    keys by disuse and by bytes, without changing an answer;
   - the sides: a bind recomputes no row of a vector that reads the node
     alone and its row of one that reads the pods; every node event (in
     place or by a new object, a delete, a retaken row, a resize) reaches
@@ -403,35 +404,70 @@ def test_chained_rows_are_seen_when_the_epoch_next_moves():
     c.check()
 
 
-def test_the_cache_bound_evicts_without_changing_an_answer():
+def test_the_cache_bound_evicts_without_changing_an_answer(monkeypatch):
+    """The bound follows the queue: one batch keeps all 200 keys it uses
+    (past the 128 that used to bound the cache by count); a key unused
+    for NODE_VECTOR_IDLE_BATCHES batches goes as the next batch opens;
+    past NODE_VECTOR_CACHE_BYTES the least recently used keys of earlier
+    batches go, and a key of the batch in hand never; a dropped key comes
+    back with the answer a fresh compiler gives."""
     c = Cluster(3, 64)
     c.taint()
     c.refresh()
-    bound = tensorize.NODE_VECTOR_CACHE_SIZE
+    dropped = c.mirror.vector_evictions
+    cache = c.terms._cache
+
+    def held():
+        return {key for key, _ in _held(cache)}
+
+    def key(pod):
+        return ("tol", tensorize._canon_tolerations(pod))
     pods = [make_pod(f"t{i}", tolerations=[api.Toleration(
         key=f"k{i}", operator="Exists", effect="NoSchedule")])
-        for i in range(bound + 12)]
+        for i in range(200)]
+    c.terms.new_batch()
     first = [c.terms.tolerations_vector(p).copy() for p in pods]
-    assert len(c.terms._cache._entries) == bound
-    held = {key for key, _ in _held(c.terms._cache)}
-    assert ("tol", tensorize._canon_tolerations(pods[0])) not in held
-    assert ("tol", tensorize._canon_tolerations(pods[-1])) in held
+    assert len(cache._entries) == 200 and dropped.value(cache="terms") == 0
+    assert cache.nbytes == sum(e.vec.nbytes for e in cache._entries.values())
+    # the first hundred stay in use, the second go idle
+    for _ in range(tensorize.NODE_VECTOR_IDLE_BATCHES):
+        c.terms.new_batch()
+        for p in pods[:100]:
+            c.terms.tolerations_vector(p)
+    assert len(cache._entries) == 200
+    c.terms.new_batch()
+    assert held() == {key(p) for p in pods[:100]}
+    assert dropped.value(cache="terms") == 100
     for _ in range(4):
         c.taint()
         c.add_pod()
     c.refresh()
     fresh = TermCompiler(c.mirror)
-    for pod, was in zip(pods, first):
-        got = c.terms.tolerations_vector(pod)
-        assert np.array_equal(got, fresh.tolerations_vector(pod))
-        assert len(c.terms._cache._entries) == bound
-    # a use moves a key to the young end: the oldest goes, not it
-    c.terms.tolerations_vector(pods[12])
-    c.terms.tolerations_vector(make_pod("one-more", tolerations=[
-        api.Toleration(key="one-more", operator="Exists")]))
-    held = {key for key, _ in _held(c.terms._cache)}
-    assert ("tol", tensorize._canon_tolerations(pods[12])) in held
-    assert ("tol", tensorize._canon_tolerations(pods[13])) not in held
+    for pod in pods:
+        assert np.array_equal(c.terms.tolerations_vector(pod),
+                              fresh.tolerations_vector(pod))
+    assert len(cache._entries) == 200 and dropped.value(cache="terms") == 100
+    # bytes for 150 vectors: a new key of a batch that uses 161 sends the
+    # earlier batch's 40 away and keeps all 161 of its own; the next
+    # batch's new key sends the oldest of the rest away, down to 150
+    size = cache._entries[key(pods[0])].vec.nbytes
+    monkeypatch.setattr(tensorize, "NODE_VECTOR_CACHE_BYTES", 150 * size)
+    extra = [make_pod(f"x{i}", tolerations=[api.Toleration(
+        key=f"x{i}", operator="Exists", effect="NoSchedule")])
+        for i in range(2)]
+    c.terms.new_batch()
+    for p in pods[40:] + extra[:1]:
+        c.terms.tolerations_vector(p)
+    assert held() == {key(p) for p in pods[40:] + extra[:1]}
+    assert dropped.value(cache="terms") == 140
+    c.terms.new_batch()
+    c.terms.tolerations_vector(extra[1])
+    assert held() == {key(p) for p in pods[52:] + extra}
+    assert cache.nbytes == 150 * size
+    assert dropped.value(cache="terms") == 152
+    for pod in pods[:60]:
+        assert np.array_equal(c.terms.tolerations_vector(pod),
+                              fresh.tolerations_vector(pod))
 
 
 def test_a_service_event_starts_the_spread_vectors_over():

@@ -366,7 +366,6 @@ def test_a_batch_of_240_pods_has_120_mask_rows_and_120_classes():
     16 zones: one row of unique_masks and one class of the class scan a
     selector, 625 nodes of 5,000 in the proportion a row admits, and the
     decisions the reference would make."""
-    from kubernetes_tpu.scheduler.tensorize import NODE_VECTOR_CACHE_SIZE
     config = dict(CONFIG, nodes=160)
     nodes = cluster.make_nodes(config, 160, 4)
     pods = cluster.PodStream(config, 4).take(240)
@@ -392,9 +391,10 @@ def test_a_batch_of_240_pods_has_120_mask_rows_and_120_classes():
         [20] * 120          # 160 nodes in 16 zones: 2 x 10 a pair
     assert scrape["scheduler_static_mask_rows_total"] == 120
     assert scrape["scheduler_scan_classes_total"] == 120
-    # 120 `sel` keys and one `tol`: under the cache's bound, by seven
-    assert len(seen["terms"]._cache._entries) == 121 \
-        == NODE_VECTOR_CACHE_SIZE - 7
+    # 120 `sel` keys and one `tol`, all kept: the batch in hand loses
+    # none of its keys, whatever their number
+    assert len(seen["terms"]._cache._entries) == 121
+    assert sum(sched_evictions(scrape).values()) == 0
     compared, said = judged(nodes, pods, listed, scrape)
     assert verdict.correct(compared), said
     assert said["pods_outside_their_zones"] == 0
@@ -440,6 +440,14 @@ def test_bind_only_cycles_of_120_selectors_recompute_no_row():
     compared, said = judged(nodes, pods, listed, scrape)
     assert verdict.correct(compared), said
     assert said["pods_outside_their_zones"] == 0
+
+
+def sched_evictions(scrape):
+    """cache -> scheduler_node_vector_evictions_total{cache} of a scrape."""
+    from kubernetes_tpu.scheduler.metrics import NODE_VECTOR_CACHES
+    return {cache: scrape[f'scheduler_node_vector_evictions_total'
+                          f'{{cache="{cache}"}}']
+            for cache in NODE_VECTOR_CACHES}
 
 
 # ------------------------------------ (e) the series and the data files
