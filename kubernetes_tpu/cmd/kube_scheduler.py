@@ -18,6 +18,7 @@ from ..apiserver.httpclient import HTTPClient
 from ..scheduler.config import (KubeSchedulerConfiguration, Policy,
                                 build_scheduler)
 from ..state.leaderelection import LeaderElector
+from ..utils import gcpolicy
 from ..utils.healthz import HealthzServer
 
 
@@ -60,6 +61,10 @@ def main(argv=None) -> int:
 
     client = HTTPClient(args.master)
     sched = build_scheduler(client, cfg)
+    # the collector stops re-walking the informer store and the cache:
+    # each full collection freezes what survived it (utils/gcpolicy.py)
+    gcpolicy.install(prefix="scheduler", process="scheduler",
+                     registry=sched.metrics.registry)
 
     healthz = None
     if cfg.healthz_bind_port > 0:

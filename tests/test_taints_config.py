@@ -500,8 +500,12 @@ def test_the_new_series_are_there_at_zero_from_process_start():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = {m["name"]: m for m in bench["per_layer"]}
-    # appended in this order, last
-    assert [m["name"] for m in bench["per_layer"]][-3:] == list(NEW_METRICS)
+    # appended in this order, after every entry that was there before
+    # them (later metrics follow them)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW_METRICS[0])
+    assert names[first:first + 3] == list(NEW_METRICS)
+    assert names[first - 1] == "hub_gc_full_pause_ms_per_pod"
     for name in NEW_METRICS:
         spec = cluster.load_json(BENCH, "metrics", f"{name}.json")
         entry = entries[name]
