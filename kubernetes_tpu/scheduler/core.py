@@ -843,7 +843,11 @@ class BatchScheduler:
                         .preferred_during_scheduling_ignored_during_execution))
                 for p in pods)
             if has_pref:
-                if self._soft_plan_cached(pods) is None:
+                # the plan of the pop, before the shell opens the cycle:
+                # the launch of the same list finds it made
+                with self._stage("soft_terms", pods=len(pods)):
+                    plan = self._soft_plan_cached(pods)
+                if plan is None:
                     # channel-union overflow: sub-chunk so frozen credits
                     # refresh between launches. Gang batches used to chunk
                     # UNCONDITIONALLY here (soft_gang); the gang kernel's
@@ -1490,6 +1494,8 @@ class BatchScheduler:
         batch.set_soft_terms(dom, n_domains, base, plan["tmpl_of"],
                              read_tids, read_w, write_tids, write_w,
                              plan["weight"])
+        if self.sched_metrics is not None:
+            self.sched_metrics.soft_channels.inc(len(plan["chan_list"]))
         return (tuple(plan["chan_list"]), plan["tmpl_sigs"],
                 plan["kmax"], plan["weight"], plan["hard_w"],
                 n_domains, self.mirror.epoch, self.mirror.t.capacity)
@@ -1790,13 +1796,14 @@ class BatchScheduler:
             if gang_units is None:
                 spread_sig = self._assign_spread_groups(pods, batch)
                 topo_cover = self._assign_topology_terms(pods, batch, profiles)
+        with self._stage("soft_terms", pods=len(pods)):
             soft_sig = self._assign_soft_terms(pods, batch)
-            spread_present = spread_sig is not None
-            soft_present = soft_sig is not None
-            if self._batch_fell_back:
-                self._batch_fell_back = False
-                if self.sched_metrics is not None:
-                    self.sched_metrics.topo_inscan_fallback_batches.inc()
+        spread_present = spread_sig is not None
+        soft_present = soft_sig is not None
+        if self._batch_fell_back:
+            self._batch_fell_back = False
+            if self.sched_metrics is not None:
+                self.sched_metrics.topo_inscan_fallback_batches.inc()
         with self._stage("dispatch", pods=len(pods)):
             nom_dev = self._nominated_device()
             if nom_dev is not None:

@@ -420,12 +420,13 @@ def _soft_raw(soft_dom, scnt, soft_base, pod):
 
 def _soft_score(raw, fits, weight):
     """minmax_normalize over the CURRENT feasible set (the oracle's
-    domain: prioritize_nodes normalizes over filtered nodes), floored with
-    the same 4e-6 epsilon as _balanced_allocation (f32 vs the oracle's f64
-    can land a hair under an exact-integer boundary)."""
-    mn = jnp.min(jnp.where(fits, raw, jnp.inf))
-    mx = jnp.max(jnp.where(fits, raw, -jnp.inf))
-    span_ok = (mx > mn) & jnp.isfinite(mn)
+    domain: prioritize_nodes normalizes over filtered nodes), min and max
+    from 0 as upstream starts them, floored with the same 4e-6 epsilon as
+    _balanced_allocation (f32 vs the oracle's f64 can land a hair under
+    an exact-integer boundary)."""
+    mn = jnp.minimum(jnp.min(jnp.where(fits, raw, jnp.inf)), 0.0)
+    mx = jnp.maximum(jnp.max(jnp.where(fits, raw, -jnp.inf)), 0.0)
+    span_ok = mx > mn
     norm = jnp.floor(MAX_PRIORITY * (raw - mn)
                      / jnp.maximum(mx - mn, jnp.float32(1e-30)) + 4e-6)
     return jnp.where(span_ok, weight * norm, 0.0)
@@ -1012,9 +1013,11 @@ def _soft_score_sharded(raw, fits, weight):
     """_soft_score with the min-max normalization domain reduced across
     shards (f32 min/max are exact, so bit-identical)."""
     from ..sharding import NODE_AXIS
-    mn = lax.pmin(jnp.min(jnp.where(fits, raw, jnp.inf)), NODE_AXIS)
-    mx = lax.pmax(jnp.max(jnp.where(fits, raw, -jnp.inf)), NODE_AXIS)
-    span_ok = (mx > mn) & jnp.isfinite(mn)
+    mn = jnp.minimum(
+        lax.pmin(jnp.min(jnp.where(fits, raw, jnp.inf)), NODE_AXIS), 0.0)
+    mx = jnp.maximum(
+        lax.pmax(jnp.max(jnp.where(fits, raw, -jnp.inf)), NODE_AXIS), 0.0)
+    span_ok = mx > mn
     norm = jnp.floor(MAX_PRIORITY * (raw - mn)
                      / jnp.maximum(mx - mn, jnp.float32(1e-30)) + 4e-6)
     return jnp.where(span_ok, weight * norm, 0.0)

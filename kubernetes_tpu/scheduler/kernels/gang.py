@@ -455,9 +455,11 @@ def gang_schedule_reference(node_cfg: Dict[str, np.ndarray],
                 raw = soft_base[max(int(soft_bidx[i]), 0)] + \
                     (soft_rw[i][:, None]
                      * np.where(valid_r, at, np.float32(0.0))).sum(axis=0)
-                mn = np.min(np.where(fits, raw, np.float32(np.inf)))
-                mx = np.max(np.where(fits, raw, np.float32(-np.inf)))
-                if mx > mn and np.isfinite(mn):
+                mn = min(np.min(np.where(fits, raw, np.float32(np.inf))),
+                         np.float32(0.0))
+                mx = max(np.max(np.where(fits, raw, np.float32(-np.inf))),
+                         np.float32(0.0))
+                if mx > mn:
                     norm = np.floor(
                         np.float32(10.0) * (raw - mn)
                         / np.maximum(mx - mn, np.float32(1e-30))

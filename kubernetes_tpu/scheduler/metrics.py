@@ -28,7 +28,18 @@ SCHEDULING_LATENCY_BUCKETS = (0.0005, 0.001, 0.002, 0.004, 0.008, 0.016,
 #: max_inflight_binds transactions.
 STAGE_PARENTS = ("algorithm", "commit", "launch", "fetch")
 STAGE_LEAVES = ("pop_wait", "refresh", "tensorize", "dispatch",
-                "scan_wait", "repair", "assume", "bind_backlog")
+                "scan_wait", "repair", "assume", "bind_backlog",
+                "soft_terms")
+# soft_terms is the channel plan and the credit tables of in-scan
+# preferred inter-pod (anti-)affinity (core._soft_plan,
+# core._assign_soft_terms), a leaf of its own at two sites: in the
+# launch between tensorize and dispatch, entered by every batch (the
+# table build, and the plan of a pod list not planned before), and in
+# core.soft_batch_limit, which the shell calls before the algorithm
+# parent opens, around the plan of a pop of more than SOFT_SCORE_CHUNK
+# pods that carries preferred terms (outside the cycle, as pop_wait
+# is). It is no part of tensorize, so the cycle's leaves still sum to
+# the cycle.
 #: what the inter-pod (anti-)affinity machinery takes of a leaf, each
 #: nested in the leaf named: topology_apply in refresh (TopologyIndex.
 #: apply, the (term, domain) counts kept as binds land, and beside it
@@ -235,6 +246,18 @@ class SchedulerMetrics:
             "Static score rows computed (distinct score keys and mask "
             "rows), summed over batches")
         self.static_score_rows.declare()
+        # the credit channels of in-scan preferred inter-pod
+        # (anti-)affinity a launch installed (core._assign_soft_terms: a
+        # (kind, term) accumulator each, len of the plan's chan_list),
+        # summed over the launches that install soft tables: 0 where no
+        # pod carries a preferred term and no required-affinity credit
+        # can move in a batch; 2 a launch where every pod carries one
+        # preferred anti-affinity term on its own label
+        self.soft_channels = r.counter(
+            "scheduler_soft_channels_total",
+            "Credit channels of in-scan preferred inter-pod affinity "
+            "tables installed, summed over launches")
+        self.soft_channels.declare()
         # SelectorSpread in the scan: the (namespace, label set) groups
         # given a slot of the carried [G, N] counts, before bucketing,
         # summed over batches (a pop of a rollout holds about one a

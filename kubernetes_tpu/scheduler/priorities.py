@@ -405,11 +405,14 @@ def normalize_reduce(scores: Dict[str, float], reverse: bool = False
 
 def minmax_normalize(scores: Dict[str, float]) -> Dict[str, int]:
     """InterPodAffinity's in-place normalization (interpod_affinity.go:
-    MaxPriority * (count - min) / (max - min); all equal -> 0)."""
+    MaxPriority * (count - min) / (max - min); all equal -> 0). min and
+    max start at 0 (CalculateInterPodAffinityPriority declares `var
+    maxCount, minCount int64` and widens both over the nodes), so where
+    every count is negative the largest still maps below 10."""
     if not scores:
         return {}
-    max_v = max(scores.values())
-    min_v = min(scores.values())
+    max_v = max(0.0, max(scores.values()))
+    min_v = min(0.0, min(scores.values()))
     if max_v - min_v <= 0:
         return {n: 0 for n in scores}
     return {n: int(MAX_PRIORITY * (v - min_v) / (max_v - min_v))

@@ -59,6 +59,24 @@ from .tensorize import (NodeVectorCache, TensorMirror, TermCompiler,
 MAXP = float(prios.MAX_PRIORITY)
 
 
+def interpod_reduce(raw: np.ndarray, fits: np.ndarray
+                    ) -> Optional[np.ndarray]:
+    """InterPodAffinityPriority's normalisation over the node axis
+    (prios.minmax_normalize): MaxPriority * (raw - min) / (max - min),
+    floored, with min and max over the feasible nodes and both from 0.
+    None where every feasible node has one count: the row is then one
+    value over them (0, or 10 where the count is positive), which moves
+    no argmax over a feasible set that only shrinks inside a batch, so
+    no row is computed or shipped for it."""
+    if not fits.any():
+        return None
+    lo, hi = float(raw[fits].min()), float(raw[fits].max())
+    if lo == hi:
+        return None
+    mn, mx = min(0.0, lo), max(0.0, hi)
+    return np.floor(MAXP * (raw - mn) / (mx - mn))
+
+
 def _canon_preferred_node_affinity(pod: Pod) -> Tuple:
     aff = pod.spec.affinity
     if not aff or not aff.node_affinity:
@@ -605,11 +623,9 @@ class ScoreCompiler:
         if w.get("InterPodAffinityPriority") and not skip_interpod:
             raw = self._interpod_raw(pod)
             if raw is not None:
-                mn = float(raw[fits].min()) if fits.any() else 0.0
-                mx = float(raw[fits].max()) if fits.any() else 0.0
-                if mx > mn:
-                    acc(np.floor(MAXP * (raw - mn) / (mx - mn)),
-                        w["InterPodAffinityPriority"])
+                row = interpod_reduce(raw, fits)
+                if row is not None:
+                    acc(row, w["InterPodAffinityPriority"])
         return total
 
     def _spread_reduce(self, counts: np.ndarray, feas: np.ndarray

@@ -564,11 +564,19 @@ def test_static_scores_is_timed_where_affinity_scores_is_not():
 def test_the_cell_and_the_configuration_are_declared_as_data():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell = bench["workloads"][-1]
+    # appended after every cell and configuration before it; later PRs
+    # append theirs after these
+    cells = [w["name"] for w in bench["workloads"]]
+    cell = bench["workloads"][cells.index("taints5k.wave4096")]
+    assert cells.index("taints5k.wave4096") \
+        > cells.index("svcspread5k.wave4096")
     assert cell == {"name": "taints5k.wave4096", "config": CONFIG["name"],
                     "traffic": "wave4096", "chips": 1, "why": cell["why"]}
     assert len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
+    configs = [c["name"] for c in bench["configs"]]
+    entry = bench["configs"][configs.index(CONFIG["name"])]
+    assert configs.index(CONFIG["name"]) \
+        > configs.index("e2e-load-5000n-services")
     assert entry["name"] == CONFIG["name"] == "dedicated-pools-5000n-taints"
     assert entry["file"] == \
         "benchmarks/configs/dedicated-pools-5000n-taints.json"
